@@ -43,6 +43,11 @@ def _load() -> ctypes.CDLL | None:
             subprocess.run(
                 [cc, "-O3", "-shared", "-fPIC", str(src), "-o", str(so)],
                 check=True, capture_output=True, timeout=120)
+            # builds of earlier framer.c revisions: nothing loads them
+            # again, and a copied tree should carry one library
+            for stale in _DIR.glob("_framer-*.so"):
+                if stale != so:
+                    stale.unlink(missing_ok=True)
         lib = ctypes.CDLL(str(so))
         lib.etl_frame_pgoutput.restype = ctypes.c_int64
         lib.etl_pack_bmat.restype = None

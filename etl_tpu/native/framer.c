@@ -238,8 +238,7 @@ int64_t etl_gather_string(const uint8_t *data, int64_t data_len,
  * Symbol alphabet (4 bits): 0-9 = digits, 10 '-', 11 '+', 12 '.', 13 ':',
  * 14 ' ', 15 = pad. Covers int/float(fixed)/date/time/timestamp text;
  * any other byte (e.g. 'e' exponents, NaN/Infinity) marks the row in
- * bad_rows for the CPU oracle. Halves the host→device transfer — the
- * binding resource on a tunnel/PCIe-attached accelerator.
+ * bad_rows for the CPU oracle. Halves the host→device transfer.
  * widths[] must all be even; bmat has sum(widths)/2 bytes per row. */
 void etl_pack_bmat_nibble(const uint8_t *data, int64_t data_len,
                           const int32_t *offsets, const int32_t *lengths,

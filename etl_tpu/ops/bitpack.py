@@ -1,8 +1,8 @@
 """Bit-packed device→host result transport.
 
-The device link is latency- and fetch-bandwidth-bound (~40 MB/s out of the
-chip vs ~1.5 GB/s in, measured on the target), so the decode program's
-output layout is the binding resource of the whole pipeline. Instead of one
+Every batch pays one device→host fetch, so the decode program's output
+layout decides how many bytes that fetch moves (the link's cost on this
+machine: not measured). Instead of one
 int32 lane per parsed component (16 B/row for a 3-int column schema), each
 row's components are packed into the fewest 32-bit words that their
 *maximum possible magnitudes* allow — and those maxima are known on the

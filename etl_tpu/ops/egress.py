@@ -436,17 +436,23 @@ def lower_egress_program(pspecs: tuple, encoder: str, row_capacity: int,
 
 
 def egress_fn_key(row_capacity: int, pspecs: tuple, encoder: str,
-                  mesh_fp) -> tuple:
+                  mesh_fp, host: bool) -> tuple:
     """Module program-cache key for one egress program. Same tuple
     arity/ordering as decode keys so the program store, the observed-
     signature recorder and the warm-restart path handle it unchanged;
     the ("egress", encoder) marker rides the pred_fp slot (decode keys
     hold None or a predicate fingerprint there — never a 2-tuple
-    starting with "egress", so the spaces cannot collide). key[-1] True:
-    the persist contract expects NO donation, which is exactly this
-    program's stance on every backend."""
+    starting with "egress", so the spaces cannot collide). key[-1] is
+    the placement, as in decode keys: True when the words this program
+    renders live on the host CPU backend — the store reloads a
+    serialized executable onto the devices its key names."""
     return (row_capacity, pspecs, False, mesh_fp, False,
-            ("egress", encoder), True)
+            ("egress", encoder), host)
+
+
+def is_egress_key(key: tuple) -> bool:
+    marker = key[5]
+    return isinstance(marker, tuple) and marker[:1] == ("egress",)
 
 
 # background-compile bookkeeping, mirroring engine._BG_COMPILE_KEYS: a
@@ -456,6 +462,21 @@ def egress_fn_key(row_capacity: int, pspecs: tuple, encoder: str,
 _EGRESS_BG_KEYS: set = set()
 _EGRESS_BG_FAILED: set = set()
 _EGRESS_BG_LOCK = threading.Lock()
+
+
+def count_failure() -> None:
+    """One egress build, dispatch or materialization raised and its batch
+    shipped without wire buffers: availability code, but countable."""
+    from ..telemetry.metrics import (ETL_EGRESS_DEVICE_FAILURES_TOTAL,
+                                     registry)
+
+    registry.counter_inc(ETL_EGRESS_DEVICE_FAILURES_TOTAL)
+
+
+def _build_failed(key: tuple) -> None:
+    with _EGRESS_BG_LOCK:
+        _EGRESS_BG_FAILED.add(key)
+    count_failure()
 
 
 def egress_fn_ready(key: tuple, builder, example_args: tuple,
@@ -483,8 +504,7 @@ def egress_fn_ready(key: tuple, builder, example_args: tuple,
         try:
             fn = program_store.acquire(key, builder, example_args)
         except Exception:
-            with _EGRESS_BG_LOCK:
-                _EGRESS_BG_FAILED.add(key)
+            _build_failed(key)
             log.warning("egress program build failed; wire encoding "
                         "stays on the host twins", exc_info=True)
             return None
@@ -503,8 +523,7 @@ def egress_fn_ready(key: tuple, builder, example_args: tuple,
             jax.block_until_ready(f(*example_args))
             _shared_fn_put(key, f)
         except Exception:
-            with _EGRESS_BG_LOCK:
-                _EGRESS_BG_FAILED.add(key)
+            _build_failed(key)
             log.warning("background egress-program compile failed; wire "
                         "encoding stays on the host twins", exc_info=True)
         finally:

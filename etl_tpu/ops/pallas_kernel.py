@@ -17,9 +17,10 @@ that docstring implied:
   shared scalar helpers, covered by the same differential suites).
 
 `DeviceDecoder(use_pallas=True)` selects it; `bench.py` measures BOTH
-engines every run and the headline takes whichever is faster. If the
-kernel fails to compile the decoder logs and falls back to the XLA
-program permanently for that instance (engine._device_call).
+engines every run and the headline takes whichever is faster. A kernel
+Mosaic refuses to compile raises at the dispatch, like any other
+compile error; only the width bound below routes a schema to the XLA
+program, and it flips the decoder's `use_pallas` flag when it does.
 
 Falls back to interpret mode off-TPU so the differential tests cover
 the same code path on CPU.
@@ -41,16 +42,20 @@ from .parsers_lanes import parse_column_lanes, unpack_nibbles_lanes
 # dense columns.
 DEFAULT_BLOCK_ROWS = 2048
 
-# The fully-unrolled parse chain crashes the Mosaic compiler
-# (tpu_compile_helper exit 1) once the kernel body grows past ~150
-# unrolled byte POSITIONS (sum of column widths — nibble packing halves
-# the gathered bytes but not the positions, so the cap is width-based)
-# — measured on v5e: 12 x 12-byte int columns (144 positions) compile,
-# 14 (168) kill the compiler. Wide schemas take the XLA program instead:
-# engine._device_call consults pallas_supported BEFORE building and
-# flips the decoder's use_pallas flag, so no doomed remote-compile
-# attempt happens and engine labels stay honest.
-MAX_TOTAL_WIDTH = 144
+# The fully-unrolled parse chain keeps every [blk]-row temporary of every
+# byte POSITION (sum of column widths — nibble packing halves the
+# gathered bytes but not the positions, so the cap is width-based) alive
+# on the kernel's VMEM stack. Measured on a v5e with libtpu 0.0.34 at
+# DEFAULT_BLOCK_ROWS (PR 21, one chip run): 10 x 12-byte int columns
+# (120 positions) compile, nibble-packed or raw, and so does a
+# 9-column mix of every non-timestamp kind at 120; 11 int columns (132)
+# fail with RESOURCE_EXHAUSTED ("ran out of memory in memory space
+# vmem"). An earlier libtpu took 144 and crashed the compiler at 168.
+# Wide schemas take the XLA program instead: engine._dispatch_stage
+# consults pallas_supported BEFORE building and flips the decoder's
+# use_pallas flag, so no doomed compile attempt happens and engine
+# labels stay honest.
+MAX_TOTAL_WIDTH = 120
 
 
 def pallas_supported(specs) -> bool:

@@ -256,8 +256,10 @@ def parse_float_lanes(rows, lengths):
         dd = jnp.where(mant_sel & is_digit, d, 0)
         limb0 = limb0 + jnp.where(mant_sel & (r // 9 == 0), dd * w, 0)
         limb1 = limb1 + jnp.where(mant_sel & (r // 9 == 1), dd * w, 0)
-        # leading-zero run among mantissa digits (non-mantissa = neutral)
-        running_zero &= jnp.where(mant_sel, d == 0, True)
+        # leading-zero run among mantissa digits (non-mantissa = neutral).
+        # Plain boolean algebra: a select between i1 vectors is something
+        # Mosaic (libtpu 0.0.34) refuses to truncate back from i8
+        running_zero &= ~mant_sel | (d == 0)
         lead_zero_run = lead_zero_run \
             + (running_zero & mant_sel).astype(jnp.int32)
 

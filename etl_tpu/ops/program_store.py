@@ -43,11 +43,11 @@ Three layers, one key space (ops/engine._SHARED_FN_CACHE keys):
    the executable instead of re-paying the XLA build (measured: a ~32 s
    120-column build loads back in well under a second). The version tag
    hashes jaxlib/jax versions, the backend, the decode-source hash, and
-   the host CPU feature flags — the XLA:CPU failure mode that sank the
-   old `jax_compilation_cache_dir` attempt (AOT results recorded against
-   different machine features hard-hang on reload) can only be hit by
-   byte-sharing a dir across heterogeneous machines, and the tag keeps
-   those populations in separate subdirectories. Writes are atomic
+   the host CPU feature flags — an XLA:CPU AOT result carries the
+   compile machine's feature set and its loader warns of SIGILL on a
+   host that lacks one, which can only happen by byte-sharing a dir
+   across heterogeneous machines, and the tag keeps those populations
+   in separate subdirectories. Writes are atomic
    (tmp + rename), so concurrent processes can share a dir; a corrupted
    or stale file is deleted and treated as a miss — degrade is always a
    clean rebuild, never a crash.
@@ -226,6 +226,27 @@ def active_dir() -> "str | None":
     return os.environ.get("ETL_TPU_PROGRAM_CACHE_DIR") or None
 
 
+def place_jax_compile_cache() -> str:
+    """Give JAX's persistent compilation cache a directory that outlives
+    the process, and return it. Entry points (chip_smoke.py, bench.py,
+    the replicator) call this once before their first compile; nothing
+    calls it at import time. Where $JAX_COMPILATION_CACHE_DIR is set JAX
+    already reads it and no code names another directory; otherwise the
+    cache lives at `<checkout>/.jax_cache` — a fixed path, because the
+    path is part of what a later process must repeat to hit. Separate
+    from the AOT store above, which stays off unless configured."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    import jax
+
+    checkout = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    path = os.path.join(checkout, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 _SOURCE_MODULES = ("bitpack.py", "parsers.py", "parsers_lanes.py",
                    "pallas_kernel.py", "engine.py", "predicate.py",
                    "staging.py")
@@ -237,7 +258,7 @@ def _cpu_features() -> str:
     guards (machine features recorded at compile time vs the execution
     host) is exactly a cross-machine mismatch, so the flags ride the
     version tag and heterogeneous hosts sharing a cache dir use separate
-    subdirectories instead of hanging each other."""
+    subdirectories instead of loading each other's code."""
     try:
         with open("/proc/cpuinfo") as f:
             for line in f:
@@ -304,12 +325,26 @@ def _path_for(key: tuple, cache_dir: str) -> str:
     return os.path.join(cache_dir, version_tag(), fingerprint(key) + ".prog")
 
 
-def _serialize_mod():
-    try:
-        from jax.experimental import serialize_executable
-        return serialize_executable
-    except Exception:  # jax without the module: persistence disabled
-        return None
+def _execution_devices(key: tuple) -> list:
+    """The devices the program behind `key` was compiled for, as the key
+    itself says: the host CPU device for a host key (key[-1]), the mesh's
+    devices for a sharded device key (the fingerprint in key[3] carries
+    their ids), else the one default device. `deserialize_and_load`
+    otherwise assumes the default backend and ALL of its devices — a
+    single-device program would come back demanding one shard per visible
+    device, and a host program would be handed to the accelerator's
+    client."""
+    import jax
+
+    if key[-1]:
+        from .engine import host_cpu_device
+
+        return [host_cpu_device()]
+    mesh_fp = key[3]
+    if mesh_fp is None:
+        return [jax.devices()[0]]
+    by_id = {d.id: d for d in jax.devices()}
+    return [by_id[i] for i in mesh_fp[2]]
 
 
 def save(key: tuple, compiled) -> bool:
@@ -318,11 +353,12 @@ def save(key: tuple, compiled) -> bool:
     observe a torn file. Best-effort: any failure logs and returns
     False — persistence never breaks decode."""
     cache_dir = active_dir()
-    se = _serialize_mod()
-    if cache_dir is None or se is None:
+    if cache_dir is None:
         return False
     try:
-        payload, in_tree, out_tree = se.serialize(compiled)
+        from jax.experimental import serialize_executable
+
+        payload, in_tree, out_tree = serialize_executable.serialize(compiled)
         blob = pickle.dumps({
             "format": _CACHE_FORMAT_VERSION, "key": _stable_repr(key),
             "payload": payload, "in_tree": in_tree, "out_tree": out_tree,
@@ -349,9 +385,10 @@ def try_load(key: tuple, record_absent: bool = True):
     leads straight into `acquire` (which probes — and counts — again);
     invalid misses always count, they are actionable events."""
     cache_dir = active_dir()
-    se = _serialize_mod()
-    if cache_dir is None or se is None:
+    if cache_dir is None:
         return None
+    from jax.experimental import serialize_executable
+
     from ..telemetry.metrics import (ETL_COMPILE_CACHE_HITS_TOTAL,
                                      ETL_COMPILE_CACHE_LOAD_SECONDS,
                                      ETL_COMPILE_CACHE_MISSES_TOTAL,
@@ -370,8 +407,10 @@ def try_load(key: tuple, record_absent: bool = True):
         if data.get("format") != _CACHE_FORMAT_VERSION \
                 or data.get("key") != _stable_repr(key):
             raise ValueError("program cache entry does not match its key")
-        fn = se.deserialize_and_load(data["payload"], data["in_tree"],
-                                     data["out_tree"])
+        devices = _execution_devices(key)
+        fn = serialize_executable.deserialize_and_load(
+            data["payload"], data["in_tree"], data["out_tree"],
+            backend=devices[0].client, execution_devices=devices)
     except Exception:
         log.warning("corrupt/stale program cache entry %s; deleting and "
                     "rebuilding", path, exc_info=True)
@@ -398,10 +437,8 @@ def acquire(key: tuple, builder, example_args: "tuple | None" = None):
     shapes/dtypes/placement ARE the jit signature, so the AOT lowering
     can never drift from what the call sites pass). Every path counts
     one program build in etl_programs_compiled_total — the counter the
-    warm-restart gates assert stays at zero. AOT or serialization
-    failures (e.g. a Mosaic rejection, which must surface at the CALL
-    site where engine's pallas fallback handles it) degrade to the plain
-    jitted callable, memory-only."""
+    warm-restart gates assert stays at zero. A compile error raises; a
+    serialization failure degrades to the in-memory executable."""
     from ..telemetry.metrics import ETL_PROGRAMS_COMPILED_TOTAL, registry
 
     fn = try_load(key)
@@ -409,16 +446,10 @@ def acquire(key: tuple, builder, example_args: "tuple | None" = None):
         return fn
     jitted = builder()
     registry.counter_inc(ETL_PROGRAMS_COMPILED_TOTAL)
-    if active_dir() is None or example_args is None \
-            or _serialize_mod() is None:
+    if active_dir() is None or example_args is None:
         return jitted
-    try:
-        lowered = jitted.lower(*example_args)
-        compiled = lowered.compile()
-    except Exception:
-        # compile errors must surface at the call (engine routes Mosaic
-        # rejections to the XLA fallback there; real errors propagate)
-        return jitted
+    lowered = jitted.lower(*example_args)
+    compiled = lowered.compile()
     problems = persist_contract_violations(key, jitted, lowered,
                                            example_args)
     if problems:
@@ -442,21 +473,25 @@ def persist_contract_violations(key: tuple, jitted, lowered,
     `--programs` pass): the no-host-callback and donation-verified
     contracts, evaluated on the program about to be cached to disk.
     Expected donation is inferred from the cache key — host programs
-    (key[-1] is True) never declare donation; device programs declare it
-    exactly when the backend supports it (engine._donation_supported).
+    (key[-1] is True) and egress programs (their input is still the
+    decode fetch's source) never declare donation; device decode
+    programs declare it exactly when the backend supports it
+    (engine._donation_supported).
     Returns human-readable violation strings; analyzer errors return []
     (the gate must never block decode or persistence on its own bug)."""
     try:
         import jax
 
         from ..analysis.ir import contracts
+        from .egress import is_egress_key
         from .engine import _donation_supported
 
         problems = []
         jaxpr = jitted.trace(*example_args).jaxpr
         for detail, _msg in contracts.check_host_callback(jaxpr):
             problems.append(f"ir-host-callback: {detail}")
-        declared = (not key[-1]) and _donation_supported()
+        declared = not key[-1] and not is_egress_key(key) \
+            and _donation_supported()
         for detail, _msg in contracts.check_donation(
                 lowered.as_text(), declared, jax.default_backend()):
             problems.append(f"ir-donation: {detail}")

@@ -308,6 +308,9 @@ def main(argv: list[str] | None = None) -> int:
                              "store's authoritative assignment")
     args = parser.parse_args(argv)
     env = Environment(args.environment) if args.environment else None
+    from .ops.program_store import place_jax_compile_cache
+
+    place_jax_compile_cache()
     try:
         asyncio.run(run_replicator(args.config_dir, env,
                                    shard=args.shard,

@@ -216,6 +216,9 @@ class Supervisor:
                     "device-degraded",
                     f"batch engine degraded to host oracle for "
                     f"{cooldown:.0f}s after repeated device-side stalls")
+                registry.counter_inc(
+                    ETL_SUPERVISION_EVENTS_TOTAL,
+                    labels={"kind": "degrade", "component": hb.name})
                 out.append(self._emit(SupervisionEvent(
                     "degrade", hb.name,
                     f"host-oracle degrade for {cooldown:.0f}s")))

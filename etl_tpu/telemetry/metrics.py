@@ -169,6 +169,11 @@ ETL_DECODE_BACKGROUND_COMPILES_TOTAL = \
 # consumed them via the fast assembly path vs fell back to the host
 # columnar encoders (label path=device|host)
 ETL_EGRESS_DEVICE_BATCHES_TOTAL = "etl_egress_device_batches_total"
+# egress program builds, dispatches or materializations that raised: the
+# batch shipped without wire buffers and the destination encoded it
+# host-side (availability over the fast path) — chip_smoke.py holds this
+# at zero
+ETL_EGRESS_DEVICE_FAILURES_TOTAL = "etl_egress_device_failures_total"
 ETL_EGRESS_WRITES_TOTAL = "etl_egress_writes_total"
 # program store (ops/program_store.py): cache hits by layer (memory =
 # the in-process _SHARED_FN_CACHE, disk = a deserialized AOT

@@ -87,12 +87,10 @@ class TestContractsFire:
         assert contracts.check_donation(text, False, "cpu") == []
 
     def test_widening_fires(self):
-        from jax.experimental import enable_x64
-
         def bad(x):
             return x.astype(jnp.float64).sum()
 
-        with enable_x64():
+        with jax.enable_x64(True):
             jaxpr = jax.jit(bad).trace(
                 jax.ShapeDtypeStruct((64,), np.float32)).jaxpr
         hits = contracts.check_widening(jaxpr)
@@ -223,6 +221,13 @@ class TestDeterminism:
 # executable)
 # ---------------------------------------------------------------------------
 
+def _gate_key(tag: str) -> tuple:
+    """A well-formed program-cache key (the decode keys' 7 slots) for a
+    single-device, non-host program: the store reads a key's placement
+    and mesh slots when it reloads."""
+    return (8, ("ir-gate-test", tag), False, None, False, None, False)
+
+
 class TestPersistGate:
     @pytest.fixture(autouse=True)
     def _store(self, tmp_path):
@@ -235,14 +240,11 @@ class TestPersistGate:
         program_store.reset_for_tests()
 
     def test_violating_program_not_persisted(self, _store, tmp_path):
-        if _store._serialize_mod() is None:
-            pytest.skip("jax AOT serialization unavailable")
-
         def bad(x):
             return jax.pure_callback(
                 lambda v: v, jax.ShapeDtypeStruct(x.shape, x.dtype), x)
 
-        key = ("ir-gate-test", "bad", False)
+        key = _gate_key("bad")
         args = (np.zeros((8,), dtype=np.float32),)
         fn = _store.acquire(key, lambda: jax.jit(bad), args)
         # still served (decode never regresses on a lint result) ...
@@ -251,10 +253,7 @@ class TestPersistGate:
         assert _store.try_load(key, record_absent=False) is None
 
     def test_clean_program_persists(self, _store):
-        if _store._serialize_mod() is None:
-            pytest.skip("jax AOT serialization unavailable")
-
-        key = ("ir-gate-test", "good", False)
+        key = _gate_key("good")
         args = (np.zeros((8,), dtype=np.float32),)
         fn = _store.acquire(key, lambda: jax.jit(lambda x: x + 1), args)
         np.testing.assert_array_equal(np.asarray(fn(*args)), args[0] + 1)
@@ -269,7 +268,7 @@ class TestPersistGate:
         args = (np.zeros((8,), dtype=np.float32),)
         lowered = jitted.lower(*args)
         problems = _store.persist_contract_violations(
-            ("k", False), jitted, lowered, args)
+            _gate_key("k"), jitted, lowered, args)
         assert any("ir-host-callback" in p for p in problems)
 
 

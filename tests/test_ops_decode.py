@@ -365,8 +365,8 @@ class TestReviewRegressions:
 
 class TestPallasKernel:
     """The Pallas program (interpret mode on CPU) must agree with the XLA
-    program bit-for-bit; on TPU the engine falls back to XLA automatically
-    if Mosaic rejects the lowering."""
+    program bit-for-bit; on TPU a lowering Mosaic rejects raises
+    (chip_smoke.py compiles every kind there)."""
 
     def test_pallas_matches_xla(self):
         oids = [Oid.INT4, Oid.INT8, Oid.DATE, Oid.TIMESTAMPTZ]

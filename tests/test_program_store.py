@@ -344,6 +344,52 @@ class TestPersistence:
                                     {"layer": "disk"}) == h0 + 1
         assert_batches_identical(b1, b2)
 
+    def test_reload_binds_the_devices_the_key_names(self, tmp_path):
+        """jax 0.9.0's deserialize_and_load defaults to the default
+        backend and ALL of its devices: with the suite's 8 forced host
+        devices a reloaded single-device program demanded 8 shards
+        ("Expected args to execute_sharded_on_local_devices to have 8
+        shards"). Store a host-key, a device-key and a mesh-key program,
+        reload each into an emptied in-process cache, and call it with
+        the arguments its dispatch passes."""
+        import jax
+
+        from etl_tpu.parallel.mesh import decode_mesh
+
+        assert len(jax.devices()) == 8
+        program_store.configure(str(tmp_path))
+        schema = make_schema([Oid.INT4, Oid.INT8], tid=45)
+        staged = stage_tuples(tuples_from_texts(
+            [[str(i), str(i * 1000)] for i in range(300)]), 2)
+        cases = (
+            ("host", dict(device_min_rows=1 << 30, host_min_rows=1,
+                          mesh=None)),
+            ("device", dict(device_min_rows=0, mesh=None)),
+            ("mesh", dict(device_min_rows=0, mesh=decode_mesh(),
+                          mesh_min_rows=0)),
+        )
+        for name, kw in cases:
+            dec = DeviceDecoder(schema, **kw)
+            first = dec.decode(staged)
+            keys = list(dec._fn_cache)
+            assert [k[-1] for k in keys] == [name == "host"]
+            assert [k[3] is not None for k in keys] == [name == "mesh"]
+            # the program may have been in memory already (layouts are
+            # shared across the suite): evict, decode again so THIS
+            # call compiles and persists, then evict and reload
+            _evict_keys(keys)
+            dec.decode(staged)
+            _evict_keys(keys)
+            c0 = registry.get_counter(ETL_PROGRAMS_COMPILED_TOTAL)
+            h0 = registry.get_counter(ETL_COMPILE_CACHE_HITS_TOTAL,
+                                      {"layer": "disk"})
+            again = DeviceDecoder(schema, **kw).decode(staged)
+            assert registry.get_counter(ETL_PROGRAMS_COMPILED_TOTAL) == c0, \
+                f"{name}: reload fell back to a rebuild"
+            assert registry.get_counter(ETL_COMPILE_CACHE_HITS_TOTAL,
+                                        {"layer": "disk"}) == h0 + 1, name
+            assert_batches_identical(first, again)
+
     def test_corrupt_file_degrades_to_rebuild(self, tmp_path):
         schema = make_schema([Oid.INT8, Oid.DATE], tid=42)
         b1, dec = _decode_once(schema, tmp_path)

@@ -388,7 +388,7 @@ class TestAutotuneModel:
     def test_slow_link_keeps_default(self):
         from etl_tpu.ops.autotune import DeviceCostModel
 
-        # tunnel-class link: 40MB/s, 50B/row → 1.25µs/row link vs
+        # slow link: 40MB/s, 50B/row → 1.25µs/row link vs
         # 0.5µs/row host → the device never wins on throughput;
         # routing keeps the static default
         m = DeviceCostModel(fixed_s=0.050, bytes_per_s=40e6,
