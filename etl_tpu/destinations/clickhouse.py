@@ -37,6 +37,9 @@ from ..models.schema import (ReplicatedTableSchema, SchemaDiff, TableId,
                              TableName)
 from ..models.table_row import ColumnarBatch
 from ..analysis.annotations import transactional_commit
+from ..telemetry import spans
+from ..telemetry.metrics import (ETL_CLICKHOUSE_RENDER_SECONDS,
+                                 ETL_CLICKHOUSE_REQUEST_SECONDS)
 from .base import CommitRange, Destination, WriteAck
 from .base import expand_batch_events
 from .util import (CDC_DELETE, CDC_UPSERT, CHANGE_SEQUENCE_COLUMN,
@@ -379,6 +382,15 @@ def render_batch_tsv_fast(schema: ReplicatedTableSchema, batch,
     `change_type_batch` S6 array; `seq_buf` the (n, 50) uint8
     `sequence_number_buffer`. Returns (body, used_device_buffers).
     @hot_loop: the ClickHouse egress hot path (etl-lint rule 13)."""
+    with spans.span("ch.render", ETL_CLICKHOUSE_RENDER_SECONDS,
+                    rows=batch.num_rows):
+        return _render_batch_tsv_fast(schema, batch, change_types, seq_buf,
+                                      egress)
+
+
+def _render_batch_tsv_fast(schema: ReplicatedTableSchema, batch,
+                           change_types, seq_buf,
+                           egress) -> "tuple[bytes, bool]":
     from ..ops import egress as eg
 
     n = batch.num_rows
@@ -469,7 +481,10 @@ class ClickHouseDestination(Destination):
                                               text[:300])
                 return text
 
-        return await with_retries(attempt, self.retry)
+        # request written -> response read, retries included
+        with spans.span("ch.request", ETL_CLICKHOUSE_REQUEST_SECONDS,
+                        bytes=len(body)):
+            return await with_retries(attempt, self.retry)
 
     # -- Destination ------------------------------------------------------------
 

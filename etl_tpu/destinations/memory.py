@@ -20,6 +20,8 @@ from ..models.errors import ErrorKind, EtlError
 from ..models.event import Event
 from ..models.schema import ReplicatedTableSchema, TableId
 from ..models.table_row import ColumnarBatch, TableRow
+from ..telemetry.metrics import (ETL_EXACTLY_ONCE_DEDUP_ROWS_TOTAL,
+                                 registry)
 from .base import (CommitRange, Destination, WriteAck, event_coordinate,
                    expand_batch_events)
 from .util import TaskSet
@@ -111,6 +113,8 @@ class TransactionalMemoryDestination(MemoryDestination):
                 key = self._row_key(e)
                 if key is not None and key in self.replayed_keys:
                     self.replay_skipped_rows += 1
+                    registry.counter_inc(ETL_EXACTLY_ONCE_DEDUP_ROWS_TOTAL,
+                                         labels={"mode": "replay"})
                     continue
                 if key is not None:
                     self.replayed_keys.add(key)
@@ -121,6 +125,8 @@ class TransactionalMemoryDestination(MemoryDestination):
                 coord = event_coordinate(e)
                 if coord is not None and coord <= self.high_water:
                     self.dedup_skipped_rows += 1
+                    registry.counter_inc(ETL_EXACTLY_ONCE_DEDUP_ROWS_TOTAL,
+                                         labels={"mode": "stream"})
                     continue
                 kept.append(e)
         # data + coordinate range land in ONE synchronous step — no await

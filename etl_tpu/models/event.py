@@ -164,7 +164,7 @@ class DecodedBatchEvent:
     __slots__ = ("start_lsn", "commit_lsn", "schema", "change_types",
                  "commit_lsns", "tx_ordinals", "old_rows", "old_is_key",
                  "delete_is_key", "_batch", "_pending", "_old_batch",
-                 "_old_pending")
+                 "_old_pending", "batch_id")
 
     def __init__(self, start_lsn: Lsn, commit_lsn: Lsn,
                  schema: ReplicatedTableSchema, *,
@@ -174,9 +174,12 @@ class DecodedBatchEvent:
                  old_batch: ColumnarBatch | None = None, old_pending=None,
                  old_rows: np.ndarray | None = None,
                  old_is_key: np.ndarray | None = None,
-                 delete_is_key: np.ndarray | None = None):
+                 delete_is_key: np.ndarray | None = None,
+                 batch_id: int = 0):
         if batch is None and pending is None:
             raise ValueError("DecodedBatchEvent needs batch or pending")
+        # telemetry/spans.py: the sealed run this event was decoded from
+        self.batch_id = batch_id
         self.start_lsn = start_lsn
         self.commit_lsn = commit_lsn
         self.schema = schema

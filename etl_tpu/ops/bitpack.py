@@ -307,24 +307,31 @@ def parse_and_pack(bmat, lengths, specs, nibble: bool,
     ref_cols = frozenset(pred.referenced_indices) if pred is not None \
         else frozenset()
     w_off = 0
+    # one named scope per stage: each fusion's metadata in the device
+    # trace then says which stage (and which column kind's parser) it is
     for j, (col_idx, kind, width, _bw) in enumerate(specs):
-        if nibble:
-            packed = bmat[:, w_off // 2 : (w_off + width) // 2]
-            b = parsers.unpack_nibbles(packed, width)
-        else:
-            b = bmat[:, w_off : w_off + width].astype(jnp.int32)
+        with jax.named_scope("gather"):
+            if nibble:
+                packed = bmat[:, w_off // 2 : (w_off + width) // 2]
+                b = parsers.unpack_nibbles(packed, width)
+            else:
+                b = bmat[:, w_off : w_off + width].astype(jnp.int32)
         w_off += width
-        comp, ok = parsers.parse_column(kind, b, lengths[:, j])
+        with jax.named_scope(f"parse_{kind.name.lower()}"):
+            comp, ok = parsers.parse_column(kind, b, lengths[:, j])
         columns.append((ok, comp))
         if col_idx in ref_cols:
             colmap[col_idx] = (comp, ok, lengths[:, j] == 0)
         if n_shards is not None:
             col_ok = ok | (lengths[:, j] == 0)
             row_ok = col_ok if row_ok is None else (row_ok & col_ok)
-    words = pack_device(layout, columns)
+    with jax.named_scope("bitpack"):
+        words = pack_device(layout, columns)
     if pred is not None:
-        keep = pred.device_keep(colmap, row_flags.astype(jnp.int32))
-        words_c, mask, counts = compact_packed(words, keep, n_shards or 1)
+        with jax.named_scope("compact"):
+            keep = pred.device_keep(colmap, row_flags.astype(jnp.int32))
+            words_c, mask, counts = compact_packed(words, keep,
+                                                   n_shards or 1)
         if n_shards is None:
             return words_c, mask, counts
     if n_shards is None:

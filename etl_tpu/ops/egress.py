@@ -388,7 +388,13 @@ def build_egress_program(pspecs: tuple, plan: EgressPlan):
         return (jnp.concatenate(bufs, axis=1),
                 jnp.stack(lens, axis=1).astype(jnp.int32))
 
-    return fn
+    def etl_egress(words):
+        import jax
+
+        with jax.named_scope("render"):
+            return fn(words)
+
+    return etl_egress
 
 
 def build_egress_fn(pspecs: tuple, plan: EgressPlan, mesh=None):

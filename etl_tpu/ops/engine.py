@@ -182,12 +182,21 @@ def build_device_program(specs: tuple[tuple[int, CellKind, int, int], ...],
                                   nibble, n_shards=n_shards, pred=pred,
                                   row_flags=row_flags)
 
-        return fn
+        return name_program(fn, "etl_decode_filter")
 
     def fn(bmat, lengths):
         return parse_and_pack(bmat, lengths.astype(jnp.int32), specs, nibble,
                               n_shards=n_shards)
 
+    return name_program(fn, "etl_decode")
+
+
+def name_program(fn: Callable, name: str) -> Callable:
+    """Name a program body before `jax.jit` sees it: the device trace's
+    module line then reads `jit_<name>` instead of `jit_fn`, so decode,
+    filter and egress device time can be told apart (no compiled code
+    changes; persistent-cache keys do, once)."""
+    fn.__name__ = fn.__qualname__ = name
     return fn
 
 
@@ -1398,13 +1407,15 @@ class DeviceDecoder:
     def _complete(self, staged: StagedBatch, specs: tuple,
                   packed, bad_rows=None,
                   meta: "_PackedInputs | None" = None) -> ColumnarBatch:
-        import time as _time
+        from ..telemetry import spans
+        from ..telemetry.metrics import (ETL_DECODE_EGRESS_FETCH_SECONDS,
+                                         ETL_DECODE_RESULT_WAIT_SECONDS,
+                                         ETL_DECODE_UNPACK_SECONDS,
+                                         ETL_DEVICE_DECODE_ROWS_TOTAL,
+                                         registry)
 
-        from ..telemetry.metrics import (ETL_DEVICE_DECODE_ROWS_TOTAL,
-                                         ETL_DEVICE_DECODE_SECONDS, registry)
-
-        _t0 = _time.perf_counter()
         n = staged.n_rows
+        ids = {"batch_id": staged.batch_id, "rows": n}
         if self._telemetry:
             # n = staged.n_rows: bucket- and mesh-padding tail rows are
             # excluded from every error/telemetry counter by construction
@@ -1421,12 +1432,20 @@ class DeviceDecoder:
                 # fallback set still comes from the unpacked ok bits, so
                 # sharded and single-device decodes stay byte-identical.
                 packed, shard_bad = packed
-            packed_np = np.asarray(packed) if packed is not None else None
+            packed_np = None
+            if packed is not None:
+                # the fetch stage's first part: the device (or host-XLA)
+                # program finishing and its packed words landing
+                with spans.span("decode.result_wait",
+                                ETL_DECODE_RESULT_WAIT_SECONDS, **ids):
+                    packed_np = np.asarray(packed)
             if shard_bad is not None and self._telemetry:
                 self._shard_health(shard_bad)
-            batch, fixups = self._assemble(
-                staged, specs, packed_np, bad_rows,
-                plan=meta.plan if meta is not None else None)
+            with spans.span("decode.unpack", ETL_DECODE_UNPACK_SECONDS,
+                            **ids):
+                batch, fixups = self._assemble(
+                    staged, specs, packed_np, bad_rows,
+                    plan=meta.plan if meta is not None else None)
             fetched = packed_np.nbytes if packed_np is not None else 0.0
             host_rf = self._host_filter_for(staged)
             if meta is not None and meta.egress is not None \
@@ -1438,8 +1457,10 @@ class DeviceDecoder:
                 from . import egress as egress_mod
 
                 try:
-                    batch.device_egress = egress_mod.materialize(
-                        meta.egress, meta.plan, self._dense, n, fixups)
+                    with spans.span("decode.egress_fetch",
+                                    ETL_DECODE_EGRESS_FETCH_SECONDS, **ids):
+                        batch.device_egress = egress_mod.materialize(
+                            meta.egress, meta.plan, self._dense, n, fixups)
                 except Exception:
                     import logging
 
@@ -1462,11 +1483,6 @@ class DeviceDecoder:
 
                 registry.counter_inc(ETL_DECODE_FETCHED_BYTES_TOTAL,
                                      float(fetched))
-        # completion time (fetch wait + unpack + combines + object cols);
-        # dispatch/transfer overlap is deliberately excluded
-        if self._telemetry:
-            registry.histogram_observe(ETL_DEVICE_DECODE_SECONDS,
-                                       _time.perf_counter() - _t0)
         return batch
 
     def _complete_filtered(self, staged: StagedBatch, specs: tuple,
@@ -1482,10 +1498,25 @@ class DeviceDecoder:
         force-kept and fixed-up survivors get one exact host
         re-evaluation so the final batch is byte-identical to the host
         oracle."""
+        from ..telemetry import spans
+        from ..telemetry.metrics import (ETL_DECODE_RESULT_WAIT_SECONDS,
+                                         ETL_DECODE_UNPACK_SECONDS)
+
+        ids = {"batch_id": staged.batch_id, "rows": staged.n_rows}
+        with spans.span("decode.result_wait", ETL_DECODE_RESULT_WAIT_SECONDS,
+                        **ids):
+            survivors, words_np, fetched = self._fetch_filtered(packed,
+                                                                meta)
+        with spans.span("decode.unpack", ETL_DECODE_UNPACK_SECONDS, **ids):
+            return self._assemble_filtered(staged, specs, bad_rows, meta,
+                                           survivors, words_np, fetched)
+
+    def _fetch_filtered(self, packed, meta: "_PackedInputs") -> tuple:
+        """The fetches of a fused-filter dispatch: (survivor row indices,
+        their compacted words, bytes fetched)."""
         from .bitpack import unpack_keep_mask
         from .staging import slice_rows
 
-        pred = self._device_filter_for(staged)
         mesh_shards = self.mesh.size if meta.use_mesh else None
         if mesh_shards is not None:
             words_d, mask_d, counts_d, shard_bad_d = packed
@@ -1522,6 +1553,13 @@ class DeviceDecoder:
             words_np = words_full[:, sel]
             S = len(sel)
         assert len(survivors) == S, (len(survivors), S)
+        return survivors, words_np, fetched
+
+    def _assemble_filtered(self, staged: StagedBatch, specs: tuple,
+                           bad_rows, meta: "_PackedInputs", survivors,
+                           words_np, fetched: float) -> ColumnarBatch:
+        pred = self._device_filter_for(staged)
+        S = len(survivors)
         cstaged = staged.gather_rows(survivors)
         cbad = bad_rows[survivors] if bad_rows is not None else None
         batch, fixup_rows = self._assemble(cstaged, specs, words_np, cbad)

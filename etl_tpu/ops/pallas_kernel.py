@@ -170,6 +170,9 @@ def build_pallas_program(specs: tuple[tuple[int, CellKind, int, int], ...],
             interpret=interpret,
         )(bmat_t, lengths_t, row_flags.reshape(1, R))
         # compaction epilogue: in-block prefix-sum scatter of survivors
-        return compact_packed(words, keep[0] > 0, 1)
+        with jax.named_scope("compact"):
+            return compact_packed(words, keep[0] > 0, 1)
 
+    # the device trace's module line reads jit_etl_decode_pallas
+    fn.__name__ = fn.__qualname__ = "etl_decode_pallas"
     return fn

@@ -439,10 +439,11 @@ class PipelinedDecode:
     what keeps the window from stalling the worker."""
 
     __slots__ = ("_pipe", "_future", "_done", "_exc", "_windowed",
-                 "_demanded", "_admitted")
+                 "_demanded", "_admitted", "batch_id")
 
-    def __init__(self, pipe: "DecodePipeline"):
+    def __init__(self, pipe: "DecodePipeline", batch_id: int = 0):
         self._pipe = pipe
+        self.batch_id = batch_id  # the staged batch's (telemetry/spans.py)
         self._future: Future = Future()
         self._done = None
         self._exc: BaseException | None = None
@@ -559,7 +560,7 @@ class DecodePipeline:
         caller's own batching (flush windows / COPY chunk thresholds)."""
         if self._closed:
             raise RuntimeError("decode pipeline is closed")
-        handle = PipelinedDecode(self)
+        handle = PipelinedDecode(self, staged.batch_id)
         self._submitted += 1
         with self._lock:
             self._undispatched.append(handle)
@@ -644,16 +645,21 @@ class DecodePipeline:
         once per batch on the dispatch path — fetches belong to _fetch."""
         from ..chaos import failpoints
         from ..models.errors import ErrorKind, EtlError
+        from ..telemetry import spans
         from ..telemetry.metrics import (
             ETL_DECODE_DEVICE_OOM_FALLBACKS_TOTAL,
             ETL_DECODE_DISPATCH_SECONDS, ETL_DECODE_PACK_SECONDS,
-            ETL_DECODE_PIPELINE_IN_FLIGHT, registry)
+            ETL_DECODE_PIPELINE_IN_FLIGHT, ETL_DECODE_WINDOW_WAIT_SECONDS,
+            registry)
         from .engine import _PendingDecode
 
         # chaos site: fires once per submitted batch at pack-stage entry
         # (before routing, so small oracle-routed batches hit it too)
         failpoints.fail_point(failpoints.PIPELINE_PACK)
-        mode, specs = decoder._route(staged)
+        ids = {"batch_id": staged.batch_id, "rows": staged.n_rows}
+        with spans.span("decode.route", **ids):
+            mode, specs = decoder._route(staged)
+        ids["mode"] = mode
         if mode != "oracle":
             # simulated (or, one day, real) device allocation failure:
             # degrade THIS batch to the host oracle instead of failing
@@ -678,8 +684,13 @@ class DecodePipeline:
         # hasn't dispatched yet (out-of-order draining) or when close()
         # fires with abandoned slots outstanding: the window overshoots
         # instead of deadlocking against its own consumer.
+        waiting_since_ns = spans.now_ns()
         self.window.acquire(
             bypass=lambda: self._closed or self._demand_waiting())
+        admitted_ns = spans.now_ns()
+        spans.record("decode.window_wait", waiting_since_ns, admitted_ns,
+                     ETL_DECODE_WINDOW_WAIT_SECONDS,
+                     batch_id=staged.batch_id)
         handle._windowed = True
         if self._admission is not None and not self._admission.closed:
             # shared-capacity seat AFTER the pipeline's own window: a
@@ -689,15 +700,23 @@ class DecodePipeline:
             # overshoots rather than deadlocking the consumer.
             self._admission.acquire(
                 bypass=lambda: self._closed or self._demand_waiting())
+            # its seconds: etl_decode_admission_wait_seconds, which the
+            # scheduler observes per tenant
+            spans.record("decode.admission_wait", admitted_ns,
+                         spans.now_ns(), batch_id=staged.batch_id)
             handle._admitted = True
         host = mode == "host"
         arena = self.pool.lease()
         t0 = time.perf_counter()
         try:
-            packed = decoder._pack_stage(staged, specs, host, arena=arena)
+            with spans.span("decode.pack", **ids):
+                packed = decoder._pack_stage(staged, specs, host,
+                                             arena=arena)
             t1 = time.perf_counter()
             failpoints.fail_point(failpoints.PIPELINE_DISPATCH)
-            packed_dev = decoder._dispatch_stage(staged, specs, packed, host)
+            with spans.span("decode.dispatch", **ids):
+                packed_dev = decoder._dispatch_stage(staged, specs, packed,
+                                                     host)
             t2 = time.perf_counter()
         except BaseException:
             arena.release()
@@ -754,12 +773,22 @@ class DecodePipeline:
         """Stage 3: wait out pack/dispatch if still running, fetch and
         complete the batch, then return the arena and window slot."""
         from ..chaos import failpoints
+        from ..telemetry import spans
         from ..telemetry.metrics import (ETL_DECODE_FETCH_SECONDS,
+                                         ETL_DECODE_HANDOFF_WAIT_SECONDS,
                                          ETL_DECODE_PIPELINE_IN_FLIGHT,
                                          registry)
 
         handle._demanded = True  # window liveness valve, see _process
-        value = handle._future.result()
+        # the consumer blocked on the worker thread: route, window and
+        # admission waits, pack and dispatch still to finish
+        waiting_since_ns = spans.now_ns()
+        try:
+            value = handle._future.result()
+        finally:
+            spans.record("decode.handoff_wait", waiting_since_ns,
+                         spans.now_ns(), ETL_DECODE_HANDOFF_WAIT_SECONDS,
+                         batch_id=handle.batch_id)
         handle._demanded = False
         if len(value) == 2:  # oracle route: (pending, None)
             pending, _ = value

@@ -86,6 +86,10 @@ class StagedBatch:
     # row-filter transforms are the PG15 walsender's job, docs/decode-
     # pipeline.md). Copy chunks and insert runs keep the default.
     allow_row_filter: bool = True
+    # telemetry/spans.py identity of the sealed run / copy chunk these
+    # rows came from, carried by every decode span of the batch (0: a
+    # batch nobody numbered, e.g. a direct decode() call)
+    batch_id: int = 0
     _maxlens: np.ndarray | None = field(default=None, repr=False,
                                         compare=False)
 
@@ -127,7 +131,7 @@ class StagedBatch:
             self.data, self.offsets[rows], self.lengths[rows],
             self.nulls[rows], self.toast[rows], len(rows),
             cpu_fallback_rows=fb, copy_escapes=self.copy_escapes,
-            allow_row_filter=False)
+            allow_row_filter=False, batch_id=self.batch_id)
 
 
 #: fetch-slice granularity: survivor counts bucket to multiples of

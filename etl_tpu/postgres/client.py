@@ -20,6 +20,10 @@ from typing import AsyncIterator
 from ..config.pipeline import PgConnectionConfig
 from ..models.errors import ErrorKind, EtlError
 from ..models.lsn import Lsn
+from ..telemetry import spans
+from ..telemetry.metrics import (ETL_INTAKE_BYTES_TOTAL,
+                                 ETL_INTAKE_DRAIN_SECONDS,
+                                 ETL_INTAKE_FRAMES_TOTAL, registry)
 from ..models.schema import (ColumnMask, ColumnSchema, ReplicatedTableSchema,
                              TableId, TableName, TableSchema)
 from .codec import pgoutput
@@ -91,31 +95,42 @@ class _WireReplicationStream(ReplicationStream):
         buf = getattr(reader, "_buffer", None)
         if buf is None or self._closed:
             return out
-        while len(out) < max_n and len(buf) >= 5:
-            length = int.from_bytes(buf[1:5], "big")
-            if len(buf) < 1 + length:
-                break
-            tag = buf[0:1]
-            payload = bytes(buf[5 : 1 + length])
-            del buf[: 1 + length]
-            if tag == b"d":
-                out.append(pgoutput.decode_replication_frame(payload))
-            elif tag == b"E":
-                # do NOT raise here: frames already parsed in this pass
-                # were deleted from the reader buffer and would be lost,
-                # forcing a restart-from-durable re-delivery. Hand the
-                # caller what it has; the stored error surfaces on the
-                # next drain/iteration.
-                from .wire import PgServerError, _parse_error_fields
+        # one span per non-empty drain (never per frame): the per-frame
+        # parse, apart from the segmentation that follows it in
+        # ReplicationStream.drain_spans
+        with spans.span("intake.drain", ETL_INTAKE_DRAIN_SECONDS) as sp:
+            buffered = len(buf)
+            while len(out) < max_n and len(buf) >= 5:
+                length = int.from_bytes(buf[1:5], "big")
+                if len(buf) < 1 + length:
+                    break
+                tag = buf[0:1]
+                payload = bytes(buf[5 : 1 + length])
+                del buf[: 1 + length]
+                if tag == b"d":
+                    out.append(pgoutput.decode_replication_frame(payload))
+                elif tag == b"E":
+                    # do NOT raise here: frames already parsed in this
+                    # pass were deleted from the reader buffer and would
+                    # be lost, forcing a restart-from-durable
+                    # re-delivery. Hand the caller what it has; the
+                    # stored error surfaces on the next drain/iteration.
+                    from .wire import PgServerError, _parse_error_fields
 
-                self._pending_error = PgServerError(
-                    _parse_error_fields(payload))
-                break
-            elif tag == b"Z":
-                self._closed = True
-                break
-            # 'c'/'C' and other tags: skip, same as copy_both_read
-        getattr(reader, "_maybe_resume_transport", lambda: None)()
+                    self._pending_error = PgServerError(
+                        _parse_error_fields(payload))
+                    break
+                elif tag == b"Z":
+                    self._closed = True
+                    break
+                # 'c'/'C' and other tags: skip, same as copy_both_read
+            getattr(reader, "_maybe_resume_transport", lambda: None)()
+            if out:
+                registry.counter_inc(ETL_INTAKE_FRAMES_TOTAL, len(out))
+                registry.counter_inc(ETL_INTAKE_BYTES_TOTAL,
+                                     buffered - len(buf))
+            else:
+                sp.drop()
         return out
 
     async def send_status_update(self, written: Lsn, flushed: Lsn,

@@ -22,6 +22,8 @@ from typing import AsyncIterator
 
 from ..models.lsn import Lsn
 from ..models.schema import ReplicatedTableSchema, TableId
+from ..telemetry import spans
+from ..telemetry.metrics import ETL_INTAKE_SEGMENT_SECONDS
 from .codec.pgoutput import ReplicationFrame
 
 
@@ -96,11 +98,16 @@ class ReplicationStream(abc.ABC):
         segment `drain_buffered` output host-side; implementations that
         can segment closer to the wire (or skip per-frame objects
         entirely, like the in-memory fake) override this."""
-        from .codec.pgoutput import XLogData
-
         frames = self.drain_buffered(max_n)
         if not frames:
             return frames
+        with spans.span("intake.segment", ETL_INTAKE_SEGMENT_SECONDS):
+            return self._segment(frames)
+
+    @staticmethod
+    def _segment(frames: list) -> list:
+        from .codec.pgoutput import XLogData
+
         out: list = []
         i, n = 0, len(frames)
         while i < n:

@@ -48,6 +48,7 @@ from typing import Awaitable, Callable
 
 from ..destinations.base import WriteAck
 from ..models.errors import ErrorKind, EtlError
+from ..telemetry import spans
 from ..telemetry.metrics import (ETL_DESTINATION_ACK_BUSY_SECONDS_TOTAL,
                                  ETL_DESTINATION_ACK_IN_FLIGHT,
                                  ETL_DESTINATION_ACK_LATENCY_SECONDS,
@@ -65,11 +66,15 @@ class AckEntry:
     abandon the pending decodes of entries that will never deliver)."""
 
     __slots__ = ("task", "commit_end_lsn", "commit_range", "n_events",
-                 "nbytes", "dispatched_at", "payload")
+                 "nbytes", "dispatched_at", "payload", "flush_id",
+                 "durable_ns")
 
     def __init__(self, task: asyncio.Task, commit_end_lsn, n_events: int,
                  nbytes: int, dispatched_at: float, payload=None,
-                 commit_range=None):
+                 commit_range=None, flush_id: int = 0):
+        self.flush_id = flush_id  # telemetry/spans.py: flush.* records
+        # perf_counter_ns when the write became durable (set by its task)
+        self.durable_ns = 0
         self.task = task
         self.commit_end_lsn = commit_end_lsn
         self.commit_range = commit_range
@@ -185,7 +190,8 @@ class AckWindow:
                  *, commit_end_lsn=None, n_events: int = 0,
                  nbytes: int = 0,
                  on_durable: "Callable[[], None] | None" = None,
-                 payload=None, commit_range=None) -> AckEntry:
+                 payload=None, commit_range=None,
+                 flush_id: int = 0) -> AckEntry:
         """Start one write: `submit()` performs the destination call and
         returns its ack (None for an event-less commit-boundary flush).
         The window serializes submissions in dispatch order and owns the
@@ -201,6 +207,7 @@ class AckWindow:
         submitted: "asyncio.Future[bool]" = loop.create_future()
         self._submit_tail = submitted
         t0 = time.monotonic()
+        t0_ns = spans.now_ns()
 
         async def run() -> None:
             ack = None
@@ -223,13 +230,18 @@ class AckWindow:
                 registry.histogram_observe(
                     ETL_DESTINATION_ACK_LATENCY_SECONDS,
                     time.monotonic() - t0, labels=self._labels)
+            # dispatch -> durable, submission-chain wait included; its
+            # seconds are etl_destination_ack_latency_seconds above
+            entry.durable_ns = spans.now_ns()
+            spans.record("flush.write", t0_ns, entry.durable_ns,
+                         flush_id=flush_id)
             if on_durable is not None:
                 on_durable()
 
         self._tick()
         entry = AckEntry(asyncio.ensure_future(run()), commit_end_lsn,
                          n_events, nbytes, t0, payload,
-                         commit_range=commit_range)
+                         commit_range=commit_range, flush_id=flush_id)
         self._entries.append(entry)
         self._bytes += nbytes
         self._publish()

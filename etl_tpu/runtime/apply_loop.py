@@ -50,11 +50,19 @@ from ..postgres.source import FrameSpan, ReplicationStream
 from ..store.base import PipelineStore
 from ..analysis.annotations import flush_path
 from ..destinations.base import Destination
+from ..telemetry import spans
 from ..telemetry.egress import record_egress
-from ..telemetry.metrics import (ETL_APPLY_LOOP_BATCHES_TOTAL,
+from ..telemetry.metrics import (ETL_APPLY_ACK_TO_STATUS_SECONDS,
+                                 ETL_APPLY_DISPATCH_BLOCKED_SECONDS_TOTAL,
+                                 ETL_APPLY_FLUSH_FILL_SECONDS,
+                                 ETL_APPLY_FRAME_WALK_SECONDS,
+                                 ETL_APPLY_LOOP_BATCHES_TOTAL,
                                  ETL_APPLY_LOOP_EVENTS_TOTAL,
                                  ETL_APPLY_LOOP_FLUSH_LAG_BYTES,
                                  ETL_APPLY_LOOP_RECEIVED_LAG_BYTES,
+                                 ETL_APPLY_PROGRESS_STORE_SECONDS,
+                                 ETL_APPLY_SELECT_WAIT_SECONDS,
+                                 ETL_APPLY_STATUS_UPDATE_SECONDS,
                                  ETL_SHARD_DELIVERED_EVENTS,
                                  ETL_SLOT_LAG_BYTES,
                                  ETL_TRANSACTION_SIZE_BYTES,
@@ -199,6 +207,10 @@ class ApplyLoop:
                                           destination=destination,
                                           config=config)
         self._batch_deadline: float | None = None
+        # perf_counter_ns at which a due flush was first held back by the
+        # write window or the breaker (span `flush.blocked`); None while
+        # nothing is held
+        self._blocked_since_ns: int | None = None
         # True while the CURRENT drain keeps coming back full: flush
         # pacing defers to mega-batching only during a live backlog
         # (the moment the producer pauses, normal deadlines resume)
@@ -311,9 +323,12 @@ class ApplyLoop:
                                   keepalive_s)
                 else:
                     timeout = keepalive_s
+                waiting_since_ns = spans.now_ns()
                 done, _ = await asyncio.wait(
                     waits, timeout=timeout,
                     return_when=asyncio.FIRST_COMPLETED)
+                spans.record("loop.select_wait", waiting_since_ns,
+                             spans.now_ns(), ETL_APPLY_SELECT_WAIT_SECONDS)
                 if self._hb is not None:
                     # one beat per wakeup (≤ keepalive cadence when idle):
                     # cheap enough for the hot path, fresh enough for the
@@ -463,6 +478,10 @@ class ApplyLoop:
     # -- frame handling ---------------------------------------------------------
 
     async def _handle_frames(self, items: list) -> ExitIntent | None:
+        with spans.span("apply.frame_walk", ETL_APPLY_FRAME_WALK_SECONDS):
+            return await self._walk_frames(items)
+
+    async def _walk_frames(self, items: list) -> ExitIntent | None:
         """Bulk path for a drained window of FrameSpans + control frames
         (stream.drain_spans). Spans — the overwhelming majority of CDC
         traffic — append into the assembler with per-SPAN bookkeeping
@@ -740,8 +759,15 @@ class ApplyLoop:
             if not self._dispatch_one(force and not dispatched):
                 return
             dispatched = True
+        if self._blocked_since_ns is None and self._flush_due(
+                force and not dispatched, self._flush_threshold()):
+            # due, and held by the window or the breaker: `flush.blocked`
+            # runs from here to the dispatch
+            self._blocked_since_ns = spans.now_ns()
 
-    def _dispatch_one(self, force: bool) -> bool:
+    def _flush_due(self, force: bool, threshold: int) -> bool:
+        """Is there a flush to dispatch: a forced one (deadline, commit
+        fast path, catchup drain) or a full batch?"""
         if len(self.assembler) == 0:
             # TPU engine: commits are not assembler events, so a commit
             # window whose owned-row set is EMPTY (unowned tables,
@@ -751,15 +777,17 @@ class ApplyLoop:
             # retention grows. Dispatch an event-less flush through the
             # normal write-window machinery (one per fill window,
             # amortized like any other deadline flush).
-            if not (force and self.state.batch_commit_end is not None):
-                return False
+            return force and self.state.batch_commit_end is not None
         # budget-aware threshold: under many active streams the per-stream
         # share shrinks below the static cap (batch_budget.rs:72-96) —
         # flushes happen mid-transaction with the commit LSN carried
         # separately (apply.rs:1932-1945), so splitting huge transactions
         # is safe for durability accounting
+        return force or self.assembler.size_bytes >= threshold
+
+    def _dispatch_one(self, force: bool) -> bool:
         threshold = self._flush_threshold()
-        if not force and self.assembler.size_bytes < threshold:
+        if not self._flush_due(force, threshold):
             return False
         # size-bounded flush: flush a WAL-ordered prefix of ≤ threshold
         # bytes — a drained backlog then dispatches as a sequence of
@@ -774,6 +802,15 @@ class ApplyLoop:
         # at; `remaining` is the highest boundary still awaiting a later
         # flush.
         before_bytes = self.assembler.size_bytes
+        now_ns = spans.now_ns()
+        flush_id = spans.next_flush_id()
+        if self._blocked_since_ns is not None:
+            registry.counter_inc(ETL_APPLY_DISPATCH_BLOCKED_SECONDS_TOTAL,
+                                 (now_ns - self._blocked_since_ns) * 1e-9)
+            spans.record("flush.blocked", self._blocked_since_ns, now_ns,
+                         flush_id=flush_id)
+            self._blocked_since_ns = None
+        filled_since_ns = self.assembler.filled_since_ns or now_ns
         events, covered, remaining = \
             self.assembler.flush_bounded(max_bytes=threshold)
         batch_bytes = before_bytes - self.assembler.size_bytes
@@ -838,7 +875,19 @@ class ApplyLoop:
         self._ack_window.dispatch(
             submit, commit_end_lsn=commit_end, n_events=len(events),
             nbytes=batch_bytes, on_durable=on_durable if events else None,
-            payload=events, commit_range=commit_range)
+            payload=events, commit_range=commit_range, flush_id=flush_id)
+        # first row of the flush pushed -> dispatched; one record per
+        # sealed batch the flush consumed names that batch as its parent
+        # (the seal may lie inside this interval: flush_bounded seals the
+        # open run)
+        dispatched_ns = spans.now_ns()
+        registry.histogram_observe(ETL_APPLY_FLUSH_FILL_SECONDS,
+                                   (dispatched_ns - filled_since_ns) * 1e-9)
+        batch_ids = [ev.batch_id for ev in events
+                     if getattr(ev, "batch_id", 0)]
+        for batch_id in batch_ids or (0,):
+            spans.record("flush.fill", filled_since_ns, dispatched_ns,
+                         flush_id=flush_id, parent=batch_id)
         return True
 
     @flush_path
@@ -851,6 +900,7 @@ class ApplyLoop:
         at most the window size)."""
         done, failure = self._ack_window.pop_ready()
         advanced = False
+        flush_id = done[-1].flush_id if done else 0
         for entry in done:
             self._delivered_events += entry.n_events
             if entry.commit_end_lsn is None:
@@ -860,8 +910,11 @@ class ApplyLoop:
             advanced = True
         if advanced:
             failpoints.fail_point(failpoints.ON_PROGRESS_STORE)
-            await self.store.update_durable_progress(
-                self.ctx.progress_key, self.state.durable_lsn)
+            with spans.span("apply.progress_store",
+                            ETL_APPLY_PROGRESS_STORE_SECONDS,
+                            flush_id=flush_id):
+                await self.store.update_durable_progress(
+                    self.ctx.progress_key, self.state.durable_lsn)
             if failure is None:
                 # NO standby status when a failure was popped: the
                 # failed entry is out of the window, so _is_idle() can
@@ -871,7 +924,15 @@ class ApplyLoop:
                 # (found by the pipeline_pack_fault chaos scenario). The
                 # durable-progress store write above is safe either way:
                 # it only ever names acked commit ends.
-                await self._send_status_update()
+                await self._send_status_update(flush_id)
+                # durable -> the status update that covers it is sent:
+                # with flush.fill and flush.write, the whole of a
+                # transaction's stay in this process
+                sent_ns = spans.now_ns()
+                for entry in done:
+                    spans.record("flush.ack", entry.durable_ns, sent_ns,
+                                 ETL_APPLY_ACK_TO_STATUS_SECONDS,
+                                 flush_id=entry.flush_id)
         if failure is not None:
             raise failure if isinstance(failure, EtlError) else EtlError(
                 ErrorKind.DESTINATION_FAILED, str(failure))
@@ -934,7 +995,12 @@ class ApplyLoop:
         return max(effective, self.state.durable_lsn,
                    self.state.last_status_flush_lsn)
 
-    async def _send_status_update(self) -> None:
+    async def _send_status_update(self, flush_id: int = 0) -> None:
+        with spans.span("apply.status_update",
+                        ETL_APPLY_STATUS_UPDATE_SECONDS, flush_id=flush_id):
+            await self._status_update()
+
+    async def _status_update(self) -> None:
         failpoints.fail_point(failpoints.ON_STATUS_UPDATE)
         registry.gauge_set(ETL_APPLY_LOOP_FLUSH_LAG_BYTES,
                            self.state.received_lsn - self.state.durable_lsn)

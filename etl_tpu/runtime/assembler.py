@@ -31,6 +31,9 @@ from ..ops.pipeline import DecodePipeline
 from ..ops.wal import stage_wal_batch
 from ..postgres.codec import event as event_codec
 from ..postgres.codec import pgoutput
+from ..telemetry import spans
+from ..telemetry.metrics import (ETL_ASSEMBLER_SEAL_SECONDS,
+                                 ETL_ASSEMBLER_SEALED_ROWS_TOTAL, registry)
 
 
 @dataclass
@@ -124,6 +127,11 @@ class EventAssembler:
         # under sustained backlog and resets it when the stream idles
         self.seal_rows = RUN_SEAL_ROWS
         self.size_bytes = 0
+        # perf_counter_ns of the first push since the assembler was last
+        # empty (None while empty): where the apply loop's `flush.fill`
+        # span starts. After a size-bounded flush that leaves a remainder
+        # it is that flush's own time.
+        self.filled_since_ns: int | None = None
         # row (non-control) events in the open window: the apply loop's
         # idle-commit fast flush keys on this — control-only windows
         # (CPU-engine Begin/Commit of unowned-table transactions) must
@@ -138,6 +146,8 @@ class EventAssembler:
 
     def push_control(self, ev: Event, size_hint: int = 64) -> None:
         """Begin/Commit/Relation/Truncate/SchemaChange — barrier events."""
+        if self.filled_since_ns is None:
+            self.filled_since_ns = spans.now_ns()
         self._seal_run()
         self._events.append(ev)
         self._meta.append((size_hint, 0))
@@ -151,6 +161,8 @@ class EventAssembler:
         host-side tuple parsing (the framer parses it on the device staging
         path). Callers guarantee payload[0] is I/U/D. @hot_loop: runs once
         per CDC row — a host transfer here caps stream throughput."""
+        if self.filled_since_ns is None:
+            self.filled_since_ns = spans.now_ns()
         if self._run is None or self._run.table_id != schema.id \
                 or self._run.schema is not schema:
             self._seal_run()
@@ -177,6 +189,8 @@ class EventAssembler:
         extends instead of per-row pushes. Returns the span's payload
         bytes (the caller's tx_bytes accounting needs the same sum).
         @hot_loop: one call per drained span on the saturated path."""
+        if self.filled_since_ns is None:
+            self.filled_since_ns = spans.now_ns()
         if self._run is None or self._run.table_id != schema.id \
                 or self._run.schema is not schema:
             self._seal_run()
@@ -239,6 +253,8 @@ class EventAssembler:
             else:
                 raise EtlError(ErrorKind.REPLICATION_MESSAGE_INVALID,
                                f"not a row message: {type(msg).__name__}")
+            if self.filled_since_ns is None:
+                self.filled_since_ns = spans.now_ns()
             self._events.append(ev)
             self._meta.append((64 + len(payload), 1))
             self.size_bytes += 64 + len(payload)
@@ -275,6 +291,17 @@ class EventAssembler:
             decoder = DeviceDecoder(r.schema, nonblocking_compile=True,
                                     egress=self.egress_encoder)
             self._decoders[r.table_id] = decoder
+        # the batch_id minted here rides the staged batch through every
+        # decode span of the run (telemetry/spans.py)
+        batch_id = spans.next_batch_id()
+        registry.counter_inc(ETL_ASSEMBLER_SEALED_ROWS_TOTAL,
+                             len(r.payloads))
+        with spans.span("assemble.seal", ETL_ASSEMBLER_SEAL_SECONDS,
+                        batch_id=batch_id, rows=len(r.payloads)):
+            self._stage_and_submit(r, decoder, batch_id)
+
+    def _stage_and_submit(self, r: _Run, decoder: DeviceDecoder,
+                          batch_id: int) -> None:
         lens = np.fromiter((len(p) for p in r.payloads), dtype=np.int32,
                            count=len(r.payloads))
         offs = np.zeros(len(r.payloads), dtype=np.int64)
@@ -322,8 +349,10 @@ class EventAssembler:
         wal.staged.allow_row_filter = bool(
             wal.old_staged is None
             and (wal.change_types == ChangeType.INSERT).all())
+        wal.staged.batch_id = batch_id
         if wal.old_staged is not None:
             wal.old_staged.allow_row_filter = False
+            wal.old_staged.batch_id = batch_id
         pending = self._pipeline.submit(decoder, wal.staged)
         old_pending = self._pipeline.submit(decoder, wal.old_staged) \
             if wal.old_staged is not None else None
@@ -335,6 +364,7 @@ class EventAssembler:
             tx_ordinals=np.asarray(r.tx_ordinals, dtype=np.uint64),
             old_pending=old_pending, old_rows=wal.old_rows,
             old_is_key=wal.old_is_key, delete_is_key=wal.delete_is_key,
+            batch_id=batch_id,
         ))
         self._meta.append((64 * len(r.payloads) + sum(map(len, r.payloads)),
                            len(r.payloads)))
@@ -390,6 +420,7 @@ class EventAssembler:
             self._commit_marks = []
             self.size_bytes = 0
             self.row_events = 0
+            self.filled_since_ns = None
             return events, covered, None
         cum = 0
         k = 0
@@ -402,6 +433,7 @@ class EventAssembler:
         self._meta = self._meta[k:]
         self.size_bytes -= cum
         self.row_events = sum(r for _, r in self._meta)
+        self.filled_since_ns = spans.now_ns()
         covered = None
         while self._commit_marks and self._commit_marks[0][0] <= k:
             covered = Lsn(self._commit_marks.pop(0)[1])

@@ -111,6 +111,11 @@ def read_rss_bytes() -> int:
         return 0
 
 
+#: thread ident -> `time.thread_time()` already counted into
+#: etl_loop_thread_cpu_seconds_total (MemoryMonitor._run)
+_LOOP_CPU_SEEN: dict = {}
+
+
 class MemoryMonitor:
     """Periodic RSS sampler with hysteresis; `pressure` is the watch value.
 
@@ -196,9 +201,38 @@ class MemoryMonitor:
         return self.pressure
 
     async def _run(self) -> None:
+        """The sampler's tick, and — because it is the one periodic task
+        every pipeline already runs on the loop thread — the event loop's
+        own two vital signs: how late each wake-up is against its
+        schedule (`etl_event_loop_lag_seconds`: the loop was busy, or the
+        machine took the core) and the loop thread's CPU time
+        (`etl_loop_thread_cpu_seconds_total`: near one CPU-second a
+        second means the apply loop is the limiter)."""
+        from ..telemetry import spans
+        from ..telemetry.metrics import (ETL_EVENT_LOOP_LAG_SECONDS,
+                                         ETL_LOOP_THREAD_CPU_SECONDS_TOTAL,
+                                         registry)
+
         interval = self.config.refresh_interval_ms / 1000
+        thread = threading.get_ident()
+        due = None
         while True:
-            self.sample_once()
+            now = time.perf_counter()
+            if due is not None:
+                registry.histogram_observe(ETL_EVENT_LOOP_LAG_SECONDS,
+                                           max(0.0, now - due))
+            # several monitors may tick on one loop thread (one per
+            # pipeline): each adds only what none has counted yet
+            cpu = time.thread_time()
+            seen = _LOOP_CPU_SEEN.get(thread)
+            if seen is not None:
+                registry.counter_inc(ETL_LOOP_THREAD_CPU_SECONDS_TOTAL,
+                                     cpu - seen)
+            _LOOP_CPU_SEEN[thread] = cpu
+            with spans.span("monitor.tick"):
+                self.sample_once()
+            spans.fold()
+            due = time.perf_counter() + interval
             await asyncio.sleep(interval)
 
     async def wait_until_resumed(self) -> None:

@@ -38,6 +38,7 @@ from dataclasses import dataclass, field
 from typing import Callable
 
 from ..config.pipeline import SupervisionConfig
+from ..telemetry import spans
 from .breaker import BreakerState, CircuitBreaker
 from .health import HealthStateMachine
 from .heartbeat import ComponentPolicy, Heartbeat, HeartbeatRegistry
@@ -130,7 +131,8 @@ class Supervisor:
         interval = self.config.check_interval_s
         while True:
             try:
-                self.sweep_once()
+                with spans.span("supervisor.sweep"):
+                    self.sweep_once()
             except Exception:  # the watchdog must outlive its own bugs; CancelledError is BaseException, passes through
                 logger.exception("supervision sweep failed")
             await asyncio.sleep(interval)

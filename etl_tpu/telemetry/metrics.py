@@ -10,6 +10,7 @@ for the API `/metrics` route and the replicator's endpoint.
 from __future__ import annotations
 
 import threading
+from bisect import bisect_left
 from collections import defaultdict
 from dataclasses import dataclass, field
 
@@ -44,7 +45,6 @@ ETL_TABLES_ERRORED = "etl_tables_errored"
 ETL_DEVICE_DECODE_ROWS_TOTAL = "etl_device_decode_rows_total"
 ETL_DEVICE_DECODE_FALLBACK_ROWS_TOTAL = \
     "etl_device_decode_fallback_rows_total"
-ETL_DEVICE_DECODE_SECONDS = "etl_device_decode_seconds"
 # fused publication row filtering (ops/predicate.py + the fused decode
 # program): rows the predicate compacted out of decode output, the bytes
 # the packed-result fetch actually moved over the device→host link
@@ -70,6 +70,14 @@ ETL_PROCESSED_BYTES_TOTAL = "etl_processed_bytes_total"
 ETL_DECODE_PACK_SECONDS = "etl_decode_pack_seconds"
 ETL_DECODE_DISPATCH_SECONDS = "etl_decode_dispatch_seconds"
 ETL_DECODE_FETCH_SECONDS = "etl_decode_fetch_seconds"
+# the fetch stage's three parts (etl_decode_fetch_seconds stays their
+# envelope) and the two waits nothing timed: the consumer blocked on the
+# pack worker (handoff), the worker blocked on its own in-flight window
+ETL_DECODE_HANDOFF_WAIT_SECONDS = "etl_decode_handoff_wait_seconds"
+ETL_DECODE_WINDOW_WAIT_SECONDS = "etl_decode_window_wait_seconds"
+ETL_DECODE_RESULT_WAIT_SECONDS = "etl_decode_result_wait_seconds"
+ETL_DECODE_UNPACK_SECONDS = "etl_decode_unpack_seconds"
+ETL_DECODE_EGRESS_FETCH_SECONDS = "etl_decode_egress_fetch_seconds"
 ETL_DECODE_PIPELINE_PACK_SECONDS_TOTAL = \
     "etl_decode_pipeline_pack_seconds_total"
 ETL_DECODE_PIPELINE_OVERLAP_SECONDS_TOTAL = \
@@ -266,6 +274,39 @@ ETL_DESTINATION_BREAKER_STATE = "etl_destination_breaker_state"
 ETL_DESTINATION_BREAKER_OPENS_TOTAL = "etl_destination_breaker_opens_total"
 ETL_DESTINATION_OP_TIMEOUTS_TOTAL = "etl_destination_op_timeouts_total"
 
+# the program's own spans (telemetry/spans.py; docs/OPERATIONS.md lists
+# each with its span name): one histogram per stage of the CDC path, the
+# copy path and the ClickHouse write, seconds each. Counters: frames and
+# bytes per non-empty wire drain, rows per sealed run, seconds a due
+# flush was held by the write window or the breaker. The last two come
+# from the memory monitor's tick on the loop thread: how late each
+# wake-up was against its schedule, and the loop thread's own CPU time —
+# together they say whether the apply loop is the limiter.
+ETL_APPLY_SELECT_WAIT_SECONDS = "etl_apply_select_wait_seconds"
+ETL_INTAKE_DRAIN_SECONDS = "etl_intake_drain_seconds"
+ETL_INTAKE_FRAMES_TOTAL = "etl_intake_frames_total"
+ETL_INTAKE_BYTES_TOTAL = "etl_intake_bytes_total"
+ETL_INTAKE_SEGMENT_SECONDS = "etl_intake_segment_seconds"
+ETL_APPLY_FRAME_WALK_SECONDS = "etl_apply_frame_walk_seconds"
+ETL_ASSEMBLER_SEAL_SECONDS = "etl_assembler_seal_seconds"
+ETL_ASSEMBLER_SEALED_ROWS_TOTAL = "etl_assembler_sealed_rows_total"
+ETL_APPLY_DISPATCH_BLOCKED_SECONDS_TOTAL = \
+    "etl_apply_dispatch_blocked_seconds_total"
+ETL_APPLY_FLUSH_FILL_SECONDS = "etl_apply_flush_fill_seconds"
+ETL_APPLY_ACK_TO_STATUS_SECONDS = "etl_apply_ack_to_status_seconds"
+ETL_APPLY_PROGRESS_STORE_SECONDS = "etl_apply_progress_store_seconds"
+ETL_APPLY_STATUS_UPDATE_SECONDS = "etl_apply_status_update_seconds"
+ETL_COPY_READ_WAIT_SECONDS = "etl_copy_read_wait_seconds"
+ETL_COPY_CUT_SECONDS = "etl_copy_cut_seconds"
+ETL_COPY_STAGE_SECONDS = "etl_copy_stage_seconds"
+ETL_COPY_DECODE_WAIT_SECONDS = "etl_copy_decode_wait_seconds"
+ETL_COPY_WRITE_SECONDS = "etl_copy_write_seconds"
+ETL_COPY_ACK_WAIT_SECONDS = "etl_copy_ack_wait_seconds"
+ETL_CLICKHOUSE_RENDER_SECONDS = "etl_clickhouse_render_seconds"
+ETL_CLICKHOUSE_REQUEST_SECONDS = "etl_clickhouse_request_seconds"
+ETL_EVENT_LOOP_LAG_SECONDS = "etl_event_loop_lag_seconds"
+ETL_LOOP_THREAD_CPU_SECONDS_TOTAL = "etl_loop_thread_cpu_seconds_total"
+
 # label keys
 LABEL_PIPELINE_ID = "pipeline_id"
 LABEL_TABLE = "table"
@@ -291,6 +332,20 @@ _BUCKETS_BY_NAME = {
     # the coarse buckets under real multi-tenant contention
     ETL_DECODE_ADMISSION_WAIT_SECONDS: _FINE_TIME_BUCKETS,
 }
+# every span series times a stage that runs in micro- to milliseconds
+_BUCKETS_BY_NAME.update(dict.fromkeys((
+    ETL_DECODE_HANDOFF_WAIT_SECONDS, ETL_DECODE_WINDOW_WAIT_SECONDS,
+    ETL_DECODE_RESULT_WAIT_SECONDS, ETL_DECODE_UNPACK_SECONDS,
+    ETL_DECODE_EGRESS_FETCH_SECONDS, ETL_APPLY_SELECT_WAIT_SECONDS,
+    ETL_INTAKE_DRAIN_SECONDS, ETL_INTAKE_SEGMENT_SECONDS,
+    ETL_APPLY_FRAME_WALK_SECONDS, ETL_ASSEMBLER_SEAL_SECONDS,
+    ETL_APPLY_FLUSH_FILL_SECONDS, ETL_APPLY_ACK_TO_STATUS_SECONDS,
+    ETL_APPLY_PROGRESS_STORE_SECONDS, ETL_APPLY_STATUS_UPDATE_SECONDS,
+    ETL_COPY_READ_WAIT_SECONDS, ETL_COPY_CUT_SECONDS,
+    ETL_COPY_STAGE_SECONDS, ETL_COPY_DECODE_WAIT_SECONDS,
+    ETL_COPY_WRITE_SECONDS, ETL_COPY_ACK_WAIT_SECONDS,
+    ETL_CLICKHOUSE_RENDER_SECONDS, ETL_CLICKHOUSE_REQUEST_SECONDS,
+    ETL_EVENT_LOOP_LAG_SECONDS), _FINE_TIME_BUCKETS))
 
 LabelSet = tuple[tuple[str, str], ...]
 
@@ -318,6 +373,16 @@ class MetricsRegistry:
         self._gauges: dict[str, dict[LabelSet, float]] = defaultdict(dict)
         self._histograms: dict[str, dict[LabelSet, _Histogram]] = \
             defaultdict(dict)
+        # run before any histogram is read: telemetry/spans.py folds the
+        # observations it deferred off the hot path
+        self._before_read: list = []
+
+    def before_read(self, hook) -> None:
+        self._before_read.append(hook)
+
+    def _settle(self) -> None:
+        for hook in self._before_read:
+            hook()
 
     def counter_inc(self, name: str, value: float = 1.0,
                     labels: dict[str, str] | None = None) -> None:
@@ -333,18 +398,29 @@ class MetricsRegistry:
 
     def histogram_observe(self, name: str, value: float,
                           labels: dict[str, str] | None = None) -> None:
-        key = _labels(labels)
+        key = _labels(labels) if labels else ()
         with self._lock:
-            h = self._histograms[name].setdefault(
-                key, _Histogram(bounds=_BUCKETS_BY_NAME.get(
-                    name, _HISTOGRAM_BUCKETS)))
+            series = self._histograms[name]
+            h = series.get(key)
+            if h is None:
+                h = series[key] = _Histogram(bounds=_BUCKETS_BY_NAME.get(
+                    name, _HISTOGRAM_BUCKETS))
             h.total += value
             h.count += 1
-            for i, b in enumerate(h.bounds):
-                if value <= b:
-                    h.buckets[i] += 1
-                    return
-            h.buckets[-1] += 1
+            h.buckets[bisect_left(h.bounds, value)] += 1
+
+    def histogram_observe_many(self, observations) -> None:
+        """(name, value) pairs of unlabeled series under one lock."""
+        with self._lock:
+            for name, value in observations:
+                series = self._histograms[name]
+                h = series.get(())
+                if h is None:
+                    h = series[()] = _Histogram(bounds=_BUCKETS_BY_NAME.get(
+                        name, _HISTOGRAM_BUCKETS))
+                h.total += value
+                h.count += 1
+                h.buckets[bisect_left(h.bounds, value)] += 1
 
     def get_counter(self, name: str,
                     labels: dict[str, str] | None = None) -> float:
@@ -359,6 +435,7 @@ class MetricsRegistry:
                       ) -> tuple[int, float]:
         """(count, sum) of one histogram series; (0, 0.0) when unseen —
         benches and tests read stage totals without parsing exposition."""
+        self._settle()
         h = self._histograms.get(name, {}).get(_labels(labels))
         return (h.count, h.total) if h is not None else (0, 0.0)
 
@@ -371,6 +448,7 @@ class MetricsRegistry:
 
     def sum_histogram(self, name: str) -> tuple[int, float]:
         """(count, sum) of a histogram summed over every label set."""
+        self._settle()
         count, total = 0, 0.0
         with self._lock:
             for h in self._histograms.get(name, {}).values():
@@ -380,6 +458,7 @@ class MetricsRegistry:
 
     def render_prometheus(self) -> str:
         """Prometheus text exposition format 0.0.4."""
+        self._settle()
         out: list[str] = []
 
         def fmt_labels(key: LabelSet, extra: str = "") -> str:

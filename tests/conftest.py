@@ -13,6 +13,13 @@ if "xla_force_host_platform_device_count" not in _flags:
         _flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 
+# pyarrow's bundled mimalloc pool segfaulted in `pa.array(...)` on the
+# decode threads of this sandbox from one hour to the next (PR 25: every
+# run of tests/test_pipeline_e2e.py, at the parent commit too, none with
+# the system or jemalloc pool; PERF.md section 7). The tests check the
+# program, not an allocator: take malloc unless the caller chose a pool.
+os.environ.setdefault("ARROW_DEFAULT_MEMORY_POOL", "system")
+
 import pytest
 
 
