@@ -1,9 +1,11 @@
-"""Native host components: the pgoutput framer (C, via ctypes).
+"""Native host components: the pgoutput framer and the CopyData block
+scan (C, via ctypes).
 
 Builds `framer.c` with the system compiler on first import (cached as
 `_framer-<hash>.so`); falls back to a pure-Python walker with identical
-outputs when no compiler is available. `frame_pgoutput` is the entry point;
-see ops/wal.py for the staging layer that consumes it.
+outputs when no compiler is available. `frame_pgoutput` is the framer's
+entry point (see ops/wal.py for the staging layer that consumes it);
+`scan_copy_data` is the COPY stream's (postgres/wire.py `copy_out`).
 """
 
 from __future__ import annotations
@@ -20,6 +22,9 @@ import numpy as np
 _DIR = Path(__file__).resolve().parent
 
 FLAG_VALUE, FLAG_NULL, FLAG_TOAST, FLAG_BINARY = 0, 1, 2, 3
+# why scan_copy_data stopped (framer.c): the block ended; a message the
+# per-message logic has to read
+COPY_SCAN_MORE, COPY_SCAN_SLOW = 0, 1
 
 _lib = None
 _build_error: str | None = None
@@ -81,6 +86,11 @@ def _load() -> ctypes.CDLL | None:
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,  # kind/relid/oldkind
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,  # new off/len/flag
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,  # old off/len/flag
+        ]
+        lib.etl_scan_copy_data.restype = ctypes.c_int32
+        lib.etl_scan_copy_data.argtypes = [
+            ctypes.c_char_p, ctypes.c_int64,  # buf, buf_len
+            ctypes.c_void_p, ctypes.c_void_p,  # out, res[3]
         ]
         _lib = lib
     except Exception as e:  # pragma: no cover - depends on toolchain
@@ -231,6 +241,51 @@ def _frame_py(data: np.ndarray, msg_off: np.ndarray, msg_len: np.ndarray,
                 out.kind[i] = 0
                 return out, i
     return out, -1
+
+
+def scan_copy_data(block: bytes) -> tuple[bytes, int, int, int]:
+    """Take the run of CopyData messages at the head of `block`.
+
+    Returns (payloads, consumed, messages, stop): the payloads of the run
+    joined, headers dropped; the bytes of `block` they took, which is the
+    offset of the first message not taken; how many there were; and why
+    the scan stopped there — COPY_SCAN_MORE (the block ends at or inside
+    that message) or COPY_SCAN_SLOW (another tag, or a length outside
+    4..1 GB + 4: the per-message reader's to judge).
+
+    Runs on the event loop, so it never builds the library: the caller
+    has loaded it off the loop (`native_available()`; wire.py does when a
+    connection opens)."""
+    lib = _lib
+    if lib is None:
+        assert _build_error is not None, \
+            "native_available() first, off the event loop"
+        return _scan_copy_data_py(block)
+    out = ctypes.create_string_buffer(len(block))
+    res = (ctypes.c_int64 * 3)()
+    stop = lib.etl_scan_copy_data(block, len(block), out, res)
+    return ctypes.string_at(out, res[1]), res[0], res[2], stop
+
+
+def _scan_copy_data_py(block: bytes) -> tuple[bytes, int, int, int]:
+    """Pure-Python fallback with identical outputs to framer.c."""
+    pos, end, parts, stop = 0, len(block), [], COPY_SCAN_MORE
+    while pos < end:
+        if block[pos] != 0x64:  # 'd'
+            stop = COPY_SCAN_SLOW
+            break
+        if pos + 5 > end:
+            break
+        payload = int.from_bytes(block[pos + 1:pos + 5], "big",
+                                 signed=True) - 4
+        if payload < 0 or payload > 1 << 30:
+            stop = COPY_SCAN_SLOW
+            break
+        if pos + 5 + payload > end:
+            break
+        parts.append(block[pos + 5:pos + 5 + payload])
+        pos += 5 + payload
+    return b"".join(parts), pos, len(parts), stop
 
 
 def pack_bmat(data, offsets, lengths, col_idx, widths, bmat, lens_out) -> bool:

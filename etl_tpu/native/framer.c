@@ -308,3 +308,50 @@ void etl_pack_bmat_nibble(const uint8_t *data, int64_t data_len,
         bad_rows[r] = bad ? 1 : 0;
     }
 }
+
+/* Scan a block of backend messages for the run of CopyData ('d') messages
+ * at its head (postgres/wire.py `copy_out`: a server sends one CopyData
+ * per row, and the socket hands them over a few thousand at a time).
+ *
+ * Each 'd' message is tag, int32 length (counts itself, not the tag),
+ * payload. The payloads of the run are written to `out` back to back, the
+ * 5-byte headers dropped; `out` is the caller's, buf_len bytes, apart from
+ * `buf`. The scan stops at the first message it may not take:
+ *
+ *   COPY_SCAN_MORE  the block ends there, or inside that 'd' message
+ *                   (header or payload cut): the caller brings more bytes
+ *   COPY_SCAN_SLOW  another tag, or a 'd' whose length is under 4 or over
+ *                   PG's 1 GB message cap (the bound `_read_message`
+ *                   keeps): the caller's per-message logic decides
+ *
+ * res[0] = bytes consumed, which is the offset of that first message;
+ * res[1] = payload bytes written; res[2] = messages taken. Untrusted
+ * input: no read past buf_len, no write past res[0] - 5 * res[2] bytes.
+ * Python twin: native/__init__.py `_scan_copy_data_py`. */
+#define COPY_SCAN_MORE 0
+#define COPY_SCAN_SLOW 1
+#define COPY_MAX_PAYLOAD ((int64_t)1 << 30)
+
+int32_t etl_scan_copy_data(const uint8_t *buf, int64_t buf_len,
+                           uint8_t *out, int64_t *res) {
+    int64_t pos = 0, written = 0, taken = 0;
+    int32_t stop = COPY_SCAN_MORE;
+    while (pos < buf_len) {
+        if (buf[pos] != 'd') { stop = COPY_SCAN_SLOW; break; }
+        if (pos + 5 > buf_len) break;
+        int64_t payload = (int64_t)(int32_t)be32(buf + pos + 1) - 4;
+        if (payload < 0 || payload > COPY_MAX_PAYLOAD) {
+            stop = COPY_SCAN_SLOW;
+            break;
+        }
+        if (pos + 5 + payload > buf_len) break;
+        memcpy(out + written, buf + pos + 5, (size_t)payload);
+        written += payload;
+        pos += 5 + payload;
+        taken++;
+    }
+    res[0] = pos;
+    res[1] = written;
+    res[2] = taken;
+    return stop;
+}

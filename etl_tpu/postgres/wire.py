@@ -28,8 +28,16 @@ from dataclasses import dataclass, field
 from typing import AsyncIterator
 
 from ..models.errors import ErrorKind, EtlError
+from ..native import COPY_SCAN_MORE, native_available, scan_copy_data
+from ..telemetry.metrics import (ETL_COPY_STREAM_MESSAGES_TOTAL,
+                                 ETL_COPY_STREAM_READS_TOTAL,
+                                 ETL_COPY_STREAM_SLOW_MESSAGES_TOTAL,
+                                 registry)
 
 PROTOCOL_VERSION = 196608  # 3.0
+# one read of the COPY stream: what one transport read delivers (asyncio's
+# selector transport receives at most 256 KiB at a time)
+COPY_BLOCK_BYTES = 1 << 18
 
 
 @dataclass
@@ -89,6 +97,10 @@ class PgWireConnection:
         self.connect_timeout_s = connect_timeout_s
         self._reader: asyncio.StreamReader | None = None
         self._writer: asyncio.StreamWriter | None = None
+        # bytes read from the socket and not yet parsed: `copy_out` reads
+        # in blocks, and what a block holds past the last message taken
+        # is the next message's first bytes
+        self._unread = b""
         self.parameters: dict[str, str] = {}
         self.backend_pid = 0
 
@@ -96,7 +108,9 @@ class PgWireConnection:
 
     async def _read_message(self) -> BackendMessage:
         assert self._reader is not None
-        header = await self._reader.readexactly(5)
+        readexactly = self._read_unread if self._unread \
+            else self._reader.readexactly
+        header = await readexactly(5)
         tag = header[:1]
         (length,) = struct.unpack(">i", header[1:5])
         # corrupted stream defense: a flipped bit in the length field
@@ -109,10 +123,22 @@ class PgWireConnection:
             raise EtlError(ErrorKind.SOURCE_PROTOCOL_VIOLATION,
                            f"corrupt message length {length} "
                            f"(tag {tag!r})")
-        payload = await self._reader.readexactly(length - 4)
+        payload = await readexactly(length - 4)
         if tag == b"E":
             raise PgServerError(_parse_error_fields(payload))
         return BackendMessage(tag, payload)
+
+    async def _read_unread(self, n: int) -> bytes:
+        """`readexactly(n)` that starts with the connection's unread
+        bytes and asks the socket only for what they lack."""
+        assert self._reader is not None
+        unread = self._unread
+        if len(unread) >= n:
+            self._unread = unread[n:]
+            return unread[:n]
+        rest = await self._reader.readexactly(n - len(unread))
+        self._unread = b""
+        return unread + rest
 
     def _send(self, tag: bytes, payload: bytes) -> None:
         assert self._writer is not None
@@ -125,6 +151,10 @@ class PgWireConnection:
     # -- connect / auth ------------------------------------------------------
 
     async def connect(self) -> None:
+        # the COPY scan's C library is built on its first load (a
+        # compiler run): here, off the loop, not at a COPY's first block
+        await asyncio.to_thread(native_available)
+        self._unread = b""
         try:
             self._reader, self._writer = await asyncio.wait_for(
                 asyncio.open_connection(self.host, self.port),
@@ -351,12 +381,51 @@ class PgWireConnection:
         return await self._read_query_response()
 
     async def copy_out(self, sql: str) -> AsyncIterator[bytes]:
-        """COPY ... TO STDOUT: yields raw CopyData payloads."""
+        """COPY ... TO STDOUT, read from the socket in blocks and not in
+        messages: each yield is the payloads of one run of whole CopyData
+        messages, joined — every byte of the COPY text, in order. A row
+        may span messages, so the consumer cuts at newlines itself.
+
+        A server sends one CopyData per row. One await and one scan
+        (native.scan_copy_data) take every whole one a block holds; a
+        message the block's end cut is carried into the next scan. The
+        scan stops at any other tag and at a length it may not trust, and
+        that one message is read as every message of this connection is
+        (`_read_message`, from the bytes already read), which also
+        completes from the socket a CopyData too large for a block. An
+        ErrorResponse is held until ReadyForQuery; the rows before it are
+        delivered. What the last block holds past ReadyForQuery stays the
+        connection's unread bytes."""
+        assert self._reader is not None
         self._send(b"Q", sql.encode() + b"\x00")
         await self._flush()
         started = False
         error: PgServerError | None = None
         while True:
+            unread = self._unread
+            stop = COPY_SCAN_MORE
+            if unread:
+                rows, consumed, messages, stop = scan_copy_data(unread)
+                if messages:
+                    # settled before the yield: a consumer that stops
+                    # there leaves the connection's bytes whole
+                    self._unread = unread = unread[consumed:]
+                    registry.counter_inc(ETL_COPY_STREAM_MESSAGES_TOTAL,
+                                         messages)
+                    if rows:
+                        yield rows
+            # a CopyData larger than a block would be carried and scanned
+            # again read after read: the per-message reader takes it
+            oversize = len(unread) >= 5 and int.from_bytes(
+                unread[1:5], "big") >= COPY_BLOCK_BYTES
+            if stop == COPY_SCAN_MORE and not oversize:
+                block = await self._reader.read(COPY_BLOCK_BYTES)
+                if not block:
+                    raise asyncio.IncompleteReadError(unread, None)
+                registry.counter_inc(ETL_COPY_STREAM_READS_TOTAL)
+                self._unread = unread + block if unread else block
+                continue
+            registry.counter_inc(ETL_COPY_STREAM_SLOW_MESSAGES_TOTAL)
             try:
                 msg = await self._read_message()
             except PgServerError as e:
@@ -364,12 +433,9 @@ class PgWireConnection:
                 continue
             if msg.tag == b"H":  # CopyOutResponse
                 started = True
-            elif msg.tag == b"d":
-                yield msg.payload
-            elif msg.tag == b"c":  # CopyDone
-                pass
-            elif msg.tag == b"C":
-                pass
+            elif msg.tag == b"d":  # one larger than a block
+                if msg.payload:
+                    yield msg.payload
             elif msg.tag == b"Z":
                 if error is not None:
                     raise error
@@ -377,6 +443,7 @@ class PgWireConnection:
                     raise EtlError(ErrorKind.SOURCE_QUERY_FAILED,
                                    f"not a COPY OUT statement: {sql!r}")
                 return
+            # 'c' CopyDone, 'C' CommandComplete, 'N', 'S': nothing to do
 
     # -- replication sub-protocol ---------------------------------------------
 

@@ -235,13 +235,16 @@ async def _copy_partition(source: ReplicationSource,
         progress.total_rows += batch.num_rows
         registry.counter_inc(ETL_TABLE_COPY_ROWS_TOTAL, batch.num_rows)
 
-    def cut_chunk(reading_since_ns: int) -> "tuple[bytes, bytes, int]":
+    def cut_chunk(reading_since_ns: int,
+                  at: int) -> "tuple[bytes, bytes, int]":
         """Close the chunk the reads have filled. Its read phase — every
         `async for` step since the last chunk was handed on: the awaited
-        socket reads, the stream's per-message Python and the append —
-        is ONE `copy.read_wait` interval (the stream yields a CopyData
-        message per ROW: a clock read per step would cost more than the
-        step). Then join and cut at the last row boundary. Returns
+        socket reads, the stream's scan of each block and the append —
+        is ONE `copy.read_wait` interval. Then join and cut at the first
+        row boundary at or past `at` bytes: where a stream of one
+        CopyData message per row crosses the threshold, whatever the
+        size of the pieces it arrives in. No boundary there yet (the row
+        that crosses is still arriving): the last one before it. Returns
         (chunk, remainder, batch_id)."""
         batch_id = spans.next_batch_id()
         spans.record("copy.read_wait", reading_since_ns, spans.now_ns(),
@@ -250,8 +253,15 @@ async def _copy_partition(source: ReplicationSource,
         with spans.span("copy.cut", ETL_COPY_CUT_SECONDS, partition=page,
                         batch_id=batch_id):
             buf = b"".join(pending)
-            cut = buf.rfind(b"\n") + 1
+            cut = buf.find(b"\n", max(at - 1, 0)) + 1 \
+                or buf.rfind(b"\n") + 1
             return buf[:cut], buf[cut:], batch_id
+
+    def threshold() -> int:
+        # budget-aware chunking: the per-stream share shrinks when many
+        # partitions copy concurrently (batch_budget.rs:72-96)
+        return max_batch_bytes if lease is None \
+            else min(max_batch_bytes, lease.ideal_batch_bytes())
 
     try:
         reading_since_ns = spans.now_ns()
@@ -263,19 +273,19 @@ async def _copy_partition(source: ReplicationSource,
                 await monitor.wait_until_resumed()
             pending.append(raw)
             pending_len += len(raw)
-            # budget-aware chunking: the per-stream share shrinks when many
-            # partitions copy concurrently (batch_budget.rs:72-96)
-            threshold = max_batch_bytes if lease is None \
-                else min(max_batch_bytes, lease.ideal_batch_bytes())
-            if pending_len >= threshold:
-                chunk, rest, batch_id = cut_chunk(reading_since_ns)
+            # a piece of the stream is a block of rows, not a row: one
+            # may cross the threshold more than once
+            while pending_len >= (at := threshold()):
+                chunk, rest, batch_id = cut_chunk(reading_since_ns, at)
                 await write_chunk(chunk, batch_id)
                 pending = [rest] if rest else []
                 pending_len = len(rest)
                 reading_since_ns = spans.now_ns()
+                if not chunk:
+                    break  # one row longer than the threshold, arriving
         # the tail: rows after the last full chunk (every row ends in a
         # newline, so the cut leaves nothing behind)
-        chunk, rest, batch_id = cut_chunk(reading_since_ns)
+        chunk, rest, batch_id = cut_chunk(reading_since_ns, pending_len)
         await write_chunk(chunk + rest, batch_id)
         while in_flight:
             await drain_one()

@@ -302,6 +302,13 @@ ETL_COPY_STAGE_SECONDS = "etl_copy_stage_seconds"
 ETL_COPY_DECODE_WAIT_SECONDS = "etl_copy_decode_wait_seconds"
 ETL_COPY_WRITE_SECONDS = "etl_copy_write_seconds"
 ETL_COPY_ACK_WAIT_SECONDS = "etl_copy_ack_wait_seconds"
+# the COPY stream, read in blocks (postgres/wire.py copy_out): blocks read
+# and scanned, CopyData messages the scan took in bulk, and messages that
+# took the per-message branch instead. messages / reads is how many rows a
+# socket read brings: a peer that flushes every row reads 1
+ETL_COPY_STREAM_READS_TOTAL = "etl_copy_stream_reads_total"
+ETL_COPY_STREAM_MESSAGES_TOTAL = "etl_copy_stream_messages_total"
+ETL_COPY_STREAM_SLOW_MESSAGES_TOTAL = "etl_copy_stream_slow_messages_total"
 ETL_CLICKHOUSE_RENDER_SECONDS = "etl_clickhouse_render_seconds"
 ETL_CLICKHOUSE_REQUEST_SECONDS = "etl_clickhouse_request_seconds"
 ETL_EVENT_LOOP_LAG_SECONDS = "etl_event_loop_lag_seconds"

@@ -1,4 +1,4 @@
-"""Fuzz targets for the codec parsers + native framer.
+"""Fuzz targets for the codec parsers + native framer and COPY scan.
 
 Reference parity: cargo-fuzz targets `parse_copy_row`, `parse_text_cell`,
 `numeric_text_roundtrip`, `parse_bytea_hex_string`
@@ -185,6 +185,178 @@ def fuzz_framer(rng: random.Random, _ignored=None) -> None:
         assert (arr_off >= 0).all() and (arr_len >= 0).all() \
                 and (ends <= total).all(), \
             "framer emitted out-of-bounds field"
+
+
+def backend_message(tag: bytes, payload: bytes = b"") -> bytes:
+    import struct
+
+    return tag + struct.pack(">i", len(payload) + 4) + payload
+
+
+def copy_stream_reference(stream: bytes):
+    """The obvious parser of a COPY OUT response: one message at a time
+    from the whole byte string. Returns (CopyData payloads joined,
+    outcome, bytes after ReadyForQuery); outcome is None for a clean end,
+    the server's error message, the ErrorKind of a typed failure, or
+    "eof" where the bytes end first (then nothing is "after")."""
+    import struct
+
+    from ..models.errors import ErrorKind
+    from ..postgres.wire import _parse_error_fields
+
+    pos, payloads, started, error = 0, [], False, None
+    while True:
+        if pos + 5 > len(stream):
+            return b"".join(payloads), "eof", None
+        tag = stream[pos:pos + 1]
+        (length,) = struct.unpack(">i", stream[pos + 1:pos + 5])
+        if length < 4 or length - 4 > 1 << 30:
+            return b"".join(payloads), \
+                ErrorKind.SOURCE_PROTOCOL_VIOLATION, None
+        if pos + 1 + length > len(stream):
+            return b"".join(payloads), "eof", None
+        payload = stream[pos + 5:pos + 1 + length]
+        pos += 1 + length
+        if tag == b"d":
+            payloads.append(payload)
+        elif tag == b"H":
+            started = True
+        elif tag == b"E":
+            error = _parse_error_fields(payload)["M"]
+        elif tag == b"Z":
+            outcome = error if error is not None else (
+                None if started else ErrorKind.SOURCE_QUERY_FAILED)
+            return b"".join(payloads), outcome, stream[pos:]
+
+
+class ScriptedReader:
+    """Stands where a connection's StreamReader does. `read(n)` returns
+    the next scripted piece and never more, so the ends of the pieces are
+    the block cuts under test; an exhausted script is the peer's EOF."""
+
+    def __init__(self, pieces):
+        self.pieces = [p for p in pieces if p]
+
+    async def read(self, n: int) -> bytes:
+        if not self.pieces:
+            return b""
+        piece = self.pieces.pop(0)
+        if len(piece) > n:
+            self.pieces.insert(0, piece[n:])
+        return piece[:n]
+
+    async def readexactly(self, n: int) -> bytes:
+        import asyncio
+
+        out = b""
+        while len(out) < n:
+            got = await self.read(n - len(out))
+            if not got:
+                raise asyncio.IncompleteReadError(out, n)
+            out += got
+        return out
+
+
+class _NullWriter:
+    def write(self, data: bytes) -> None:
+        pass
+
+    async def drain(self) -> None:
+        pass
+
+    def close(self) -> None:
+        pass
+
+    async def wait_closed(self) -> None:
+        pass
+
+
+def scripted_connection(pieces):
+    """A PgWireConnection past its start-up whose peer is `pieces`."""
+    from ..native import native_available
+    from ..postgres.wire import PgWireConnection
+
+    native_available()  # what `connect()` does, off the loop
+    conn = PgWireConnection(host="scripted", port=0, database="d", user="u")
+    conn._reader, conn._writer = ScriptedReader(pieces), _NullWriter()
+    return conn
+
+
+async def run_copy_out(conn):
+    """(yields, outcome) of one `copy_out`, outcome as the reference's."""
+    import asyncio
+
+    from ..postgres.wire import PgServerError
+
+    got, outcome = [], None
+    try:
+        async for rows in conn.copy_out("COPY t TO STDOUT"):
+            got.append(rows)
+    except PgServerError as e:
+        outcome = e.fields["M"]
+    except EtlError as e:
+        outcome = e.kind
+    except asyncio.IncompleteReadError:
+        outcome = "eof"
+    return got, outcome
+
+
+def fuzz_copy_stream(rng: random.Random, _ignored=None) -> None:
+    """Random backend messages (sizes, tags, corrupt lengths) cut into
+    random blocks, through `PgWireConnection.copy_out` — the C scan or its
+    Python walk, whichever this process loaded — against the obvious
+    per-message parser of the same bytes: the same CopyData bytes in the
+    same order, the same outcome (clean end, the server's error, a typed
+    protocol violation, the peer's EOF), and every byte past
+    ReadyForQuery still the connection's to read."""
+    import asyncio
+    import struct
+
+    from ..native import (_scan_copy_data_py, native_available,
+                          scan_copy_data)
+
+    message = backend_message
+    stream = bytearray(message(b"H", b"\x00\x00\x00")
+                       if rng.random() < 0.9 else b"")
+    for _ in range(rng.randint(0, 40)):
+        c = rng.random()
+        if c < 0.8:
+            # now and then one larger than a block of the reader
+            size = 300_000 if rng.random() < 0.01 else rng.choice(
+                (0, 1, 7, 100, 100, 100, 1000, 70_000))
+            stream += message(b"d", rng.randbytes(rng.randint(0, size)))
+        elif c < 0.9:
+            stream += message(rng.choice((b"N", b"S", b"c", b"C")),
+                              rng.randbytes(rng.randint(0, 30)))
+        elif c < 0.95:
+            stream += message(b"E", b"SERROR\x00C57014\x00Mfuzz\x00\x00")
+        elif c < 0.98:  # a length no message may have
+            stream += rng.choice((b"d", b"N")) + struct.pack(
+                ">i", rng.choice((-1, 0, 3, (1 << 30) + 5, -(1 << 31))))
+        else:  # ReadyForQuery mid-stream: the rest is another statement's
+            stream += message(b"Z", b"I")
+    stream += message(b"c") + message(b"Z", b"I")
+    stream += rng.randbytes(rng.randint(0, 12))
+    if rng.random() < 0.1:  # the peer goes away
+        del stream[rng.randrange(len(stream) + 1):]
+    stream = bytes(stream)
+    want, outcome, after = copy_stream_reference(stream)
+
+    cuts = sorted(rng.randrange(len(stream) + 1)
+                  for _ in range(rng.randint(0, 12)))
+    pieces = [stream[a:b] for a, b in zip([0, *cuts], [*cuts, len(stream)])]
+    if native_available():
+        for piece in pieces:
+            assert scan_copy_data(piece) == _scan_copy_data_py(piece), \
+                "C scan and Python walk differ"
+
+    conn = scripted_connection(pieces)
+    got, seen = asyncio.run(asyncio.wait_for(run_copy_out(conn), 30))
+    assert seen == outcome, f"outcome {seen!r}, want {outcome!r}"
+    assert b"".join(got) == want, "CopyData bytes differ"
+    if after is not None:
+        assert conn._unread + b"".join(conn._reader.pieces) == after, \
+            "bytes after ReadyForQuery dropped"
 
 
 _AVRO_FUZZ_DIR: str | None = None  # one temp dir per process, not per case
@@ -379,6 +551,7 @@ TARGETS = {
     "numeric_roundtrip": fuzz_numeric_roundtrip,
     "bytea_hex": fuzz_bytea_hex,
     "framer": fuzz_framer,
+    "copy_stream": fuzz_copy_stream,
     "avro_ocf": fuzz_avro_ocf,
     "pb_append_rows": fuzz_pb_append_rows,
     "snowpipe_batches": fuzz_snowpipe_batches,

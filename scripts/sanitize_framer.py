@@ -9,7 +9,9 @@ any OOB read/write, overflow, or misaligned access ABORTS the child), then
 hammer it with
 
   1. the structured-mutation framer fuzzer (testing/fuzz.py `framer`
-     target — valid pgoutput streams + byte mutations + truncations), and
+     target — valid pgoutput streams + byte mutations + truncations) and
+     the COPY stream fuzzer (`copy_stream` target — random message sizes,
+     tags, corrupt lengths and block cuts through etl_scan_copy_data), and
   2. the full differential test file (tests/test_native_framer.py), which
      also exercises etl_pack_bmat / etl_gather_string / nibble packing.
 
@@ -105,16 +107,18 @@ def main(argv=None) -> int:
         print("FAIL: instrumented framer did not load", file=sys.stderr)
         return rc or 1
 
-    # 2. structured-mutation fuzz under ASan/UBSan
-    fuzz_args = ["-m", "etl_tpu.testing.fuzz", "--target", "framer",
-                 "--seconds", str(args.seconds)]
-    if args.seed is not None:
-        fuzz_args += ["--seed", str(args.seed)]
-    rc = run_child(so, fuzz_args)
-    if rc != 0:
-        print("FAIL: sanitizer or fuzz failure in framer target",
-              file=sys.stderr)
-        return rc
+    # 2. structured-mutation fuzz under ASan/UBSan: the framer, then the
+    # CopyData block scan
+    for target in ("framer", "copy_stream"):
+        fuzz_args = ["-m", "etl_tpu.testing.fuzz", "--target", target,
+                     "--seconds", str(args.seconds)]
+        if args.seed is not None:
+            fuzz_args += ["--seed", str(args.seed)]
+        rc = run_child(so, fuzz_args)
+        if rc != 0:
+            print(f"FAIL: sanitizer or fuzz failure in {target} target",
+                  file=sys.stderr)
+            return rc
 
     # 3. the pure-framer differential tests (the TestWalStaging class
     # compiles jax programs, which is impractically slow under ASan
@@ -139,7 +143,7 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return rc
     print("sanitize_framer: no findings "
-          f"(fuzz {args.seconds:.0f}s + framer differentials + "
+          f"(fuzz 2 x {args.seconds:.0f}s + framer differentials + "
           f"pack/gather hammer under ASan+UBSan)")
     return 0
 
