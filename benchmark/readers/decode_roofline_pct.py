@@ -14,8 +14,11 @@ def read(ctx: dict, params: dict):
     if rows <= 0:
         return None
     egress = ctx["config"]["destination"]["type"] != "null"
+    config = ctx["config"]
+    tables = config["tables"] if "tables" in config else [config["table"]]
     nbytes = roofline.decode_bytes(
-        ctx["config"]["table"]["columns"], rows,
+        roofline.mean_columns(tables, ctx["report"].get("table_event_share")),
+        rows,
         ctx["report"]["payload_bytes_per_row"], egress)
     least_s = nbytes / roofline.peak(ctx["device_kind"])["hbm_bytes_per_s"]
     return 100.0 * least_s / tr["program_s"]

@@ -31,14 +31,30 @@ def peak(device_kind: str) -> dict:
     return peaks[device_kind]
 
 
+def mean_columns(tables: list, share: "dict | None") -> list:
+    """The columns of the rows a decode sees: one table's own, or those of
+    several tables weighted by each table's share of the stream's events
+    (`share`: {table name: share}, the source's report) — the count below
+    is linear in the columns, so a weighted column counts its share."""
+    if len(tables) == 1 or not share:
+        return tables[0]["columns"]
+    return [{**c, "weight": float(share.get(t["name"], 0.0))}
+            for t in tables for c in t["columns"]]
+
+
 def decode_bytes(columns: list, rows: float, payload_bytes_per_row: float,
                  egress: bool) -> float:
-    """Bytes the chip has to move at the least for `rows` routed rows."""
-    typed = sum(TYPED_BYTES.get(c["type"], 0) for c in columns)
+    """Bytes the chip has to move at the least for `rows` routed rows of
+    the schema `columns` (`mean_columns` gives a mix of tables')."""
+    def w(c):
+        return c.get("weight", 1.0)
+
+    typed = sum(w(c) * TYPED_BYTES.get(c["type"], 0) for c in columns)
     total = payload_bytes_per_row + typed
     if egress:
-        host_text = sum(c.get("text_bytes", 0) for c in columns
+        host_text = sum(w(c) * c.get("text_bytes", 0) for c in columns
                         if c["type"] not in TYPED_BYTES)
         total += max(0.0, payload_bytes_per_row - MESSAGE_OVERHEAD
-                     - COLUMN_OVERHEAD * len(columns) - host_text)
+                     - COLUMN_OVERHEAD * sum(w(c) for c in columns)
+                     - host_text)
     return rows * total

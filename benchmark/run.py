@@ -13,8 +13,10 @@ plain reference; prints one JSON object as its last stdout line.
 Everything that belongs to one configuration, one traffic mix, one
 per-layer metric or one host span is a data file found by the name in
 `BENCHMARK.json` (`configs/`, `traffic/`, `metrics/`, `spans/`); a reader
-kind that needs code is one file under `readers/`. `README.md` beside this
-file says how to add a cell and how to rehearse one on a CPU.
+kind that needs code is one file under `readers/`, a deployment's data one
+generator file under `deployments/`, named by the configuration's file.
+`README.md` beside this file says how to add a configuration or a cell and
+how to rehearse one on a CPU.
 """
 
 from __future__ import annotations
@@ -66,7 +68,8 @@ def process_age_s() -> float:
 
 class Cell:
     def __init__(self, workload: str, rehearse: bool,
-                 traffic_file: "str | None" = None):
+                 traffic_file: "str | None" = None,
+                 config_file: "str | None" = None):
         bench = load_json(ROOT, "BENCHMARK.json")
         entry = next((w for w in bench["workloads"]
                       if w["name"] == workload), None)
@@ -74,7 +77,7 @@ class Cell:
             raise SystemExit(f"no workload {workload!r} in BENCHMARK.json")
         cfg = next(c for c in bench["configs"] if c["name"] == entry["config"])
         self.chips = int(entry["chips"])
-        self.config_path = os.path.join(ROOT, cfg["file"])
+        self.config_path = config_file or os.path.join(ROOT, cfg["file"])
         self.traffic_path = traffic_file or os.path.join(
             HERE, "traffic", entry["traffic"] + ".json")
         self.config = load_json(self.config_path)
@@ -82,12 +85,17 @@ class Cell:
         if rehearse:
             self.config.update(self.config.get("rehearsal", {}))
             self.traffic.update(self.traffic.get("rehearsal", {}))
+        import oplog
+
+        self.tables = oplog.tables_of(self.config)
+        self.generator = oplog.load_generator(self.config, self.config_path)
 
         def mine(metric: dict) -> bool:
             return workload in metric.get(
                 "workloads", [w["name"] for w in bench["workloads"]])
 
         self.end_to_end = [m for m in bench["end_to_end"] if mine(m)]
+        self.units = {m["name"]: m["unit"] for m in bench["end_to_end"]}
         self.per_layer = [m for m in bench["per_layer"] if mine(m)]
         self.readers = {m["name"]: load_json(HERE, "metrics",
                                              m["name"] + ".json")
@@ -300,39 +308,52 @@ def _null_destination_class():
 
     class NullDestination(Destination):
         """Resolves every batch (so the decode is on the timed path),
-        keeps its integer columns and WAL coordinates, and acks at once.
-        The comparison with the reference happens after the window."""
+        keeps what the comparison needs — the table, every column with its
+        validity, the change kinds, old and key images, WAL coordinates:
+        one copy per typed column, everything else by reference, no
+        per-row work — and acks at once. The comparison with the
+        reference happens after the window."""
 
         def __init__(self) -> None:
-            self.parts: list = []
-            self.fillers: list = []
+            self.copied: list = []  # (table id, columns)
+            self.parts: list = []   # (table id, columns, change kinds,
+            #                          commit LSNs, ordinals, old images)
             self.other_events = 0
 
         async def startup(self):
             return None
 
-        def _keep(self, batch, commit_lsns=None, tx_ordinals=None) -> None:
-            cols = batch.columns
+        @staticmethod
+        def _columns(batch) -> list:
+            return [(np.array(c.data) if isinstance(c.data, np.ndarray)
+                     else c.data, np.array(c.validity),
+                     None if c.toast_unchanged is None
+                     else np.array(c.toast_unchanged), c.lazy_text_oid)
+                    for c in batch.columns]
+
+        def _keep(self, e) -> None:
+            old = e.old_batch
             self.parts.append((
-                *(np.array(c.data, dtype=np.int64) for c in cols[:3]),
-                None if commit_lsns is None
-                else np.array(commit_lsns, dtype=np.int64),
-                None if tx_ordinals is None
-                else np.array(tx_ordinals, dtype=np.int64),
-                bool(all(np.asarray(c.validity).all() for c in cols))))
-            self.fillers.append(cols[3].data)
+                int(e.schema.id), self._columns(e.batch),
+                np.array(e.change_types),
+                np.array(e.commit_lsns, dtype=np.int64),
+                np.array(e.tx_ordinals, dtype=np.int64),
+                None if old is None else (
+                    self._columns(old), np.array(e.old_rows),
+                    np.array(e.old_is_key)),
+                None if e.delete_is_key is None
+                else np.array(e.delete_is_key)))
 
         async def write_table_rows(self, schema, batch):
-            self._keep(batch)
+            self.copied.append((int(schema.id), self._columns(batch)))
             return WriteAck.durable()
 
         async def write_events(self, events):
             for e in events:
                 if isinstance(e, DecodedBatchEvent):
-                    if np.asarray(e.change_types).any() or len(e.old_rows):
-                        self.other_events += 1
-                    self._keep(e.batch, e.commit_lsns, e.tx_ordinals)
-                elif hasattr(e, "row"):
+                    self._keep(e)
+                elif hasattr(e, "row") or hasattr(e, "old_row"):
+                    # a change delivered row by row: no cell's engine does
                     self.other_events += 1
             return WriteAck.durable()
 
@@ -342,30 +363,82 @@ def _null_destination_class():
         async def truncate_table(self, table_id):
             return None
 
-        def received(self, filler: str) -> dict:
-            def cat(i):
-                arrs = [p[i] for p in self.parts if p[i] is not None]
-                return np.concatenate(arrs) if arrs \
-                    else np.zeros(0, dtype=np.int64)
-
-            bad = self.other_events
-            for data, part in zip(self.fillers, self.parts):
-                if not part[5]:
-                    bad += len(part[0])  # a NULL where the source sent none
-                elif hasattr(data, "to_pylist"):
-                    import pyarrow.compute as pc
-
-                    bad += int(pc.sum(pc.not_equal(data, filler)).as_py()
-                               or 0)
-                else:
-                    bad += sum(1 for t in data if t != filler)
-            out = {"aid": cat(0), "bid": cat(1), "abalance": cat(2),
-                   "bad_text_rows": bad}
-            if any(p[3] is not None for p in self.parts):
-                out["commit_lsn"], out["tx_ordinal"] = cat(3), cat(4)
+        def received(self, tables: list) -> dict:
+            """{table id: {"copy": rows or None, "cdc": rows or None}} in
+            the plain form `reference.py` reads, in delivery order."""
+            out = {}
+            for table in tables:
+                tid = int(table["id"])
+                copied = [c for t, c in self.copied if t == tid]
+                parts = [p for p in self.parts if p[0] == tid]
+                out[tid] = {
+                    "copy": {"cols": _plain_columns(table, copied)}
+                    if copied else None,
+                    "cdc": _plain_events(table, parts) if parts else None}
             return out
 
     return NullDestination
+
+
+def _plain_columns(table: dict, batches: list) -> list:
+    """The kept columns of some batches of one table as (values, null,
+    unchanged) in plain types: numpy for typed columns, a pyarrow string
+    array for text, the server's text for NUMERIC."""
+    import numpy as np
+    import pyarrow as pa
+
+    out = []
+    for i, column in enumerate(table["columns"]):
+        kept = [b[i] for b in batches]
+        valid = np.concatenate([k[1] for k in kept])
+        toast = np.concatenate([
+            k[2] if k[2] is not None else np.zeros(len(k[1]), dtype=bool)
+            for k in kept]) if any(k[2] is not None for k in kept) else None
+        if all(isinstance(k[0], np.ndarray) for k in kept):
+            values = np.concatenate([k[0] for k in kept])
+        elif column["type"] == "numeric" or any(
+                not isinstance(k[0], (pa.Array, pa.ChunkedArray))
+                or k[3] is not None for k in kept):
+            # host-side values (lazy text, PgNumeric, ...): their text
+            values = []
+            for data, ok, _, lazy in kept:
+                items = data.to_pylist() if hasattr(data, "to_pylist") \
+                    else list(data)
+                values += [None if v is None or not good
+                           else v if isinstance(v, str)
+                           else v.pg_text() if hasattr(v, "pg_text")
+                           else str(v)
+                           for v, good in zip(items, ok.tolist())]
+        else:
+            values = pa.chunked_array([k[0] for k in kept]).cast(pa.string())
+        null = None if valid.all() else \
+            ~valid if toast is None else ~valid & ~toast
+        out.append((values, null, toast))
+    return out
+
+
+def _plain_events(table: dict, parts: list) -> dict:
+    import numpy as np
+
+    n_before = np.cumsum([0] + [len(p[2]) for p in parts])
+    olds = [(p[5], at) for p, at in zip(parts, n_before) if p[5] is not None]
+    out = {"cols": _plain_columns(table, [p[1] for p in parts]),
+           "change": np.concatenate([p[2] for p in parts]).astype(np.uint8),
+           "commit_lsn": np.concatenate([p[3] for p in parts]),
+           "tx_ordinal": np.concatenate([p[4] for p in parts]),
+           "old": None, "delete_is_key": None}
+    if olds:
+        out["old"] = {
+            "cols": _plain_columns(table, [o[0] for o, _ in olds]),
+            "rows": np.concatenate([np.asarray(o[1], dtype=np.int64) + at
+                                    for o, at in olds]),
+            "is_key": np.concatenate([np.asarray(o[2], dtype=bool)
+                                      for o, _ in olds])}
+    if any(p[6] is not None for p in parts):
+        out["delete_is_key"] = np.concatenate([
+            p[6] if p[6] is not None else np.zeros(len(p[2]), dtype=bool)
+            for p in parts]).astype(bool)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -477,38 +550,71 @@ class Run:
 
     def warm_programs(self, destination) -> None:
         """Every decode program this cell's traffic can touch, through the
-        program's own decoder and the cell's own bytes: the host program
-        of each row bucket, then whatever production routing picks for a
-        full bucket. Persistent-cache hits after a checkout's first run."""
+        program's own decoder and the cell's own bytes: for every published
+        table the log has events of, the host program of each row bucket,
+        then whatever production routing picks for a full bucket — fed with
+        the table's first events of each kind of change from the generator,
+        rendered as the source renders them. Persistent-cache hits after a checkout's first run.
+        The log and the snapshot made here are the ones the comparison
+        after the window reads."""
         import numpy as np
 
-        import pgbench
+        import wire
         from etl_tpu.models import ReplicatedTableSchema
         from etl_tpu.ops import engine, program_store
         from etl_tpu.ops.wal import stage_wal_batch
         from source import table_schema
 
-        buckets = self.cell.traffic.get("warm_row_buckets", [])
-        if not buckets:
+        cell, gen = self.cell, self.cell.generator
+        self.stream = gen.stream(cell.config, cell.traffic, self.args.seed,
+                                 self.args.seconds)
+        self.table_rows = gen.snapshot(cell.config, cell.traffic,
+                                     self.args.seed)
+        buckets = cell.traffic.get("warm_row_buckets", [])
+        if not buckets or self.stream is None:
             return
-        schema = ReplicatedTableSchema.with_all_columns(
-            table_schema(self.cell.config["table"]))
-        program_store.warm_host_programs([schema], buckets, wait=True)
+        oids = getattr(gen, "TYPE_OIDS", None)
+        extra = getattr(gen, "TEXT_BLOCKS", None)
+        counts = np.bincount(self.stream.table, minlength=len(cell.tables))
+        used = [(t, ReplicatedTableSchema.with_all_columns(
+            table_schema(table, oids)))
+            for t, table in enumerate(cell.tables) if counts[t]]
+        program_store.warm_host_programs([s for _, s in used], buckets,
+                                         wait=True)
         egress = getattr(destination, "egress_encoder", None) \
-            if self.cell.config["pipeline"].get("batch", {}) \
+            if cell.config["pipeline"].get("batch", {}) \
             .get("device_egress", True) else None
-        decoder = engine.DeviceDecoder(schema, egress=egress)
         n = max(buckets)
-        first_aid = int(self.cell.config["rows"]) + 1
-        cols = pgbench.accounts_columns(self.args.seed, n, first_aid)
         zeros = np.zeros(n, dtype=np.int64)
-        blob, offsets, payload_len = pgbench.render_insert_frames(
-            int(self.cell.config["table"]["id"]), cols, zeros, zeros, 0)
         head = 5 + 25  # CopyData header + XLogData header
-        for bucket in buckets:
-            decoder.decode(stage_wal_batch(
-                blob, offsets[:bucket] + head,
-                payload_len[:bucket].astype(np.int32), 4).staged)
+        stream = self.stream
+        kinds = wire.old_kinds(cell.tables, stream)
+        for t, schema in used:
+            decoder = engine.DeviceDecoder(schema, egress=egress)
+            table, ev = cell.tables[t], stream.events[t]
+            mine = slice(None) if len(stream.events) == 1 \
+                else np.flatnonzero(stream.table == t)
+            # each kind of change the table sees (an insert, an update with
+            # a key image, ...) has its own message shape and text widths
+            group = (stream.op[mine].astype(np.uint16) << 8) | kinds[mine]
+            keys = [int(group[0])] if (group == group[0]).all() \
+                else np.unique(group).tolist()
+            for key in keys:
+                rows = np.arange(n) if len(keys) == 1 and len(group) >= n \
+                    else np.resize(np.flatnonzero(group == key), n)
+                op, old_kind = key >> 8, key & 0xFF
+                blob, offsets, payload_len = wire.render_change_frames(
+                    table, op, old_kind, [c.pick(rows) for c in ev.new],
+                    [c.pick(rows) for c in ev.old] if old_kind else None,
+                    zeros, zeros, 0, extra)
+                for bucket in buckets:
+                    wal = stage_wal_batch(
+                        blob, offsets[:bucket] + head,
+                        payload_len[:bucket].astype(np.int32),
+                        len(table["columns"]))
+                    decoder.decode(wal.staged)
+                    if wal.old_staged is not None:
+                        decoder.decode(wal.old_staged)
         while engine.background_compiles_inflight():
             time.sleep(0.02)
 
@@ -568,11 +674,11 @@ class Run:
 
         t0 = time.perf_counter()
         pipeline, store = self.make_pipeline(listening["port"], destination)
-        tid = int(cell.config["table"]["id"])
         try:
             await pipeline.start()
-            await asyncio.wait_for(
-                store.notify_on(tid, TableStateType.READY), 120)
+            for table in cell.tables:
+                await asyncio.wait_for(store.notify_on(
+                    int(table["id"]), TableStateType.READY), 120)
             self.pipeline_ready_s = time.perf_counter() - t0
             self.stamps["traffic_go_s"] = time.perf_counter() - self.t_start
             self.source.send("go")
@@ -613,32 +719,28 @@ class Run:
     def finish_cdc(self, report: dict, destination, opened, closed) -> dict:
         import numpy as np
 
-        import pgbench
         import reference
-        from source import layout_of
 
         cell = self.cell
-        layout = layout_of(cell.config, cell.traffic, self.args.seconds)
-        first_aid = int(cell.config["rows"]) + 1
-        cum = np.concatenate(([0], np.cumsum(layout.rows)))
-        sent = [[0, int(cum[report["sent_tx"]])]]
-        need = [[0, int(cum[report["durable_tx"]])]]
+        starts = self.stream.layout.starts
+        sent = int(starts[report["sent_tx"]])
+        need = int(starts[report["durable_tx"]])
         if self.sink is not None:
-            self.sink.send("verify", seed=self.args.seed,
-                           tx_rows=_run_lengths(layout.rows),
-                           first_aid=first_aid, sent=sent, need=need,
+            self.sink.send("verify", config=cell.config_path,
+                           traffic=cell.traffic_path,
+                           rehearse=bool(self.args.rehearse),
+                           seed=self.args.seed, seconds=self.args.seconds,
+                           sent=sent, need=need,
                            t_open=opened["t"], t_close=closed["t"])
             verdict = self.sink.wait_sync("verified")
             if "error" in verdict:
                 raise RuntimeError(f"sink: {verdict['error']}")
             self.sink_service = verdict["service"]
         else:
-            ref = pgbench.accounts_columns(
-                self.args.seed, int(cum[-1]), first_aid)
-            verdict = reference.verify(
-                ref, first_aid, need, sent,
-                destination.received(pgbench.FILLER.decode()),
-                layout.row_coordinates(0, len(layout.rows)))
+            verdict = reference.verify_received(
+                cell.tables, self.table_rows, self.stream, sent, need,
+                destination.received(cell.tables))
+            verdict["numbers"]["wrong_rows"] += destination.other_events
             self.sink_service = None
         numbers = dict(verdict["numbers"])
         metrics, extra = {}, {}
@@ -667,16 +769,13 @@ class Run:
         """Whole copies back to back, each a new Pipeline into a fresh
         store and destination, the first one being the warm-up. The copy
         in flight at --seconds is finished and counted."""
-        import numpy as np
-
-        import pgbench
         import reference
         from etl_tpu.models.table_state import TableStateType
 
         cell = self.cell
-        tid = int(cell.config["table"]["id"])
-        n_rows = int(cell.config["rows"])
-        ref = pgbench.accounts_columns(self.args.seed, n_rows)
+        truth = {int(t["id"]): reference.SnapshotIndex(
+            t, self.table_rows.get(int(t["id"])) or []) for t in cell.tables}
+        n_rows = sum(index.n for index in truth.values())
         copies: list = []
         numbers = {k: 0 for k in reference.LIMITS}
         failed = 0
@@ -697,8 +796,9 @@ class Run:
                 if tracing:
                     self.trace_start()
                 try:
-                    await asyncio.wait_for(
-                        store.notify_on(tid, TableStateType.READY), 300)
+                    for table in cell.tables:
+                        await asyncio.wait_for(store.notify_on(
+                            int(table["id"]), TableStateType.READY), 300)
                     ok = True
                 except asyncio.TimeoutError:
                     ok = False
@@ -718,11 +818,8 @@ class Run:
                     raise RuntimeError("the warm-up copy never got ready")
                 continue
             if ok:
-                verdict = reference.verify(
-                    ref, 1, [[0, n_rows]], [[0, n_rows]],
-                    destination.received(pgbench.FILLER.decode()))
-                for k, v in verdict["numbers"].items():
-                    numbers[k] += v
+                reference.check_copies(
+                    truth, destination.received(cell.tables), numbers)
             else:
                 failed += 1
             copies.append({"start": t_started, "ready": t_ready})
@@ -805,16 +902,6 @@ class Run:
         return metrics, breakdown
 
 
-def _run_lengths(rows) -> list:
-    out: list = []
-    for r in rows.tolist():
-        if out and out[-1][0] == r:
-            out[-1][1] += 1
-        else:
-            out.append([int(r), 1])
-    return out
-
-
 def main(argv=None) -> int:
     t_start = time.perf_counter() - process_age_s()
     ap = argparse.ArgumentParser()
@@ -829,8 +916,13 @@ def main(argv=None) -> int:
                     help="play this mix file in place of the cell's own "
                          "(sweep_paced.py; trying a new mix before a PR "
                          "adds it)")
+    ap.add_argument("--config-file", default=None,
+                    help="run this configuration file in place of the "
+                         "cell's own: a deployment is rehearsed, and run on "
+                         "the chip, before a PR makes it a cell")
     args = ap.parse_args(argv)
-    cell = Cell(args.workload, args.rehearse, args.traffic_file)
+    cell = Cell(args.workload, args.rehearse, args.traffic_file,
+                args.config_file)
     # the program has to be there before anything is started or printed
     import etl_tpu  # noqa: F401
 
@@ -848,9 +940,8 @@ def main(argv=None) -> int:
         if args.trace:
             metrics, breakdown = run.per_layer(device)
         else:
-            metrics = {k: {"value": v, "unit": next(
-                m["unit"] for m in cell.end_to_end if m["name"] == k)}
-                for k, v in result["metrics"].items()}
+            metrics = {k: {"value": v, "unit": cell.units[k]}
+                       for k, v in result["metrics"].items()}
             metrics["setup_s"] = {"value": run.setup_s, "unit": "s"}
             breakdown = None
     finally:

@@ -2,8 +2,9 @@
 (never imports JAX) that, inside the window, only reads each request's body,
 keeps the bytes and answers 200. It stamps its own service time — body
 complete to response written — so the cell can show the sink did not set the
-pace. Parsing the TSV bodies and comparing them with the reference happens
-on the `verify` command, after the window has closed.
+pace. Parsing the TSV bodies — each table's by that table's columns from the
+configuration's file — and comparing them with the reference happens on the
+`verify` command, after the window has closed.
 
 Control: JSON lines on stdin (`verify`, `quit`), events on stdout.
 """
@@ -15,6 +16,7 @@ import asyncio
 import io
 import json
 import os
+import re
 import sys
 import threading
 import time
@@ -25,7 +27,7 @@ import numpy as np
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
 
-import pgbench  # noqa: E402
+import oplog  # noqa: E402
 import reference  # noqa: E402
 
 COMMIT_LOG = "_etl_commit_log"  # the destination's own bookkeeping table
@@ -88,45 +90,116 @@ class Sink:
                 "service_s": float(sum(s for s, _ in inside)),
                 "bytes": int(sum(n for _, n in inside))}
 
-    def received(self) -> dict:
-        """The data INSERTs' rows as columns, parsed from the kept bytes."""
-        import pyarrow as pa
-        import pyarrow.compute as pc
-        from pyarrow import csv
+    def received(self, tables: list, database: str) -> dict:
+        """{table id: {"copy": rows or None, "cdc": rows or None}}: the data
+        INSERTs' rows of each table, parsed from the kept bytes in the order
+        they came. A row whose sequence key has no commit is a copied row."""
+        out = {}
+        for table in tables:
+            name = "`%s`.`%s`" % (database, _escaped(table["name"]))
+            bodies = [body for _, _, q, body in self.requests
+                      if q.startswith(f"INSERT INTO {name} ")]
+            rows = _parse_tsv(table, b"".join(bodies)) if bodies else None
+            out[int(table["id"])] = {"copy": None, "cdc": None}
+            if rows is None:
+                continue
+            copied = rows["commit_lsn"] == 0
+            for key, mask in (("copy", copied), ("cdc", ~copied)):
+                if mask.all():
+                    out[int(table["id"])][key] = rows
+                elif mask.any():
+                    out[int(table["id"])][key] = _only(rows, mask)
+        return out
 
-        bodies = [body for _, _, q, body in self.requests
-                  if q.startswith("INSERT INTO") and COMMIT_LOG not in q]
-        names = ["aid", "bid", "abalance", "filler", "change", "seq"]
-        if not bodies:
-            z = np.zeros(0, dtype=np.int64)
-            return {"aid": z, "bid": z, "abalance": z, "commit_lsn": z,
-                    "tx_ordinal": z, "bad_text_rows": 0}
-        tab = csv.read_csv(
-            io.BytesIO(b"".join(bodies)),
-            read_options=csv.ReadOptions(column_names=names),
-            parse_options=csv.ParseOptions(delimiter="\t", quote_char=False,
-                                           escape_char=False),
-            convert_options=csv.ConvertOptions(column_types={
-                "aid": pa.int64(), "bid": pa.int64(), "abalance": pa.int64(),
-                "filler": pa.string(), "change": pa.string(),
-                "seq": pa.string()}, strings_can_be_null=False))
-        bad = pc.sum(pc.or_(
-            pc.not_equal(tab["filler"], pgbench.FILLER.decode()),
-            pc.not_equal(tab["change"], "UPSERT"))).as_py() or 0
-        seq = tab["seq"].combine_chunks()
-        n = len(seq)
-        lengths = pc.binary_length(seq)
-        if n and (pc.min(lengths).as_py() != SEQ_WIDTH
-                  or pc.max(lengths).as_py() != SEQ_WIDTH):
-            raise ValueError("a sequence key is not 50 characters wide")
-        raw = np.frombuffer(seq.buffers()[2], dtype=np.uint8)
-        start = seq.offset * SEQ_WIDTH
-        text = raw[start:start + n * SEQ_WIDTH].reshape(n, SEQ_WIDTH)
-        return {"aid": tab["aid"].to_numpy(), "bid": tab["bid"].to_numpy(),
-                "abalance": tab["abalance"].to_numpy(),
-                "commit_lsn": _hex16(text[:, 0:16]),
-                "tx_ordinal": _hex16(text[:, 17:33]),
-                "bad_text_rows": int(bad)}
+
+def _only(rows: dict, mask: np.ndarray) -> dict:
+    """The parsed rows that `mask` keeps."""
+    out = dict(rows)
+    for k in ("change", "commit_lsn", "tx_ordinal"):
+        out[k] = rows[k][mask]
+    out["cols"] = [tuple(None if a is None else reference._pick(a, mask)
+                         for a in c) for c in rows["cols"]]
+    return out
+
+
+def _escaped(name: str) -> str:
+    """The destination's table name: `schema_table`, underscores doubled."""
+    schema, table = name.split(".")
+    return schema.replace("_", "__") + "_" + table.replace("_", "__")
+
+
+_TSV_UNESCAPE = {"\\\\": "\\", "\\t": "\t", "\\n": "\n", "\\r": "\r"}
+
+
+def _parse_tsv(table: dict, body: bytes) -> dict:
+    """One table's TabSeparated rows as plain columns (`reference.py`'s
+    form): the table's columns by their types, then the change label and
+    the sequence key `{commit:016x}/{tx_ordinal:016x}/{ordinal:016x}`."""
+    import pyarrow as pa
+    import pyarrow.compute as pc
+    from pyarrow import csv
+
+    columns = table["columns"]
+    names = [c["name"] for c in columns] + ["_change", "_seq"]
+    arrow_type = {"bool": pa.string(), "int2": pa.int64(), "int4": pa.int64(),
+                  "int8": pa.int64(), "float8": pa.float64(),
+                  "date": pa.date32(), "timestamp": pa.timestamp("us"),
+                  "timestamptz": pa.timestamp("us")}
+    tab = csv.read_csv(
+        io.BytesIO(body),
+        read_options=csv.ReadOptions(column_names=names),
+        parse_options=csv.ParseOptions(delimiter="\t", quote_char=False,
+                                       escape_char=False),
+        convert_options=csv.ConvertOptions(
+            column_types={c["name"]: arrow_type.get(c["type"], pa.string())
+                          for c in columns}
+            | {"_change": pa.string(), "_seq": pa.string()},
+            null_values=["\\N"], strings_can_be_null=True))
+    n = len(tab)
+    cols = []
+    for c in columns:
+        col = tab[c["name"]].combine_chunks()
+        null = col.is_null().to_numpy(zero_copy_only=False)
+        kind = c["type"]
+        if kind in reference.TEXT_TYPES:
+            if pc.any(pc.match_substring(col, "\\")).as_py():
+                col = pa.array([None if v is None else re.sub(
+                    r"\\[\\tnr]", lambda m: _TSV_UNESCAPE[m.group(0)], v)
+                    for v in col.to_pylist()], type=pa.string())
+            values = col
+        elif kind == "numeric" or kind not in arrow_type:
+            values = col.to_pylist()
+        elif kind == "bool":
+            values = pc.equal(col, "true").fill_null(False).to_numpy(
+                zero_copy_only=False)
+        elif kind == "float8":
+            values = col.fill_null(0.0).to_numpy(zero_copy_only=False)
+        else:
+            if kind == "date":
+                col = col.cast(pa.int32())
+            values = col.cast(pa.int64()).fill_null(0).to_numpy(
+                zero_copy_only=False)
+        cols.append((values, null, None))
+    label = tab["_change"].combine_chunks()
+    seq = tab["_seq"].combine_chunks()
+    lengths = pc.binary_length(seq)
+    if n and (pc.min(lengths).as_py() != SEQ_WIDTH
+              or pc.max(lengths).as_py() != SEQ_WIDTH):
+        raise ValueError("a sequence key is not 50 characters wide")
+    raw = np.frombuffer(seq.buffers()[2], dtype=np.uint8)
+    start = seq.offset * SEQ_WIDTH
+    text = raw[start:start + n * SEQ_WIDTH].reshape(n, SEQ_WIDTH)
+    # a label that is neither UPSERT nor DELETE matches no change kind
+    change = np.where(
+        pc.equal(label, "UPSERT").fill_null(False).to_numpy(
+            zero_copy_only=False), reference.GOT_UPSERT,
+        np.where(pc.equal(label, "DELETE").fill_null(False).to_numpy(
+            zero_copy_only=False), reference.GOT_DELETE, 255)
+    ).astype(np.uint8)
+    return {"cols": cols, "change": change,
+            "commit_lsn": _hex16(text[:, 0:16]),
+            "tx_ordinal": _hex16(text[:, 17:33]),
+            "old": None, "delete_is_key": None}
 
 
 def _hex16(chars: np.ndarray) -> np.ndarray:
@@ -137,17 +210,25 @@ def _hex16(chars: np.ndarray) -> np.ndarray:
 
 
 def verify(sink: Sink, cmd: dict) -> dict:
-    """Hold what the sink received to the reference. `cmd` carries the
-    seed, the stream's layout (run lengths of transaction sizes) and the
-    row ranges that were sent and that the flush position has passed."""
-    rows = np.asarray(cmd["tx_rows"], dtype=np.int64)
-    layout = pgbench.TxLayout.build(
-        np.repeat(rows[:, 0], rows[:, 1]), cmd["first_aid"])
-    ref = pgbench.accounts_columns(cmd["seed"], int(layout.rows.sum()),
-                                   cmd["first_aid"])
-    out = reference.verify(ref, cmd["first_aid"], cmd["need"], cmd["sent"],
-                           sink.received(),
-                           layout.row_coordinates(0, len(layout.rows)))
+    """Hold what the sink received to the reference. `cmd` names the
+    configuration and the mix, the seed and the seconds — the generator
+    makes the log again from them — and how many of the log's events were
+    sent and how many the flush position has passed."""
+    with open(cmd["config"]) as f:
+        config = json.load(f)
+    with open(cmd["traffic"]) as f:
+        traffic = json.load(f)
+    if cmd["rehearse"]:
+        config.update(config.get("rehearsal", {}))
+        traffic.update(traffic.get("rehearsal", {}))
+    generator = oplog.load_generator(config, cmd["config"])
+    tables = oplog.tables_of(config)
+    out = reference.verify_received(
+        tables, generator.snapshot(config, traffic, cmd["seed"]),
+        generator.stream(config, traffic, cmd["seed"], cmd["seconds"]),
+        cmd["sent"], cmd["need"],
+        sink.received(tables, config["destination"].get("database",
+                                                        "default")))
     out["service"] = sink.service(cmd["t_open"], cmd["t_close"])
     return out
 

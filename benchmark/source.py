@@ -5,9 +5,10 @@ It answers the pipeline's catalog, slot and snapshot queries with the
 program's own `FakePgServer` (startup, simple/extended query, slot
 management), and replaces the two hot paths with prebuilt bytes:
 
-  * COPY OUT sends CopyData rows rendered during set-up (`pgbench.py`);
-  * START_REPLICATION on the apply slot plays one traffic mix: every
-    transaction is one prebuilt buffer, written on its due time (paced) or
+  * COPY OUT sends each table's CopyData rows, rendered during set-up from
+    the deployment's snapshot (`wire.py`, `deployments/<generator>.py`);
+  * START_REPLICATION on the apply slot plays one traffic mix over the
+    deployment's operation log: every transaction is one prebuilt buffer, written on its due time (paced) or
     as fast as the socket takes it (backlog). Standby status updates are
     read by a task of their own and stamped on arrival, on the same clock
     as the due times.
@@ -22,6 +23,7 @@ import argparse
 import asyncio
 import json
 import os
+import re
 import struct
 import sys
 import threading
@@ -34,11 +36,12 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
 sys.path.insert(0, os.path.dirname(HERE))
 
-import pgbench  # noqa: E402
+import oplog  # noqa: E402
+import wire  # noqa: E402
 
 CLOCK_US = 1_700_000_000_000_000  # fixed send/commit stamp inside frames
-RENDER_CHUNK_TX = 512
-RENDER_CHUNK_ROWS = RENDER_CHUNK_TX * 500
+RENDER_CHUNK_ROWS = 256_000
+COPY_CHUNK_ROWS = 250_000
 RENDER_THREADS = 2
 COPY_SEND_BYTES = 1 << 18
 
@@ -59,12 +62,12 @@ class Plan:
     prebuilt buffers, and the readings taken while it plays."""
 
     def __init__(self, config: dict, traffic: dict, seed: int,
-                 seconds: float):
+                 seconds: float, generator):
         self.config, self.traffic, self.seed = config, traffic, seed
         self.seconds = float(seconds)
         self.kind = traffic["kind"]
-        self.table_id = int(config["table"]["id"])
-        self.first_aid = int(config["rows"]) + 1
+        self.tables = oplog.tables_of(config)
+        self.generator = generator
         self.go = asyncio.Event()
         self.stop = asyncio.Event()
         self.flush_t: list = []
@@ -78,45 +81,54 @@ class Plan:
         self.capped = False
         self.copy_serving_s = self.copy_blocked_s = 0.0
         self.copies_served = 0
-        self.layout = layout_of(config, traffic, seconds)
+        self.stream = self.layout = None
         self.bufs: list = []
         self.payload_bytes = 0
-        self.copy_blob = self.copy_offsets = None
+        self.copies: dict = {}  # table name -> (blob, row offsets)
 
     def render(self) -> None:
         t0 = time.perf_counter()
-        if self.kind == "copy":
-            cols = pgbench.accounts_columns(self.seed, int(self.config["rows"]))
+        extra = getattr(self.generator, "TEXT_BLOCKS", None)
+        self.stream = self.generator.stream(self.config, self.traffic,
+                                            self.seed, self.seconds)
+        if self.stream is not None:
+            self.layout = self.stream.layout
+        snapshot = self.generator.snapshot(self.config, self.traffic,
+                                           self.seed)
+        for table in self.tables:
+            cols = snapshot.get(int(table["id"]))
+            n = oplog.n_rows(cols) if cols else 0
             blobs, offsets, at = [], [np.zeros(1, dtype=np.int64)], 0
-            for i in range(0, len(cols[0]), 250_000):
-                blob, off = pgbench.render_copy_rows(
-                    tuple(c[i:i + 250_000] for c in cols))
+            for i in range(0, n, COPY_CHUNK_ROWS):
+                rows = slice(i, min(n, i + COPY_CHUNK_ROWS))
+                blob, off = wire.render_copy_rows(
+                    table, [c.pick(rows) for c in cols],
+                    rows.stop - rows.start, extra)
                 blobs.append(blob)
                 offsets.append(off[1:] + at)
                 at += len(blob)
-            self.copy_blob = memoryview(np.concatenate(blobs))
-            self.copy_offsets = np.concatenate(offsets)
-        elif self.layout is not None:
-            n_rows = int(self.layout.rows.sum())
-            cols = pgbench.accounts_columns(self.seed, n_rows, self.first_aid)
-            n_tx = len(self.layout.rows)
-            # equal chunks (whole bulk cycles), so the renderer's scratch
-            # arrays are made once
-            every = int(self.traffic.get("bulk_every_transactions", 0))
-            step = RENDER_CHUNK_TX
-            if every:
-                cycle_rows = int(self.layout.rows[:every].sum())
-                step = every * max(1, RENDER_CHUNK_ROWS // cycle_rows)
+            self.copies[table["name"]] = (
+                memoryview(np.concatenate(blobs)) if blobs else b"",
+                np.concatenate(offsets))
+        if self.stream is not None:
+            stream = self.stream
+            kinds = wire.old_kinds(self.tables, stream)
+            local = stream.local_index()
+            oids = {**wire.TYPE_OIDS,
+                    **getattr(self.generator, "TYPE_OIDS", {})}
+            relations = [wire.relation_payload(t, oids) for t in self.tables]
+            cuts = chunk_cuts(self.layout.rows)
 
-            def chunk(k0: int):
-                return pgbench.render_transactions(
-                    self.table_id, self.layout, cols, k0,
-                    min(k0 + step, n_tx), CLOCK_US, k0 == 0)
+            def chunk(j: int):
+                return wire.render_transactions(
+                    self.tables, stream, kinds, local, int(cuts[j]),
+                    int(cuts[j + 1]), CLOCK_US,
+                    relations if j == 0 else None, extra)
 
             # numpy releases the interpreter lock in the large copies, so
             # two threads render nearly twice as fast on the source's cores
             with ThreadPoolExecutor(RENDER_THREADS) as pool:
-                for bufs, nbytes in pool.map(chunk, range(0, n_tx, step)):
+                for bufs, nbytes in pool.map(chunk, range(len(cuts) - 1)):
                     self.bufs += bufs
                     self.payload_bytes += nbytes
         self.render_s = time.perf_counter() - t0
@@ -216,8 +228,8 @@ class Plan:
 
     @property
     def n_paced(self) -> int:
-        return self.n_warm + round(
-            self.seconds * float(self.traffic["transactions_per_second"]))
+        """The generator makes one transaction for each due time."""
+        return len(self.layout.rows)
 
     # -- the report ----------------------------------------------------------
 
@@ -232,6 +244,11 @@ class Plan:
                 out["final_flush_lsn"]))
             out["payload_bytes_per_row"] = \
                 self.payload_bytes / max(1, int(lay.rows.sum()))
+            out["table_event_share"] = {
+                self.tables[t]["name"]: float(n) / max(1, len(
+                    self.stream.table))
+                for t, n in enumerate(np.bincount(
+                    self.stream.table, minlength=len(self.tables)))}
         if self.kind == "backlog":
             cum = np.concatenate(([0], np.cumsum(lay.rows)))
             if self.t_open is None or self.t_close is None:
@@ -275,6 +292,25 @@ class Plan:
         return out
 
 
+def chunk_cuts(rows: np.ndarray) -> np.ndarray:
+    """Transaction indices at which the render is cut into chunks of
+    RENDER_CHUNK_ROWS events or so. Where the transactions' sizes repeat
+    with a period, every chunk is a whole number of periods and so of one
+    size, and the renderer's scratch arrays are made once."""
+    n = len(rows)
+    period = next((p for p in range(1, min(n // 2, 2048) + 1)
+                   if np.array_equal(rows[p:], rows[:-p])), 0)
+    if period:
+        step = period * max(1, RENDER_CHUNK_ROWS
+                            // max(1, int(rows[:period].sum())))
+        return np.append(np.arange(0, n, step), n)
+    cum = np.cumsum(rows)
+    total = int(cum[-1]) if n else 0
+    return np.unique(np.concatenate((
+        [0], np.searchsorted(cum, np.arange(
+            RENDER_CHUNK_ROWS, total, RENDER_CHUNK_ROWS)) + 1, [n])))
+
+
 def _pcts(lag: np.ndarray) -> dict:
     ms = np.sort(lag) * 1e3
     if not len(ms):
@@ -283,50 +319,36 @@ def _pcts(lag: np.ndarray) -> dict:
             "p95": float(ms[min(len(ms) - 1, int(len(ms) * 0.95))])}
 
 
-def layout_of(config: dict, traffic: dict, seconds: float):
-    """The transaction layout a mix plays, from its parameters alone — the
-    harness builds the same one to know what the sink has to hold.
-
-    Every transaction has `transaction_rows` rows, except that every
-    `bulk_every_transactions`-th has `bulk_rows`: a bulk insert among the
-    small ones, which seals at the device-routed size. A backlog holds
-    `backlog_events_per_second` events for every second of warm-up and
-    window and one more; a paced mix one transaction per due time."""
-    first_aid = int(config["rows"]) + 1
-    if traffic["kind"] not in ("backlog", "paced"):
-        return None
-    tx_rows = int(traffic["transaction_rows"])
-    every = int(traffic.get("bulk_every_transactions", 0))
-    bulk = int(traffic.get("bulk_rows", 0)) if every else 0
-    if traffic["kind"] == "backlog":
-        events = float(traffic["backlog_events_per_second"]) * (
-            float(traffic["warmup_seconds"]) + float(seconds) + 1.0)
-        mean = tx_rows + (bulk - tx_rows) / every if every else tx_rows
-        n = int(-(-events // mean))
-    else:
-        rate = float(traffic["transactions_per_second"])
-        n = round(float(traffic["warmup_seconds"]) * rate) \
-            + round(float(seconds) * rate)
-    rows = np.full(n, tx_rows, dtype=np.int64)
-    if every:
-        rows[every - 1::every] = bulk
-    return pgbench.TxLayout.build(rows, first_aid)
-
-
-def table_schema(table: dict):
-    """The program's TableSchema of a configuration's `table` entry."""
+def table_schema(table: dict, type_oids: "dict | None" = None):
+    """The program's TableSchema of one of a configuration's tables. A
+    column's `key` is true or its 1-based place in the key; `nullable`
+    defaults to "not a key column"; the type modifier is the file's
+    `modifier`, or follows from `text_bytes` / `precision` and `scale`."""
     from etl_tpu.models import ColumnSchema, Oid, TableName, TableSchema
 
-    oids = {"int4": Oid.INT4, "bpchar": Oid.BPCHAR}
+    def oid(kind: str) -> int:
+        if type_oids and kind in type_oids:
+            return int(type_oids[kind])
+        return getattr(Oid, kind.upper())
+
+    def modifier(c: dict) -> dict:
+        if "modifier" in c:
+            return {"modifier": int(c["modifier"])}
+        if c["type"] in ("bpchar", "varchar") and "text_bytes" in c:
+            return {"modifier": int(c["text_bytes"]) + 4}
+        if c["type"] == "numeric" and "precision" in c:
+            return {"modifier": ((int(c["precision"]) << 16)
+                                 | int(c.get("scale", 0))) + 4}
+        return {}
+
+    ordinal = {i: k + 1 for k, i in enumerate(oplog.key_indices(table))}
     namespace, name = table["name"].split(".")
     return TableSchema(
         int(table["id"]), TableName(namespace, name),
-        tuple(ColumnSchema(c["name"], oids[c["type"]],
-                           nullable=not c.get("key", False),
-                           primary_key_ordinal=1 if c.get("key") else None,
-                           **({"modifier": c["modifier"]}
-                              if "modifier" in c else {}))
-              for c in table["columns"]))
+        tuple(ColumnSchema(c["name"], oid(c["type"]),
+                           nullable=bool(c.get("nullable", i not in ordinal)),
+                           primary_key_ordinal=ordinal.get(i), **modifier(c))
+              for i, c in enumerate(table["columns"])))
 
 
 def make_server(plan: Plan):
@@ -334,40 +356,46 @@ def make_server(plan: Plan):
     from etl_tpu.postgres.fake import FakeDatabase
     from etl_tpu.testing import fake_pg_server as fps
 
-    schema = table_schema(plan.config["table"])
     db = FakeDatabase()
-    db.create_table(schema, rows=[])
+    oids = getattr(plan.generator, "TYPE_OIDS", None)
+    for table in plan.tables:
+        db.create_table(table_schema(table, oids), rows=[])
+        db.set_replica_identity(int(table["id"]),
+                                table.get("replica_identity", "d"))
     db.create_publication(plan.config["pipeline"]["publication"],
-                          [plan.table_id])
-    db._lsn = pgbench.BASE_LSN
-    snapshot_rows = int(plan.config["rows"]) if plan.kind == "copy" else 0
+                          [int(t["id"]) for t in plan.tables])
+    db._lsn = oplog.BASE_LSN
+    snapshot_rows = {int(t["id"]): len(plan.copies[t["name"]][1]) - 1
+                     for t in plan.tables}
 
     class BenchPgServer(fps.FakePgServer):
         async def _try_handle(self, sess, norm, sql):
-            if "reltuples" in norm and "FROM pg_class WHERE oid" in norm:
+            m = re.search(r"FROM pg_class WHERE oid = (\d+)", norm)
+            if m and "reltuples" in norm:
                 # planner statistics of the snapshot: 64 rows to a page,
                 # as the base server counts them
+                n = snapshot_rows.get(int(m.group(1)), 0)
                 self._send_rows(sess.writer, ["reltuples", "relpages"],
-                                [[str(snapshot_rows),
-                                  str(max(1, snapshot_rows // 64))]])
+                                [[str(n), str(max(1, n // 64))]])
                 return True
             return await super()._try_handle(sess, norm, sql)
 
         async def _copy_out(self, sess, m):
             w = sess.writer
+            blob, off = plan.copies.get(f"{m.group(2)}.{m.group(3)}",
+                                        (b"", np.zeros(1, dtype=np.int64)))
+            n = len(off) - 1
             lo = int(m.group(4)) * 64 if m.group(4) else 0
-            hi = min(int(m.group(5)) * 64 if m.group(5) else snapshot_rows,
-                     snapshot_rows)
+            hi = min(int(m.group(5)) * 64 if m.group(5) else n, n)
             n_cols = len(m.group(1).split(","))
             w.write(fps._msg(b"H", struct.pack(">bh", 0, n_cols)
                              + b"\x00\x00" * n_cols))
             t_begin = time.perf_counter()
             blocked = 0.0
             if hi > lo:
-                off = plan.copy_offsets
                 at, end = int(off[lo]), int(off[hi])
                 while at < end:
-                    w.write(plan.copy_blob[at:min(end, at + COPY_SEND_BYTES)])
+                    w.write(blob[at:min(end, at + COPY_SEND_BYTES)])
                     at += COPY_SEND_BYTES
                     t0 = time.perf_counter()
                     await w.drain()
@@ -419,7 +447,7 @@ def make_server(plan: Plan):
                 waits.add(asyncio.ensure_future(until.wait()))
             try:
                 while not any(t.done() for t in waits):
-                    w.write(pgbench.keepalive_frame(
+                    w.write(wire.keepalive_frame(
                         int(db.current_lsn), CLOCK_US, True))
                     await w.drain()
                     await asyncio.wait(waits, timeout=0.05,
@@ -487,7 +515,8 @@ def main(argv=None) -> int:
         traffic.update(traffic.get("rehearsal", {}))
 
     async def amain() -> None:
-        plan = Plan(config, traffic, args.seed, args.seconds)
+        plan = Plan(config, traffic, args.seed, args.seconds,
+                    oplog.load_generator(config, args.config))
         plan.render()
         loop = asyncio.get_running_loop()
         commands: asyncio.Queue = asyncio.Queue()

@@ -190,7 +190,8 @@ def test_every_new_metric_prints_in_a_rehearsal(workload, on_cpu_count):
     # silent there, every other new metric reports a finite number
     on_cpu = [name for name, reader in expected
               if reader != "device_module_stat"]
-    assert len(on_cpu) == on_cpu_count
+    # at least the recorder's own (PR 25): later PRs append theirs
+    assert len(on_cpu) >= on_cpu_count
     for name in on_cpu:
         value = line["metrics"]["rehearsal." + name]["value"]
         assert value == value and abs(value) != float("inf"), name
