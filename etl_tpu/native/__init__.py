@@ -1,11 +1,12 @@
-"""Native host components: the pgoutput framer and the CopyData block
-scan (C, via ctypes).
+"""Native host components: the pgoutput framer, the CopyData block scan
+and the COPY chunk scan (C, via ctypes).
 
 Builds `framer.c` with the system compiler on first import (cached as
 `_framer-<hash>.so`); falls back to a pure-Python walker with identical
 outputs when no compiler is available. `frame_pgoutput` is the framer's
 entry point (see ops/wal.py for the staging layer that consumes it);
-`scan_copy_data` is the COPY stream's (postgres/wire.py `copy_out`).
+`scan_copy_data` is the COPY stream's (postgres/wire.py `copy_out`);
+`scan_copy_chunk` is the copy staging's (ops/staging.py `stage_copy_chunk`).
 """
 
 from __future__ import annotations
@@ -25,6 +26,10 @@ FLAG_VALUE, FLAG_NULL, FLAG_TOAST, FLAG_BINARY = 0, 1, 2, 3
 # why scan_copy_data stopped (framer.c): the block ended; a message the
 # per-message logic has to read
 COPY_SCAN_MORE, COPY_SCAN_SLOW = 0, 1
+# what scan_copy_chunk found (framer.c): well-formed rows; a delimiter
+# count that is not rows x columns; the count right and a row ragged
+COPY_STAGE_OK, COPY_STAGE_COUNT, COPY_STAGE_RAGGED = 0, 1, 2
+_COPY_STAGE_FULL = 3  # more rows than the outputs it was given hold
 
 _lib = None
 _build_error: str | None = None
@@ -91,6 +96,14 @@ def _load() -> ctypes.CDLL | None:
         lib.etl_scan_copy_data.argtypes = [
             ctypes.c_char_p, ctypes.c_int64,  # buf, buf_len
             ctypes.c_void_p, ctypes.c_void_p,  # out, res[3]
+        ]
+        lib.etl_stage_copy_chunk.restype = ctypes.c_int32
+        lib.etl_stage_copy_chunk.argtypes = [
+            ctypes.c_char_p, ctypes.c_int64,  # buf, buf_len
+            ctypes.c_int32, ctypes.c_int64,  # n_cols, max_rows
+            ctypes.c_void_p, ctypes.c_void_p,  # offsets, lengths [max,C]
+            ctypes.c_void_p, ctypes.c_void_p,  # nulls [max,C], fallback
+            ctypes.c_void_p,  # res[3]
         ]
         _lib = lib
     except Exception as e:  # pragma: no cover - depends on toolchain
@@ -286,6 +299,54 @@ def _scan_copy_data_py(block: bytes) -> tuple[bytes, int, int, int]:
         parts.append(block[pos + 5:pos + 5 + payload])
         pos += 5 + payload
     return b"".join(parts), pos, len(parts), stop
+
+
+def scan_copy_chunk(chunk: bytes, n_cols: int):
+    """One C pass over a newline-terminated chunk of COPY text rows.
+
+    Returns (status, n_rows, n_delims, offsets, lengths, nulls, fallback),
+    or None where the library is not loaded: the caller then runs its
+    numpy twin, which gives the same. `status` is COPY_STAGE_OK, _COUNT or
+    _RAGGED; `n_rows` and `n_delims` count the chunk's newlines and its
+    tabs and newlines. On OK the first `n_rows` rows of int32 / int32 /
+    bool [rows, n_cols] hold each field's start, length (0 where NULL) and
+    whether it is a bare \\N, and `fallback` the ascending int64 rows that
+    hold any other backslash; the caller cuts the matrices to the rows it
+    keeps (`ndarray.resize`, in place).
+
+    The scan counts the rows as it goes, so its outputs are sized first
+    from a guess — the newlines in the chunk's first 8 KiB, scaled to its
+    length, and a quarter more — and, where the scan runs out of them
+    (rows that get much shorter further on), from a bound it cannot pass:
+    a row takes at least `n_cols` bytes. (The bound alone is nine bytes of
+    address space per byte of chunk ÷ `n_cols`: tens of megabytes mapped
+    and unmapped a call, which beside the pipeline's other threads costs
+    more than the scan does.)
+
+    Runs on the event loop, so it never builds the library: the copy has
+    loaded it off the loop (`native_available()`, runtime/copy.py
+    `parallel_table_copy`)."""
+    lib = _lib
+    if lib is None:
+        return None
+    bound = len(chunk) // max(n_cols, 1) + 1
+    sample = min(len(chunk), 8192) or 1
+    guess = len(chunk) * chunk.count(b"\n", 0, sample) * 5 // (4 * sample) + 64
+    res = (ctypes.c_int64 * 3)()
+    p = _ptr
+    for max_rows in (min(guess, bound), bound):
+        shape = (max_rows, max(n_cols, 0))
+        offsets = np.empty(shape, dtype=np.int32)
+        lengths = np.empty(shape, dtype=np.int32)
+        nulls = np.empty(shape, dtype=np.bool_)
+        fallback = np.empty(max_rows, dtype=np.int64)
+        status = lib.etl_stage_copy_chunk(
+            chunk, len(chunk), n_cols, max_rows, p(offsets), p(lengths),
+            p(nulls), p(fallback), res)
+        if status != _COPY_STAGE_FULL:
+            break
+    return (status, res[0], res[1], offsets, lengths, nulls,
+            fallback[:res[2]].copy())
 
 
 def pack_bmat(data, offsets, lengths, col_idx, widths, bmat, lens_out) -> bool:

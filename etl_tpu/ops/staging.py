@@ -11,9 +11,11 @@ device kernels consume:
     toast    bool[R, C]           TOAST-unchanged ('u' tuple kind)
 
 Row counts are bucketed to powers of two so jit caches stay small; column
-count C is static per schema. The COPY path is fully vectorized numpy
-(the memchr/SIMD analogue of reference codec/table_row.rs:13-53); rows
-containing escape sequences are flagged for the CPU fallback decoder.
+count C is static per schema. The COPY path finds its field boundaries in
+one scan of the chunk (native/framer.c `etl_stage_copy_chunk`, the
+memchr/SIMD analogue of reference codec/table_row.rs:13-53; vectorized
+numpy where the library is not built); rows containing escape sequences
+are flagged for the CPU fallback decoder.
 """
 
 from __future__ import annotations
@@ -25,6 +27,8 @@ from typing import Sequence
 import numpy as np
 
 from ..models.errors import ErrorKind, EtlError
+from ..native import (COPY_STAGE_COUNT, COPY_STAGE_OK, COPY_STAGE_RAGGED,
+                      scan_copy_chunk)
 from ..postgres.codec.pgoutput import (TUPLE_NULL, TUPLE_TEXT,
                                        TUPLE_UNCHANGED_TOAST, TupleData)
 
@@ -201,9 +205,11 @@ _NULL_FIELD_BYTES = (92, 78)  # "\\N"
 
 
 def stage_copy_chunk(chunk: bytes, n_cols: int) -> StagedBatch:
-    """Stage a chunk of COPY text rows (newline-terminated) with a fully
-    vectorized delimiter scan. Rows whose fields contain backslash escapes
-    (other than a bare \\N null) are routed to `cpu_fallback_rows`."""
+    """Stage a chunk of COPY text rows (newline-terminated): one scan of
+    its bytes for the field boundaries, in C where the native library is
+    loaded (`native.scan_copy_chunk`) and in numpy where it is not. Rows
+    whose fields contain backslash escapes (other than a bare \\N null)
+    are routed to `cpu_fallback_rows`."""
     if not chunk:
         return StagedBatch(np.zeros(0, np.uint8), np.zeros((0, n_cols), np.int32),
                            np.zeros((0, n_cols), np.int32),
@@ -212,21 +218,49 @@ def stage_copy_chunk(chunk: bytes, n_cols: int) -> StagedBatch:
     if not chunk.endswith(b"\n"):
         chunk += b"\n"
     data = np.frombuffer(chunk, dtype=np.uint8)
+    scanned = scan_copy_chunk(chunk, n_cols)
+    if scanned is None:
+        scanned = _scan_copy_chunk_np(data, n_cols)
+    status, n_rows, n_delims, offsets, lengths, nulls, fallback = scanned
+    # each row must contribute exactly n_cols delimiters (C-1 tabs + 1 nl)
+    if status == COPY_STAGE_COUNT:
+        raise EtlError(
+            ErrorKind.COPY_FORMAT_INVALID,
+            f"COPY chunk: {n_delims} delimiters for {n_rows} rows × "
+            f"{n_cols} cols")
+    if status == COPY_STAGE_RAGGED:
+        raise EtlError(ErrorKind.COPY_FORMAT_INVALID,
+                       "COPY chunk: ragged rows (tab/newline mismatch)")
+
+    cap_rows = bucket_rows(n_rows)
+
+    def padrc(a, fill=0):
+        # in place: the C scan's matrices are cut from the bound they
+        # were sized to, the twin's grow by the padding rows
+        a.resize((cap_rows, n_cols), refcheck=False)
+        a[n_rows:] = fill
+        return a
+
+    toast = np.zeros((cap_rows, n_cols), dtype=np.bool_)
+    return StagedBatch(data, padrc(offsets), padrc(lengths),
+                       padrc(nulls, True), toast, n_rows,
+                       cpu_fallback_rows=fallback, copy_escapes=True)
+
+
+def _scan_copy_chunk_np(data: np.ndarray, n_cols: int):
+    """`native.scan_copy_chunk` in numpy, for a process without the
+    native library: the same tuple from the same bytes, in some fifteen
+    vectorized passes where the C scan takes one."""
     is_tab = data == 9
     is_nl = data == 10
     delim_pos = np.flatnonzero(is_tab | is_nl)
     nl_pos = np.flatnonzero(is_nl)
     n_rows = len(nl_pos)
-    # each row must contribute exactly n_cols delimiters (C-1 tabs + 1 nl)
     if len(delim_pos) != n_rows * n_cols:
-        raise EtlError(
-            ErrorKind.COPY_FORMAT_INVALID,
-            f"COPY chunk: {len(delim_pos)} delimiters for {n_rows} rows × "
-            f"{n_cols} cols")
+        return COPY_STAGE_COUNT, n_rows, len(delim_pos), None, None, None, None
     ends = delim_pos.reshape(n_rows, n_cols)
     if not np.array_equal(ends[:, -1], nl_pos):
-        raise EtlError(ErrorKind.COPY_FORMAT_INVALID,
-                       "COPY chunk: ragged rows (tab/newline mismatch)")
+        return COPY_STAGE_RAGGED, n_rows, len(delim_pos), None, None, None, None
     starts = np.empty_like(ends)
     starts[:, 0] = np.concatenate(([0], nl_pos[:-1] + 1))
     starts[:, 1:] = ends[:, :-1] + 1
@@ -241,7 +275,7 @@ def stage_copy_chunk(chunk: bytes, n_cols: int) -> StagedBatch:
 
     # escape detection per row: any backslash in the row span that is not
     # a \N (chunks with no backslash at all — the common case — skip the
-    # cumsum, which costs ~5ms/MiB on the copy hot path)
+    # cumsum, which costs ~5ms/MiB)
     is_bs = data == 92
     if is_bs.any():
         bs_cum = np.concatenate(([0], np.cumsum(is_bs)))
@@ -252,21 +286,8 @@ def stage_copy_chunk(chunk: bytes, n_cols: int) -> StagedBatch:
         fallback = np.flatnonzero(bs_in_row != nulls_in_row)
     else:
         fallback = np.zeros(0, dtype=np.int64)
-
-    cap_rows = bucket_rows(n_rows)
-    if cap_rows != n_rows:
-        pad = cap_rows - n_rows
-
-        def padrc(a, fill=0):
-            return np.concatenate([a, np.full((pad, n_cols), fill, a.dtype)])
-
-        offsets = padrc(offsets)
-        lengths = padrc(lengths)
-        nulls = padrc(nulls, True)
-    toast = np.zeros((cap_rows, n_cols), dtype=np.bool_)
-    lengths = np.where(nulls, 0, lengths)
-    return StagedBatch(data, offsets, lengths, nulls, toast, n_rows,
-                       cpu_fallback_rows=fallback, copy_escapes=True)
+    return (COPY_STAGE_OK, n_rows, len(delim_pos), offsets,
+            np.where(nulls, 0, lengths), nulls, fallback)
 
 
 # ---------------------------------------------------------------------------

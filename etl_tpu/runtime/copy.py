@@ -22,6 +22,7 @@ from ..config.pipeline import BatchEngine, PipelineConfig
 from ..models.errors import ErrorKind, EtlError
 from ..models.schema import ReplicatedTableSchema, TableId
 from ..models.table_row import ColumnarBatch
+from ..native import native_available
 from ..ops.engine import DeviceDecoder
 from ..ops.pipeline import DecodePipeline
 from ..ops.staging import stage_copy_chunk
@@ -338,6 +339,9 @@ async def parallel_table_copy(*, source_factory, primary_source,
             await primary_source.estimate_table_stats(schema.id)
         parts = plan_copy_partitions(est_rows, heap_pages, config)
     n_conns = min(config.table_sync_copy.max_connections, len(parts))
+    # the staging scan's C library is built on its first load (a compiler
+    # run): here, off the loop, not at the first chunk
+    await asyncio.to_thread(native_available)
     # nonblocking: cold decode programs compile off-thread while their
     # chunks decode on the oracle — an inline first-touch build of a wide
     # schema would freeze this sync worker past its stall deadline (see

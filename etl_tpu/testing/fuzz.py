@@ -1,4 +1,4 @@
-"""Fuzz targets for the codec parsers + native framer and COPY scan.
+"""Fuzz targets for the codec parsers + native framer and COPY scans.
 
 Reference parity: cargo-fuzz targets `parse_copy_row`, `parse_text_cell`,
 `numeric_text_roundtrip`, `parse_bytea_hex_string`
@@ -359,6 +359,149 @@ def fuzz_copy_stream(rng: random.Random, _ignored=None) -> None:
             "bytes after ReadyForQuery dropped"
 
 
+def stage_copy_chunk_reference(chunk: bytes, n_cols: int):
+    """The obvious staging of a COPY chunk: rows by `split`, fields by
+    `split`. Returns (n_rows, cells, fallback_rows) — cells[r][c] =
+    (offset, length, is_null), length 0 where the field is a bare \\N;
+    fallback_rows those with any other backslash — or the message of the
+    COPY_FORMAT_INVALID that `ops/staging.stage_copy_chunk` raises."""
+    if not chunk:
+        return 0, [], []
+    if not chunk.endswith(b"\n"):
+        chunk += b"\n"
+    lines = chunk.split(b"\n")[:-1]
+    n_delims = chunk.count(b"\t") + len(lines)
+    if n_delims != len(lines) * n_cols:
+        return (f"COPY chunk: {n_delims} delimiters for {len(lines)} rows × "
+                f"{n_cols} cols")
+    pos, cells, fallback = 0, [], []
+    for r, line in enumerate(lines):
+        fields = line.split(b"\t")
+        if len(fields) != n_cols:
+            return "COPY chunk: ragged rows (tab/newline mismatch)"
+        row = []
+        for f in fields:
+            null = f == b"\\N"
+            row.append((pos, 0 if null else len(f), null))
+            pos += len(f) + 1
+        if any(b"\\" in f and f != b"\\N" for f in fields):
+            fallback.append(r)
+        cells.append(row)
+    return len(lines), cells, fallback
+
+
+def check_stage_copy_chunk(chunk: bytes, n_cols: int) -> None:
+    """`stage_copy_chunk` — the C scan or its numpy twin, whichever this
+    process loaded — against the reference above: every array of the
+    StagedBatch by value, dtype and shape, the padding rows of the row
+    bucket included, or the same COPY_FORMAT_INVALID message. Where the C
+    scan is loaded, it and the twin must also agree with each other."""
+    import numpy as np
+
+    from ..models.errors import ErrorKind
+    from ..native import native_available, scan_copy_chunk
+    from ..ops.staging import (_scan_copy_chunk_np, bucket_rows,
+                               stage_copy_chunk)
+
+    native_available()  # what the copy does before its first chunk
+    want = stage_copy_chunk_reference(chunk, n_cols)
+    try:
+        staged = stage_copy_chunk(chunk, n_cols)
+    except EtlError as e:
+        assert e.kind is ErrorKind.COPY_FORMAT_INVALID, e.kind
+        assert e.detail == want, f"{e.detail!r}, want {want!r}"
+        staged = None
+    else:
+        assert not isinstance(want, str), f"staged, want error {want!r}"
+    whole = chunk if not chunk or chunk.endswith(b"\n") else chunk + b"\n"
+    if whole and (c_scan := scan_copy_chunk(whole, n_cols)) is not None:
+        twin = _scan_copy_chunk_np(np.frombuffer(whole, np.uint8), n_cols)
+        assert c_scan[:3] == twin[:3], f"{c_scan[:3]} != {twin[:3]}"
+        for c_arr, np_arr in zip(c_scan[3:], twin[3:]):
+            if np_arr is not None:
+                c_arr = c_arr[:len(np_arr)]
+                assert c_arr.dtype == np_arr.dtype \
+                    and np.array_equal(c_arr, np_arr), "C scan != numpy twin"
+    if staged is None:
+        return
+
+    n_rows, cells, fallback = want
+    cap = 0 if not chunk else bucket_rows(n_rows)
+    assert staged.n_rows == n_rows and staged.copy_escapes == bool(chunk)
+    assert staged.data.dtype == np.uint8 and staged.data.tobytes() == whole
+    cells = np.array(cells, dtype=np.int64).reshape(n_rows, n_cols, 3)
+    pad = np.zeros((cap - n_rows, n_cols), dtype=np.int64)
+    for name, dtype, vals, fill in (
+            ("offsets", np.int32, cells[:, :, 0], 0),
+            ("lengths", np.int32, cells[:, :, 1], 0),
+            ("nulls", np.bool_, cells[:, :, 2], 1),
+            ("toast", np.bool_, 0 * cells[:, :, 0], 0)):
+        got = getattr(staged, name)
+        assert got.dtype == dtype and got.shape == (cap, n_cols), \
+            f"{name}: {got.dtype}{got.shape}"
+        assert np.array_equal(got, np.concatenate([vals, pad + fill])), name
+    got = staged.cpu_fallback_rows
+    assert got.dtype == np.int64 and got.tolist() == fallback, \
+        f"fallback rows {got.tolist()}, want {fallback}"
+
+
+# plain field bytes: none a tab, a newline or a backslash; 0x08, 0x0b and
+# 0x5d are each one bit from one of them (the C scan's word-at-a-time test
+# may flag the byte after a match falsely and must then look at it)
+_COPY_PLAIN = b"0123456789 abN" + bytes((0x08, 0x0B, 0x5D, 0x80, 0xFF))
+_COPY_ESCAPED = (b"\\\\", b"a\\tb", b"\\n", b"x\\N", b"\\Nx", b"\\N\\N",
+                 b"\\")
+
+
+def fuzz_stage_copy_chunk(rng: random.Random, _ignored=None) -> None:
+    """COPY text chunks — rows of NULLs, empty fields, escapes and plain
+    bytes, then some of them mutated, cut short or plain noise — through
+    `check_stage_copy_chunk`."""
+    n_cols = rng.randint(1, 6)
+
+    def field() -> bytes:
+        c = rng.random()
+        if c < 0.15:
+            return b"\\N"
+        if c < 0.25:
+            return b""
+        if c < 0.26:
+            return rng.choice(_COPY_ESCAPED)
+        return bytes(rng.choice(_COPY_PLAIN)
+                     for _ in range(rng.randint(0, 24)))
+
+    alphabet = _COPY_PLAIN + b"\t\t\n\n\\"
+    if rng.random() < 0.1:
+        chunk = bytearray(rng.choice(alphabet)
+                          for _ in range(rng.randint(0, 80)))
+    else:
+        chunk = bytearray(b"".join(
+            b"\t".join(field() for _ in range(n_cols)) + b"\n"
+            for _ in range(rng.randint(0, 40))))
+        if rng.random() < 0.05:
+            # long rows first: the C scan's guess of the row count, from
+            # the chunk's head, runs out and it scans again from its bound
+            chunk[:0] = (b"\t".join([b"0" * 5000] * n_cols) + b"\n") * 2
+        if chunk and rng.random() < 0.15:
+            del chunk[-1]  # the stream left the last newline out
+        if chunk and rng.random() < 0.35:
+            for _ in range(rng.randint(1, 3)):
+                i = rng.randrange(len(chunk))
+                c = rng.random()
+                if c < 0.3:
+                    chunk[i] = rng.choice(alphabet)
+                elif c < 0.5:
+                    chunk.insert(i, rng.choice(alphabet))
+                elif c < 0.7 and len(chunk) > 1:
+                    del chunk[i]
+                else:  # as many delimiters, in other places: ragged
+                    j = rng.randrange(len(chunk))
+                    chunk[i], chunk[j] = chunk[j], chunk[i]
+    if rng.random() < 0.05:
+        n_cols = rng.choice((0, 7, 100))
+    check_stage_copy_chunk(bytes(chunk), n_cols)
+
+
 _AVRO_FUZZ_DIR: str | None = None  # one temp dir per process, not per case
 
 
@@ -552,6 +695,7 @@ TARGETS = {
     "bytea_hex": fuzz_bytea_hex,
     "framer": fuzz_framer,
     "copy_stream": fuzz_copy_stream,
+    "stage_copy_chunk": fuzz_stage_copy_chunk,
     "avro_ocf": fuzz_avro_ocf,
     "pb_append_rows": fuzz_pb_append_rows,
     "snowpipe_batches": fuzz_snowpipe_batches,

@@ -9,11 +9,16 @@ any OOB read/write, overflow, or misaligned access ABORTS the child), then
 hammer it with
 
   1. the structured-mutation framer fuzzer (testing/fuzz.py `framer`
-     target — valid pgoutput streams + byte mutations + truncations) and
+     target — valid pgoutput streams + byte mutations + truncations),
      the COPY stream fuzzer (`copy_stream` target — random message sizes,
-     tags, corrupt lengths and block cuts through etl_scan_copy_data), and
+     tags, corrupt lengths and block cuts through etl_scan_copy_data) and
+     the COPY chunk fuzzer (`stage_copy_chunk` target — rows of NULLs,
+     escapes and near-miss bytes, mutated and cut, through
+     etl_stage_copy_chunk),
   2. the full differential test file (tests/test_native_framer.py), which
-     also exercises etl_pack_bmat / etl_gather_string / nibble packing.
+     also exercises etl_pack_bmat / etl_gather_string / nibble packing, and
+  3. a direct hammer of the pack/gather entry points and of
+     etl_stage_copy_chunk with outputs smaller than its rows.
 
 Exit 0 = no sanitizer findings. Run:  python scripts/sanitize_framer.py
 [--seconds N] [--seed N]. CI-sized invocation lives in
@@ -108,8 +113,8 @@ def main(argv=None) -> int:
         return rc or 1
 
     # 2. structured-mutation fuzz under ASan/UBSan: the framer, then the
-    # CopyData block scan
-    for target in ("framer", "copy_stream"):
+    # CopyData block scan and the COPY chunk scan
+    for target in ("framer", "copy_stream", "stage_copy_chunk"):
         fuzz_args = ["-m", "etl_tpu.testing.fuzz", "--target", target,
                      "--seconds", str(args.seconds)]
         if args.seed is not None:
@@ -132,7 +137,8 @@ def main(argv=None) -> int:
         return rc
 
     # 4. direct hammer of the pack/gather entry points (numpy-only):
-    # adversarial widths, truncated fields, and buffer-edge offsets
+    # adversarial widths, truncated fields, and buffer-edge offsets; and
+    # of the COPY chunk scan with too few output rows
     hammer_args = ["scripts/sanitize_framer.py", "--hammer",
                    "--seconds", str(args.seconds)]
     if args.seed is not None:
@@ -143,15 +149,18 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return rc
     print("sanitize_framer: no findings "
-          f"(fuzz 2 x {args.seconds:.0f}s + framer differentials + "
-          f"pack/gather hammer under ASan+UBSan)")
+          f"(fuzz 3 x {args.seconds:.0f}s + framer differentials + "
+          f"pack/gather/stage hammer under ASan+UBSan)")
     return 0
 
 
 def hammer(seconds: float, seed: int | None) -> int:
     """Child mode: randomized pack_bmat / pack_bmat_nibble / gather_string
     calls over fuzz-framed batches, including adversarial gather widths and
-    fields ending at the exact buffer boundary."""
+    fields ending at the exact buffer boundary; then etl_stage_copy_chunk
+    called past its binding, with outputs of fewer rows than the chunk has
+    and chunks that do not end in a newline."""
+    import ctypes
     import random
     import time
 
@@ -161,6 +170,7 @@ def hammer(seconds: float, seed: int | None) -> int:
     from etl_tpu.postgres.codec import pgoutput
 
     assert native.native_available(), native._build_error
+    p = native._ptr
     rng = random.Random(seed if seed is not None else 20260729)
     deadline = time.monotonic() + seconds
     cases = 0
@@ -214,6 +224,21 @@ def hammer(seconds: float, seed: int | None) -> int:
             vals = np.zeros(cap, dtype=np.uint8)
             native.gather_string(data, framed.new_off, framed.new_len,
                                  valid, col, aoff, vals)
+        # the chunk scan may write max_rows rows and not one more: exact-
+        # size outputs put the sanitizer's red zone right behind them
+        chunk = b"".join(
+            b"\t".join(rng.choice((b"\\N", b"", b"a\\b", b"12345"))
+                       for _ in range(n_cols)) + b"\n"
+            for _ in range(rng.randint(0, 40)))[:rng.choice((None, -1, -3))]
+        for max_rows in (0, 1, rng.randint(0, 40), len(chunk)):
+            asked = rng.choice((n_cols, n_cols, 0, -2, 9))
+            shape = (max_rows, max(asked, 0))
+            res = (ctypes.c_int64 * 3)()
+            native._lib.etl_stage_copy_chunk(
+                chunk, len(chunk), asked,
+                max_rows, p(np.empty(shape, np.int32)),
+                p(np.empty(shape, np.int32)), p(np.empty(shape, np.bool_)),
+                p(np.empty(max_rows, np.int64)), res)
         cases += 1
     print(f"hammer: {cases} cases OK")
     return 0
