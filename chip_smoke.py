@@ -294,19 +294,6 @@ def oracle_batch(schema, payloads):
     return ColumnarBatch.from_rows(schema, rows)
 
 
-def identical(got, want) -> bool:
-    """Byte identity of two decoded batches, survivor mapping included."""
-    import numpy as np
-
-    from bench import _batches_identical
-
-    a, b = got.source_rows, want.source_rows
-    if (a is None) != (b is None) or (a is not None
-                                      and not np.array_equal(a, b)):
-        return False
-    return _batches_identical(got, want)
-
-
 def _stager(schema, payloads):
     """() -> a fresh StagedBatch of the payloads (a decode may consume
     its staging), the bytes concatenated once."""
@@ -331,13 +318,15 @@ def _decode_checks(schema, payloads, oracle, case: Case, label: str,
 
     from etl_tpu.ops.engine import DeviceDecoder
     from etl_tpu.ops.predicate import parse_row_filter
+    from etl_tpu.testing.batches import batches_identical
 
     stage = _stager(schema, payloads)
     dec = DeviceDecoder(schema, device_min_rows=0, **decoder_kw)
     t0 = time.perf_counter()
     batch = dec.decode(stage())
     out = {"first_decode_s": round(time.perf_counter() - t0, 3)}
-    check(identical(batch, oracle), f"{label}: differs from the CPU codecs")
+    check(batches_identical(batch, oracle),
+          f"{label}: differs from the CPU codecs")
     t0 = time.perf_counter()
     dec.decode(stage())
     out["warm_decode_s"] = round(time.perf_counter() - t0, 4)
@@ -351,7 +340,7 @@ def _decode_checks(schema, payloads, oracle, case: Case, label: str,
     survivors = np.flatnonzero(case.keep).astype(np.int64)
     want = oracle.take(survivors)
     want.source_rows = survivors
-    check(identical(fdec.decode(staged), want),
+    check(batches_identical(fdec.decode(staged), want),
           f"{label}: fused filter differs from the CPU codecs")
     out["filter_keep"] = round(len(survivors) / len(case.keep), 4)
     if decoder_kw.get("use_pallas"):
@@ -373,13 +362,14 @@ def _egress_checks(case: Case, label: str, **decoder_kw) -> dict:
                                            sequence_number_buffer)
     from etl_tpu.ops.egress import ENCODER_TSV
     from etl_tpu.ops.engine import DeviceDecoder
+    from etl_tpu.testing.batches import batches_identical
 
     batch = DeviceDecoder(case.schema, device_min_rows=0, egress=ENCODER_TSV,
                           **decoder_kw).decode(
                               _stager(case.schema, case.payloads)())
     check(batch.device_egress is not None,
           f"{label}: no device egress buffers attached")
-    check(identical(batch, case.oracle),
+    check(batches_identical(batch, case.oracle),
           f"{label}: egress decode differs from the CPU codecs")
     n = batch.num_rows
     lsns = np.arange(n, dtype=np.uint64) + (1 << 40)

@@ -57,10 +57,10 @@ from .domains import (COORDINATOR, LOOP, THREAD_DOMAINS, DomainMap,
 from .findings import Finding
 from .visitor import terminal_name
 
-#: path heads the shared-mutation/loop-affinity rules police (chaos/,
-#: testing/, benchmarks/ double deliberately race or are single-process
-#: test scaffolding; top-level production modules listed by filename —
-#: their canonical path has no directory segment)
+#: path heads the shared-mutation/loop-affinity rules police (chaos/
+#: and testing/ deliberately race or are single-process test
+#: scaffolding; top-level production modules listed by filename — their
+#: canonical path has no directory segment)
 CONCURRENCY_RULE_SCOPES = (
     "runtime", "ops", "destinations", "postgres", "store", "supervision",
     "api", "telemetry", "parallel", "dlq", "fleet", "autoscale",

@@ -122,7 +122,7 @@ def default_schemas() -> list:
 
 def filtered_schema():
     """(name, schema, compiled-filter-producing decoder schema): pgbench
-    with the bench suite's `abalance < 0` publication row filter —
+    with the `abalance < 0` publication row filter —
     device-supported, referenced column dense."""
     from ...ops.predicate import parse_row_filter
 

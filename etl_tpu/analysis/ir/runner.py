@@ -28,8 +28,8 @@ from .catalog import ProgramDescriptor, build_catalog
 
 _REPO_ROOT = Path(__file__).resolve().parents[3]
 
-#: forced device count for the mesh subprocess — matches the bench
-#: suite's 8-shard mesh check, i.e. one pod-slice's worth of shards
+#: forced device count for the mesh subprocess — the 8 virtual devices
+#: tests/conftest.py forces, i.e. one pod-slice's worth of shards
 MESH_FORCED_DEVICES = 8
 
 _MESH_SUBPROCESS_TIMEOUT_S = 600

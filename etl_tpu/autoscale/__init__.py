@@ -12,7 +12,7 @@ AdmissionScheduler.
 `python -m etl_tpu.autoscale --replay signals.json` replays a recorded
 timeline through the policy and prints the deterministic decision
 trace; `--synthetic --seed N` does the same over the seeded surge→drain
-story the bench reaction-time gate uses.
+story the reaction-time test uses.
 """
 
 from .controller import (AutoscaleController, AutoscaleJournal,

@@ -33,8 +33,8 @@ def main(argv: "list[str] | None" = None) -> int:
                           "{frames: [{tick, at_s, shards: [...]}]})")
     src.add_argument("--synthetic", action="store_true",
                      help="generate the seeded surge→drain timeline "
-                          "instead of reading a file (the bench "
-                          "reaction-time gate's input)")
+                          "instead of reading a file (the "
+                          "reaction-time test's input)")
     parser.add_argument("--seed", type=int, default=7,
                         help="synthetic-timeline seed (default 7)")
     parser.add_argument("--start-k", type=int, default=None,

@@ -393,7 +393,7 @@ class AutoscaleController:
         """Simple periodic driver for sidecar deployments: resume any
         crash-interrupted decision first, apply SLO weights, then tick
         forever (or until `shutdown` — a ShutdownSignal-alike with
-        `.triggered` — fires). Chaos and bench drive tick() directly."""
+        `.triggered` — fires). Chaos and tests drive tick() directly."""
         import time
 
         await self.resume()

@@ -323,7 +323,7 @@ def simulate(frames, policy: AutoscalePolicy,
     loop closed in memory: every non-hold decision updates the simulated
     topology and starts the cooldown, exactly as a controller applying
     each decision instantly would. Pure — the replay CLI's trace, the
-    bench reaction-time gate, and the no-flap property tests all run
+    reaction-time test, and the no-flap property tests all run
     through here, so they exercise the same loop semantics."""
     decisions: list[Decision] = []
     current_k = start_k

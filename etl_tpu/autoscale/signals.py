@@ -16,8 +16,8 @@ Two collectors ship:
   RegistrySignalSource — reads the in-process telemetry registry
       (`etl_slot_lag_bytes{shard}` + `etl_shard_delivered_events{shard}`,
       published by the apply loop on its status-update cadence, and the
-      memory-backpressure gauge). The single-process vantage: bench
-      runs, tests, and a sidecar controller sharing the pod.
+      memory-backpressure gauge). The single-process vantage: tests
+      and a sidecar controller sharing the pod.
   StoreSignalSource — the COORDINATOR's vantage: per-shard lag computed
       as (source WAL position − per-shard apply-slot durable progress)
       against the shared StateStore, plus per-shard health probes. This
@@ -27,8 +27,8 @@ Two collectors ship:
 Frames and timelines serialize to JSON (`--replay` files, chaos
 manifests). `seeded_surge_timeline` generates the canonical synthetic
 surge→drain story deterministically per seed — the replay CLI default,
-the bench reaction-time gate, and the hysteresis property tests all
-draw from it.
+the reaction-time test (ticks, tests/test_autoscale.py) and the
+hysteresis property tests all draw from it.
 """
 
 from __future__ import annotations
@@ -262,7 +262,7 @@ def seeded_surge_timeline(seed: int = 7, *, shards: int = 2,
     `surge_ticks`, then a linear drain back to baseline. Durable LSNs
     advance at a steady per-tick rate so the policy's capacity estimate
     is well-defined. Used by the replay CLI's --synthetic mode, the
-    bench reaction-time gate (`bench.py --autoscale`), and the
+    reaction-time test (tests/test_autoscale.py::TestBenchGate) and the
     hysteresis property tests (noise around a band edge must not flap).
     """
     rng = random.Random(seed)

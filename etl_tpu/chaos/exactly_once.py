@@ -45,6 +45,7 @@ from dataclasses import dataclass, field
 from ..config import (BatchConfig, BatchEngine, PipelineConfig, RetryConfig,
                       SupervisionConfig)
 from ..destinations import DelayedAckDestination, TransactionalMemoryDestination
+from ..destinations.base import event_coordinate
 from ..models.errors import ErrorKind, EtlError
 from ..models.lsn import Lsn
 from ..models.table_state import TableStateType
@@ -131,7 +132,7 @@ async def _run_window(window: str, seed: int, report: InvariantReport,
         if window == "mid_write" else inner
     config = _config()
     restarts: list[RestartRecord] = []
-    doc: dict = {"window": window, "seed": seed}
+    doc: dict = {"window": window, "seed": seed, "unacked_suffix_rows": 0}
 
     def make_pipeline():
         from ..runtime import Pipeline
@@ -176,6 +177,12 @@ async def _run_window(window: str, seed: int, report: InvariantReport,
         restarts.append(RestartRecord(
             kind="crash", resume_lsn=int(resume or Lsn.ZERO),
             at_tx=workload.tx_index))
+        # rows the sink holds beyond durable progress: all a restart can
+        # re-stream, so the bound on what the sink's dedup may absorb
+        doc["unacked_suffix_rows"] = sum(
+            1 for e in inner.events
+            if (c := event_coordinate(e)) is not None
+            and c[0] > restarts[0].resume_lsn)
 
         if window == "mid_recovery":
             # restart whose sink recovery query is slow + transiently
@@ -261,6 +268,12 @@ async def _run_window(window: str, seed: int, report: InvariantReport,
         report.fail(
             f"{window}: {inner.uncoordinated_writes} CDC write(s) "
             f"bypassed the transactional seam")
+    if inner.dedup_skipped_rows > doc["unacked_suffix_rows"]:
+        report.fail(
+            f"{window}: re-stream exceeded the unacked suffix — "
+            f"{inner.dedup_skipped_rows} already-applied rows re-delivered "
+            f"vs {doc['unacked_suffix_rows']} unacked at the kill "
+            f"(recovery did not trim the resume point)")
 
     doc.update({
         "restarts": [r.describe() for r in restarts],

@@ -160,8 +160,8 @@ def reconstruct_final_view(dest, table_ids) -> dict:
 def view_matches(dest, table_ids, expected: dict) -> bool:
     """True when the destination's reconstructed final view equals the
     committed source truth — the shared quiescence/verification test used
-    by both the chaos runner and the workload bench harness, so the
-    collapse rules above can never silently diverge between them."""
+    by the chaos runner and the workload generator's `delivered`, so
+    the collapse rules above can never silently diverge between them."""
     view = reconstruct_final_view(dest, table_ids)
     for tid, rows in expected.items():
         got = view.get(tid, {})

@@ -5,11 +5,10 @@ while the write itself applies immediately — exactly the shape of a real
 destination (BigQuery commit, ClickHouse insert quorum, an object-store
 PUT) where `write_*` hands the payload off fast and crash-safety is
 signalled one round trip later. The apply loop's bounded write window
-(runtime/ack_window.py) exists to hide this latency; `bench.py
---ack-latency` wraps the null destination with this class and measures
-windowed vs window=1 throughput, and the chaos K-in-flight crash
-scenario uses it to hold ≥2 acks in flight deterministically at the
-kill point.
+(runtime/ack_window.py) exists to hide this latency;
+tests/test_ack_window.py drains one backlog through it at the default
+window and at window=1, and the chaos K-in-flight crash scenario uses
+it to hold ≥2 acks in flight deterministically at the kill point.
 
 Accounting for assertions: `pending` / `max_pending` count unresolved
 delayed acks — `max_pending >= 2` is the evidence that a run actually

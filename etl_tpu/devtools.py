@@ -18,7 +18,6 @@ Commands:
                  (NetworkChaos partition analogue), then verify exactly-
                  once delivery to the destination
   fuzz           seeded parser fuzzing (etl_tpu.testing.fuzz)
-  bench-compare  diff two benchmark JSON reports (etl_tpu.benchmarks)
   fill-table     bulk-load a table over the wire client — parallel
                  connections, multi-row batches (xtask pg-fill-table)
   rotate-encryption-key  re-encrypt stored control-plane configs under a
@@ -454,11 +453,6 @@ def main(argv=None) -> int:
     fp.add_argument("--seconds", type=float, default=10.0)
     fp.add_argument("--seed", type=int, default=None)
 
-    bp = sub.add_parser("bench-compare", help="diff two bench reports")
-    bp.add_argument("a")
-    bp.add_argument("b")
-    bp.add_argument("--fail-pct", type=float, default=None)
-
     ft = sub.add_parser("fill-table",
                         help="bulk-load a table over the wire client "
                              "(xtask pg-fill-table)")
@@ -498,13 +492,6 @@ def main(argv=None) -> int:
         if args.seed is not None:
             fuzz_args += ["--seed", str(args.seed)]
         return fuzz_main(fuzz_args)
-    if args.cmd == "bench-compare":
-        from .benchmarks.compare import main as cmp_main
-
-        cmp_args = [args.a, args.b]
-        if args.fail_pct is not None:
-            cmp_args += ["--fail-pct", str(args.fail_pct)]
-        return cmp_main(cmp_args)
     if args.cmd == "fill-table":
         return asyncio.run(fill_table(args))
     if args.cmd == "rotate-encryption-key":

@@ -12,7 +12,7 @@ seam the rest of the repo already uses:
                  converge, plus crash resume;
   runtime.py     the actuation seam (Orchestrator-backed production
                  runtime);
-  sim.py         the 100-pipeline in-process fleet for chaos + bench;
+  sim.py         the 100-pipeline in-process fleet for chaos + tests;
   bus.py         the shared signal bus: admission / PID lag-target /
                  adaptive ack-depth policies as plugins.
 """

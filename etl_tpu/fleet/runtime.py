@@ -13,7 +13,7 @@ Implementations:
   - `OrchestratorFleetRuntime` (here): drives a real `Orchestrator`
     (K8s StatefulSets or local subprocesses) — the production path;
   - `SimulatedFleetRuntime` (sim.py): the 100-pipeline in-process
-    model the chaos scenario and bench converge gate run against.
+    model the chaos scenario and the converge-ledger test run against.
 """
 
 from __future__ import annotations

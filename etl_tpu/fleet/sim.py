@@ -1,5 +1,5 @@
 """The 100-pipeline simulated fleet: what the chaos scenario and the
-bench converge gate reconcile against.
+converge-ledger test (tests/test_fleet.py) reconcile against.
 
 `SimulatedFleetRuntime` implements the FleetRuntime verbs over
 in-process state — no subprocesses, no sockets — so a hundred

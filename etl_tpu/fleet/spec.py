@@ -140,7 +140,7 @@ class FleetSpec:
     def with_edit(self, *, add=(), remove=(),
                   resize: "dict[int, int] | None" = None) -> "FleetSpec":
         """A new spec (version + 1) with pipelines added/removed/resized
-        — the operator-edit primitive the chaos and bench scripts use."""
+        — the operator-edit primitive the chaos scenario and tests use."""
         from dataclasses import replace
 
         by_id = self.by_id()

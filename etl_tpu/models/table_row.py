@@ -53,10 +53,10 @@ def value_size_hint(v: Any) -> int:
 
 #: process-wide count of TableRow constructions (mutable cell so the hot
 #: path pays one list-index increment, no attribute lookup on a registry).
-#: The columnar egress path never builds rows, so bench.py --smoke asserts
-#: this counter's delta over the streamed CDC window is ZERO — the row
-#: path creeping back into egress fails CI instead of silently eating the
-#: decode speedups (ROADMAP item 2).
+#: The columnar egress path never builds rows, so tests/
+#: test_columnar_egress.py asserts this counter's delta over a streamed
+#: CDC window is ZERO — the row path creeping back into egress fails CI
+#: instead of silently eating the decode speedups (ROADMAP item 2).
 _ROWS_CONSTRUCTED = [0]
 
 

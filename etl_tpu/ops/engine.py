@@ -1014,7 +1014,7 @@ class DeviceDecoder:
                 # for the unrolled parse chain (MAX_TOTAL_WIDTH) — take
                 # the XLA program without a doomed compile attempt.
                 # Flipping the FLAG (not silently routing)
-                # keeps bench/harness engine labels honest: they report
+                # keeps engine labels honest: callers (chip_smoke) read
                 # which engine actually ran via use_pallas.
                 import logging
 

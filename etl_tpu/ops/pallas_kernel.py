@@ -16,9 +16,9 @@ that docstring implied:
   (ops/parsers_lanes.py — semantics transcribed 1:1 from parsers.py,
   shared scalar helpers, covered by the same differential suites).
 
-`DeviceDecoder(use_pallas=True)` selects it; `bench.py` measures BOTH
-engines every run and the headline takes whichever is faster. A kernel
-Mosaic refuses to compile raises at the dispatch, like any other
+`DeviceDecoder(use_pallas=True)` selects it; no production caller does,
+and its speed against the XLA program was never measured on the chip
+(ROADMAP D1). A kernel Mosaic refuses to compile raises at the dispatch, like any other
 compile error; only the width bound below routes a schema to the XLA
 program, and it flips the decoder's `use_pallas` flag when it does.
 

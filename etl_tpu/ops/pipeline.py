@@ -317,7 +317,7 @@ class AdmissionScheduler:
             self._cond.notify_all()
         # grant telemetry only for REAL grants: a tenant closed during
         # the wait wakes without a ticket, and counting it would skew
-        # the per-tenant fairness evidence the bench reports
+        # the per-tenant fairness evidence
         if granted:
             labels = {"pipeline": tenant.name}
             registry.counter_inc(ETL_DECODE_ADMISSION_GRANTS_TOTAL,
@@ -387,7 +387,7 @@ _GLOBAL_ADMISSION_LOCK = threading.Lock()
 
 def reset_global_admission() -> None:
     """Drop the process-wide scheduler so the NEXT global_admission()
-    caller fixes a fresh capacity (bench harness / test isolation). Only
+    caller fixes a fresh capacity (test isolation). Only
     safe with no production pipelines running: live tenants keep their
     seats on the old scheduler object until they close, so a reset under
     traffic splits capacity accounting across two schedulers."""

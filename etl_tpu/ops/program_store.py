@@ -57,8 +57,9 @@ Three layers, one key space (ops/engine._SHARED_FN_CACHE keys):
    canonical layouts, and warms the deduped host-program keys through
    the SAME `engine._host_fn_ready` machinery the nonblocking streaming
    decoders use: disk hits load synchronously (a warm restart reaches
-   its first durable batch with ZERO fresh XLA builds — gated in
-   bench.py --coldstart/--smoke via the compile counter), cold keys
+   its first durable batch with ZERO fresh XLA builds — held by
+   tests/test_program_store.py::TestPersistence via the compile
+   counter), cold keys
    compile on background threads while batches decode on the host
    oracle. One API, three callers: pipeline prewarm, the streaming
    decoders' nonblocking first touch, and the chaos restart scenarios.
@@ -228,8 +229,8 @@ def active_dir() -> "str | None":
 
 def place_jax_compile_cache() -> str:
     """Give JAX's persistent compilation cache a directory that outlives
-    the process, and return it. Entry points (chip_smoke.py, bench.py,
-    the replicator) call this once before their first compile; nothing
+    the process, and return it. Entry points (chip_smoke.py,
+    benchmark/run.py, the replicator) call this once before their first compile; nothing
     calls it at import time. Where $JAX_COMPILATION_CACHE_DIR is set JAX
     already reads it and no code names another directory; otherwise the
     cache lives at `<checkout>/.jax_cache` — a fixed path, because the

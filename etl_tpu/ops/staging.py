@@ -141,7 +141,8 @@ class StagedBatch:
 #: fetch-slice granularity: survivor counts bucket to multiples of
 #: max(R/16, 256) so the filtered fetch compiles at most ~16 slice
 #: programs per (capacity, layout) while bounding pad slack at ~1/16 of
-#: the batch (the "pad slack" term in the bench.py --selectivity gate)
+#: the batch (the "pad slack" term of tests/test_filter_fusion.py
+#: ::TestFetchedBytes)
 def slice_rows(n: int, capacity: int) -> int:
     if n <= 0:
         return 0

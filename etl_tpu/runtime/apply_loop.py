@@ -796,8 +796,8 @@ class ApplyLoop:
         # behind it (and whose payload can exceed what a destination
         # accepts per request). max_size_bytes is now a real per-write
         # bound, not just a flush trigger; the delivered event stream is
-        # byte-identical at every window depth (asserted by bench.py
-        # --ack-latency). The commit watermark (`covered`) — not the raw
+        # byte-identical at every window depth (tests/test_ack_window.py
+        # ::test_window1_equivalence_and_overlap). The commit watermark (`covered`) — not the raw
         # batch_commit_end — is what a PREFIX flush may claim durability
         # at; `remaining` is the highest boundary still awaiting a later
         # flush.

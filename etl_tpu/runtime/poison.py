@@ -80,7 +80,7 @@ _ROW_EVENTS = (InsertEvent, UpdateEvent, DeleteEvent)
 
 #: per-isolation trace records (appended by every `_isolate` run):
 #: {"rows", "tables", "probe_writes", "control_probes", "poison_rows",
-#: "quarantined"} — the chaos scenario and bench gate read these to
+#: "quarantined"} — the chaos scenario and tests read these to
 #: assert the bisection bound (≤ 2·log₂(batch) probes per poison row +
 #: one probe per table; control-event barrier writes are counted
 #: separately, outside the bound). Bounded: a long-running worker

@@ -19,8 +19,8 @@ from dataclasses import dataclass, field
 ETL_TABLE_COPY_ROWS_TOTAL = "etl_table_copy_rows_total"
 # TableRow/PartialTableRow constructions (models/table_row keeps the hot
 # counter; publish_table_rows_constructed() mirrors it here). Zero over a
-# streamed-CDC window = the egress path stayed columnar fetch-to-wire —
-# bench.py --smoke gates on exactly that.
+# streamed-CDC window = the egress path stayed columnar fetch-to-wire
+# (tests/test_columnar_egress.py::test_streamed_cdc_constructs_no_rows).
 ETL_TABLE_ROWS_CONSTRUCTED_TOTAL = "etl_table_rows_constructed_total"
 ETL_TABLE_COPY_BYTES_TOTAL = "etl_table_copy_bytes_total"
 ETL_TABLE_COPY_DURATION_SECONDS = "etl_table_copy_duration_seconds"
@@ -188,8 +188,8 @@ ETL_EGRESS_WRITES_TOTAL = "etl_egress_writes_total"
 # executable), misses by reason (absent = never compiled on this
 # version tag, invalid = corrupt/stale file deleted and rebuilt), disk
 # load latency, and ACTUAL XLA program builds — the counter the
-# warm-restart gates pin at zero (bench.py --coldstart, the chaos
-# crash_restart_warm_programs scenario). The canonical-layout gauge is
+# warm-restart gates pin at zero (tests/test_program_store.py
+# ::TestPersistence, the chaos crash_restart_warm_programs scenario). The canonical-layout gauge is
 # the number of distinct padded layouts live in this process: its ratio
 # to tables-seen is the compile sharing canonicalization buys.
 ETL_COMPILE_CACHE_HITS_TOTAL = "etl_compile_cache_hits_total"
@@ -508,7 +508,7 @@ registry = MetricsRegistry()
 def publish_table_rows_constructed() -> int:
     """Mirror the models/table_row construction counter into the registry
     (the hot path pays a bare list-index increment, not a registry lock;
-    scrapes and the bench gates read through here) and return it."""
+    scrapes read through here) and return it."""
     from ..models.table_row import rows_constructed
 
     n = rows_constructed()

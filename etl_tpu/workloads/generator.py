@@ -9,8 +9,8 @@ payload sequence (asserted in tests/test_workloads.py).
 The generator tracks the committed source truth as it goes (`expected`:
 {table_id: {pk: tuple(decoded values)}}, mirroring the fake's storage but
 in decoded-cell form), which is exactly what the chaos invariant checker
-consumes — so the same object drives `bench.py --workload`, the chaos
-corpus × profile matrix, and `devtools serve-source --workload`.
+consumes — so the same object drives the chaos corpus × profile matrix
+and `devtools serve-source --workload`.
 """
 
 from __future__ import annotations
@@ -103,7 +103,7 @@ class WorkloadGenerator:
         self.poison_pks: dict[int, set[int]] = \
             {tid: set() for tid in self.table_ids}
         self.tx_index = 0  # generator steps completed
-        self.row_ops = 0  # Insert/Update/Delete ops committed (bench rate)
+        self.row_ops = 0  # Insert/Update/Delete ops committed
 
     # -- setup ----------------------------------------------------------------
 

@@ -11,9 +11,9 @@ deterministic stream of FakeTransaction commits.
 Adding a profile: add an entry to `PROFILES` (and, if it needs a new
 column mix, a builder in `COLUMN_MIXES`). Every registered profile is
 automatically covered by the determinism and decode round-trip tests in
-tests/test_workloads.py — no further wiring needed for `bench.py
---workload <name>`, `python -m etl_tpu.chaos --workload <name>`, or
-`devtools serve-source --workload <name>`.
+tests/test_workloads.py — no further wiring needed for `python -m
+etl_tpu.chaos --workload <name>` or `devtools serve-source --workload
+<name>`.
 """
 
 from __future__ import annotations

@@ -432,70 +432,119 @@ class TestDispatchBlockedByteCap:
         w.pop_ready()
 
 
+ACK_TID = 16395
+
+
+async def _ack_pipeline(write_window: int, ack_delay_s: float,
+                        max_size_bytes: int):
+    """(db, store, inner, dest, pipeline): one two-column table streamed
+    by the per-tuple engine into a memory sink whose every ack turns
+    durable `ack_delay_s` late; started, table READY."""
+    from etl_tpu.config import BatchConfig, BatchEngine, PipelineConfig
+    from etl_tpu.destinations import (DelayedAckDestination,
+                                      MemoryDestination)
+    from etl_tpu.models import ColumnSchema, Oid, TableName, TableSchema
+    from etl_tpu.models.table_state import TableStateType
+    from etl_tpu.postgres.fake import FakeDatabase, FakeSource
+    from etl_tpu.runtime import Pipeline
+    from etl_tpu.store import NotifyingStore
+
+    db = FakeDatabase()
+    db.create_table(TableSchema(
+        ACK_TID, TableName("public", "ack_t"),
+        (ColumnSchema("id", Oid.INT8, nullable=False,
+                      primary_key_ordinal=1),
+         ColumnSchema("v", Oid.INT4))))
+    db.create_publication("pub", [ACK_TID])
+    store = NotifyingStore()
+    inner = MemoryDestination()
+    dest = DelayedAckDestination(inner, ack_delay_s)
+    pipeline = Pipeline(
+        config=PipelineConfig(
+            pipeline_id=1, publication_name="pub",
+            batch=BatchConfig(max_size_bytes=max_size_bytes,
+                              max_fill_ms=10,
+                              batch_engine=BatchEngine.CPU,
+                              write_window=write_window)),
+        store=store, destination=dest,
+        source_factory=lambda: FakeSource(db))
+    await pipeline.start()
+    await asyncio.wait_for(
+        store.notify_on(ACK_TID, TableStateType.READY), 60)
+    return db, store, inner, dest, pipeline
+
+
+def _delivered_rows(inner) -> list:
+    """The sink's row events in arrival order, as (commit lsn, ordinal,
+    values): a flat list, so it is the same however flushes were cut."""
+    from etl_tpu.models import InsertEvent
+
+    return [(int(e.commit_lsn), e.tx_ordinal, tuple(e.row.values))
+            for e in inner.events if isinstance(e, InsertEvent)]
+
+
+async def _drain_backlog(write_window: int, n_events: int = 300,
+                         tx_size: int = 20) -> dict:
+    """Commit the whole backlog, then let one pipeline drain it through
+    `write_window` against 5 ms-late acks; what was delivered and how
+    many acks were ever open at once."""
+    from etl_tpu.telemetry.metrics import (
+        ETL_DESTINATION_ACK_OVERLAP_SECONDS_TOTAL, registry)
+
+    labels = {"path": "apply"}
+    overlap0 = registry.get_counter(
+        ETL_DESTINATION_ACK_OVERLAP_SECONDS_TOTAL, labels)
+    db, _, inner, dest, pipeline = await _ack_pipeline(
+        write_window, 0.005, max_size_bytes=2048)
+    for first in range(0, n_events, tx_size):
+        tx = db.transaction()
+        for i in range(first, min(first + tx_size, n_events)):
+            tx.insert(ACK_TID, [str(i), str(i % 97)])
+        await tx.commit()
+    while len(_delivered_rows(inner)) < n_events or dest.pending:
+        assert not pipeline._apply_task.done(), "pipeline stopped early"
+        await asyncio.sleep(0.005)
+    await pipeline.shutdown_and_wait()
+    return {
+        "rows": _delivered_rows(inner),
+        "max_acks_pending": dest.max_pending,
+        "overlap_seconds": registry.get_counter(
+            ETL_DESTINATION_ACK_OVERLAP_SECONDS_TOTAL, labels) - overlap0,
+    }
+
+
 class TestEndToEnd:
     async def test_window1_equivalence_and_overlap(self):
-        """The A/B harness at miniature scale: byte-identical delivery
-        digests across window depths, the one-in-flight contract at
-        window=1, provable overlap at the default window (the full
-        gated version runs in bench.py --smoke)."""
-        from etl_tpu.benchmarks import harness
+        """The same backlog through the default write window and through
+        window=1: the same rows in the same order, never more than one
+        ack open at window=1, and at the default window at least two
+        open at once with overlap recorded."""
+        from etl_tpu.config import BatchConfig
 
-        out = await harness.run_ack_latency(ack_ms=5.0, n_events=300,
-                                            tx_size=20)
-        assert out["failures"] == []
-        assert out["windowed"]["delivery_digest"] \
-            == out["window1"]["delivery_digest"]
-        assert out["window1"]["max_acks_pending"] <= 1
-        assert out["windowed"]["max_acks_pending"] >= 2
-        assert out["windowed"]["ack_overlap_seconds"] > 0
+        windowed = await _drain_backlog(BatchConfig().write_window)
+        serial = await _drain_backlog(1)
+        assert len(serial["rows"]) == 300
+        assert windowed["rows"] == serial["rows"]
+        assert serial["max_acks_pending"] <= 1
+        assert windowed["max_acks_pending"] >= 2
+        assert windowed["overlap_seconds"] > 0
 
     async def test_drain_on_shutdown_waits_every_ack(self):
         """Shutdown with acks in flight: the drain must wait them out
         and persist durable progress for the full acked prefix."""
-        from etl_tpu.config import (BatchConfig, BatchEngine,
-                                    PipelineConfig)
-        from etl_tpu.destinations import (DelayedAckDestination,
-                                          MemoryDestination)
-        from etl_tpu.models import (ColumnSchema, InsertEvent, Oid,
-                                    TableName, TableSchema)
-        from etl_tpu.models.table_state import TableStateType
-        from etl_tpu.postgres.fake import FakeDatabase, FakeSource
         from etl_tpu.postgres.slots import apply_slot_name
-        from etl_tpu.runtime import Pipeline
-        from etl_tpu.store import NotifyingStore
 
-        TID = 16395
-        db = FakeDatabase()
-        db.create_table(TableSchema(
-            TID, TableName("public", "drain_t"),
-            (ColumnSchema("id", Oid.INT8, nullable=False,
-                          primary_key_ordinal=1),
-             ColumnSchema("v", Oid.INT4))))
-        db.create_publication("pub", [TID])
-        store = NotifyingStore()
-        inner = MemoryDestination()
-        dest = DelayedAckDestination(inner, 0.15)
-        pipeline = Pipeline(
-            config=PipelineConfig(
-                pipeline_id=1, publication_name="pub",
-                batch=BatchConfig(max_size_bytes=512, max_fill_ms=10,
-                                  batch_engine=BatchEngine.CPU,
-                                  write_window=4)),
-            store=store, destination=dest,
-            source_factory=lambda: FakeSource(db))
-        await pipeline.start()
-        await asyncio.wait_for(
-            store.notify_on(TID, TableStateType.READY), 60)
+        db, store, inner, dest, pipeline = await _ack_pipeline(
+            4, 0.15, max_size_bytes=512)
         last_commit = None
         for t in range(3):
             tx = db.transaction()
             for i in range(8):
-                tx.insert(TID, [str(t * 8 + i + 1), str(i)])
+                tx.insert(ACK_TID, [str(t * 8 + i + 1), str(i)])
             last_commit = await tx.commit()
         # writes reach the destination quickly; acks are still pending
         # when shutdown begins — the drain must wait them out
-        while sum(1 for e in inner.events
-                  if isinstance(e, InsertEvent)) < 24:
+        while len(_delivered_rows(inner)) < 24:
             await asyncio.sleep(0.005)
         assert dest.pending >= 1
         await pipeline.shutdown_and_wait()

@@ -284,6 +284,81 @@ class TestPipelineIntegration:
         assert run.scheduler_drained
         assert len(run.restarts) == 1 and run.restarts[0].kind == "crash"
 
+    async def test_two_full_pipelines_take_tickets_and_drain(self):
+        """Two whole `Pipeline`s (fake walsender -> apply loop -> decode
+        -> sink) on the process-wide scheduler: both end states verify,
+        the streams really took admission tickets (the 512-row
+        transactions of `giant_tx` cross the host-program threshold;
+        oracle-routed flushes hold no capacity), and after shutdown the
+        scheduler holds no ticket and no tenant."""
+        import asyncio
+
+        from etl_tpu.chaos.runner import TracingDestination
+        from etl_tpu.config import (BatchConfig, BatchEngine,
+                                    PipelineConfig)
+        from etl_tpu.models.table_state import TableStateType
+        from etl_tpu.ops import engine
+        from etl_tpu.ops.pipeline import (global_admission,
+                                          reset_global_admission)
+        from etl_tpu.postgres.fake import FakeSource
+        from etl_tpu.runtime import Pipeline
+        from etl_tpu.store import NotifyingStore
+        from etl_tpu.workloads import WorkloadGenerator, get_profile
+
+        reset_global_admission()
+        streams = []
+        for i, name in enumerate(("insert_heavy", "giant_tx")):
+            gen = WorkloadGenerator(get_profile(name), seed=7 + i)
+            db = gen.build_db()
+            store, dest = NotifyingStore(), TracingDestination()
+            pipeline = Pipeline(
+                config=PipelineConfig(
+                    pipeline_id=i + 1, publication_name="pub",
+                    batch=BatchConfig(max_fill_ms=30,
+                                      batch_engine=BatchEngine.TPU)),
+                store=store, destination=dest,
+                source_factory=lambda db=db: FakeSource(db))
+            streams.append((gen, db, store, dest, pipeline))
+
+        async def produce_and_verify(gen, db, dest, pipeline, ops):
+            base = gen.row_ops
+            while gen.row_ops - base < ops:
+                await gen.run_tx(db)
+            while not gen.delivered(dest):
+                assert not pipeline._apply_task.done(), "stream stopped"
+                await asyncio.sleep(0.05)
+
+        started = []
+        try:
+            for gen, db, store, dest, pipeline in streams:
+                await pipeline.start()
+                started.append(pipeline)
+                for tid in gen.table_ids:
+                    await asyncio.wait_for(
+                        store.notify_on(tid, TableStateType.READY), 60)
+            # a cold program serves its first batches from the oracle
+            # while it builds on a background thread: warm both streams,
+            # then count grants over traffic the built programs decode
+            await asyncio.gather(*(
+                produce_and_verify(g, db, d, p, 60)
+                for g, db, _, d, p in streams))
+            while engine.background_compiles_inflight():
+                await asyncio.sleep(0.05)
+            grants0 = registry.sum_counter(ETL_DECODE_ADMISSION_GRANTS_TOTAL)
+            await asyncio.gather(*(
+                produce_and_verify(g, db, d, p, 1500)
+                for g, db, _, d, p in streams))
+            grants = registry.sum_counter(
+                ETL_DECODE_ADMISSION_GRANTS_TOTAL) - grants0
+            tenants = global_admission().stats()["tenants"]
+        finally:
+            for pipeline in started:
+                await pipeline.shutdown_and_wait()
+        assert grants > 0
+        assert len(tenants) >= 2
+        drained = global_admission().stats()
+        assert drained["in_flight"] == 0 and not drained["tenants"]
+
     def test_oracle_route_takes_no_ticket(self):
         schema = make_schema([Oid.INT4, Oid.INT8])
         # default thresholds: a 4-row batch routes to the oracle

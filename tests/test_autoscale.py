@@ -4,8 +4,8 @@ serialization + seeded-timeline determinism, the decision journal's
 persistence (memory + sqlite) and resume idempotence, controller
 actuation/overlap/resume/abort against stub coordinators, admission SLO
 weights, the orchestrator scale seam, the replay CLI's deterministic
-trace, the bench reaction-time gate, and the two chaos scenarios in
-tier-1."""
+trace, the policy's reaction time in ticks, and the two chaos scenarios
+in tier-1."""
 
 from __future__ import annotations
 
@@ -698,21 +698,32 @@ class TestReplayCli:
 
 class TestBenchGate:
     def test_reaction_time_gate_green(self):
-        import importlib.util
-        import sys
-        from pathlib import Path
+        """The seeded surge -> drain timeline through the policy with the
+        applied-K loop closed, counted in policy ticks: scale-up within
+        3 ticks of the surge, no scale-down inside the cooldown, back to
+        the starting K, and the same decisions on a second run of the
+        seed."""
+        surge_at = 10
+        config = AutoscalePolicyConfig(
+            min_shards=2, max_shards=3, drain_slo_s=2.0,
+            up_backlog_bytes=256 * 1024, down_backlog_bytes=64 * 1024,
+            up_ticks=2, down_ticks=3, cooldown_ticks=5)
 
-        path = Path(__file__).resolve().parents[1] / "bench.py"
-        spec = importlib.util.spec_from_file_location("_bench_as", path)
-        mod = importlib.util.module_from_spec(spec)
-        sys.modules["_bench_as"] = mod
-        spec.loader.exec_module(mod)
-        out = mod.run_autoscale_bench(seed=7, reaction_ticks_max=3)
-        assert out["ok"], out["failures"]
-        assert out["reaction_ticks"] <= 3
-        assert out["scale_down_tick"] - out["scale_up_tick"] \
-            >= out["cooldown_ticks"]
-        assert out["deterministic"]
+        def trace():
+            timeline = seeded_surge_timeline(7, shards=2, ticks=40,
+                                             surge_at=surge_at)
+            return [d.describe() for d in simulate(
+                timeline.frames, AutoscalePolicy(config), 2)]
+
+        first = trace()
+        assert first == trace()
+        moves = [(d["tick"], d["action"], d["target_k"]) for d in first
+                 if d["action"] != ACTION_HOLD]
+        assert [a for _, a, _ in moves] == [ACTION_UP, ACTION_DOWN]
+        (up_tick, _, up_k), (down_tick, _, down_k) = moves
+        assert 0 <= up_tick - surge_at <= 3
+        assert down_tick - up_tick >= config.cooldown_ticks
+        assert (up_k, down_k) == (3, 2)
 
 
 class TestChaosScenarios:

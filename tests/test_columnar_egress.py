@@ -736,6 +736,96 @@ class TestSeamPlumbing:
         finally:
             await server.stop()
 
+    async def test_streamed_cdc_constructs_no_rows(self):
+        """The whole streamed path — fake walsender -> apply loop ->
+        assembler -> decode engine -> a sink that resolves batches —
+        builds ZERO TableRows once the table's program is warm: decoded
+        batches reach the destination columnar."""
+        from etl_tpu.config import (BatchConfig, BatchEngine,
+                                    PipelineConfig)
+        from etl_tpu.models.table_state import TableStateType
+        from etl_tpu.ops import engine
+        from etl_tpu.postgres.fake import FakeDatabase, FakeSource
+        from etl_tpu.runtime import Pipeline
+        from etl_tpu.store import NotifyingStore
+        from etl_tpu.telemetry.metrics import (
+            ETL_DECODE_ROUTED_ORACLE_ROWS_TOTAL, registry)
+
+        class ResolvingSink(Destination):
+            rows = 0
+
+            async def startup(self):
+                return None
+
+            async def write_table_rows(self, schema, batch):
+                return WriteAck.durable()
+
+            async def write_events(self, events):
+                for e in events:
+                    if isinstance(e, DecodedBatchEvent):
+                        self.rows += e.batch.num_rows  # resolves it
+                    elif isinstance(e, InsertEvent):
+                        self.rows += 1
+                return WriteAck.durable()
+
+            async def drop_table(self, table_id, schema=None):
+                return None
+
+            async def truncate_table(self, table_id):
+                return None
+
+        tid = 41037
+        db = FakeDatabase()
+        db.create_table(TableSchema(
+            tid, TableName("public", "stream_t"),
+            (ColumnSchema("id", Oid.INT8, nullable=False,
+                          primary_key_ordinal=1),
+             ColumnSchema("v", Oid.INT4),
+             ColumnSchema("note", Oid.TEXT))))
+        db.create_publication("pub", [tid])
+        store, dest = NotifyingStore(), ResolvingSink()
+        # a long fill window: flushes are cut at commits, so every flush
+        # of a 200-row transaction stages into the same row bucket
+        pipeline = Pipeline(
+            config=PipelineConfig(
+                pipeline_id=1, publication_name="pub",
+                batch=BatchConfig(max_fill_ms=2000,
+                                  batch_engine=BatchEngine.TPU)),
+            store=store, destination=dest,
+            source_factory=lambda: FakeSource(db))
+
+        async def commit_and_deliver(first: int) -> None:
+            want = dest.rows + 200
+            tx = db.transaction()
+            for i in range(first, first + 200):
+                tx.insert(tid, [str(i), str(i % 97), "note-%d" % i])
+            await tx.commit()
+            while dest.rows < want:
+                assert not pipeline._apply_task.done(), "stream stopped"
+                await asyncio.sleep(0.01)
+
+        await pipeline.start()
+        try:
+            await asyncio.wait_for(
+                store.notify_on(tid, TableStateType.READY), 60)
+            # the cold program builds on a background thread while the
+            # per-row oracle (which does build rows) serves the batch
+            await commit_and_deliver(1000)
+            while engine.background_compiles_inflight():
+                await asyncio.sleep(0.02)
+            before = rows_constructed()
+            oracle0 = registry.get_counter(
+                ETL_DECODE_ROUTED_ORACLE_ROWS_TOTAL)
+            for k in range(2, 6):
+                await commit_and_deliver(k * 1000)
+            assert registry.get_counter(
+                ETL_DECODE_ROUTED_ORACLE_ROWS_TOTAL) == oracle0, \
+                "the measured transactions were not decoded by the engine"
+            assert rows_constructed() == before, \
+                "the streamed CDC path constructed TableRows"
+        finally:
+            await pipeline.shutdown_and_wait()
+
     async def test_memory_shim_still_expands(self):
         from etl_tpu.destinations.memory import MemoryDestination
 

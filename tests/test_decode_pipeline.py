@@ -2,15 +2,12 @@
 multiple in-flight pendings per decoder, byte-identical pipelined vs
 serial output, fallback fixup with a second batch in flight, the LRU
 program cache, mesh row-capacity padding, arena reuse, the in-flight
-window's backpressure behavior, and the bench.py --smoke CI gate."""
+window's backpressure behavior."""
 
-import subprocess
-import sys
 import threading
 import time
 import types
 from collections import OrderedDict
-from pathlib import Path
 
 import numpy as np
 import pytest
@@ -154,8 +151,7 @@ class TestMultipleInFlight:
 
     def test_overlap_recorded(self):
         """Pack of batch N+1 concurrent with batch N in flight must show
-        up in the pipeline's overlap accounting (the acceptance-criteria
-        signal, measured the same way bench.py reports it)."""
+        up in the pipeline's overlap accounting."""
         schema = make_schema(OIDS)
         dec = DeviceDecoder(schema, device_min_rows=0)
         pipe = DecodePipeline(window=3)
@@ -170,6 +166,29 @@ class TestMultipleInFlight:
             assert stats["overlap_seconds_total"] > 0
         finally:
             pipe.close()
+
+    def test_stage_histograms_observed(self):
+        """One run through the pipeline leaves observations in each of
+        the three stage series (pack / dispatch / fetch) — the series
+        every per-layer reading of the decode stage is computed from."""
+        from etl_tpu.telemetry.metrics import (ETL_DECODE_DISPATCH_SECONDS,
+                                               ETL_DECODE_FETCH_SECONDS,
+                                               ETL_DECODE_PACK_SECONDS,
+                                               registry)
+
+        series = (ETL_DECODE_PACK_SECONDS, ETL_DECODE_DISPATCH_SECONDS,
+                  ETL_DECODE_FETCH_SECONDS)
+        before = [registry.get_histogram(n)[0] for n in series]
+        dec = DeviceDecoder(make_schema(OIDS), device_min_rows=0)
+        pipe = DecodePipeline(window=2)
+        try:
+            for h in [pipe.submit(dec, _stage(_rows(300, k * 300)))
+                      for k in range(3)]:
+                h.result()
+        finally:
+            pipe.close()
+        for name, n0 in zip(series, before):
+            assert registry.get_histogram(name)[0] >= n0 + 3, name
 
     def test_failed_fetch_is_permanent(self):
         """A fetch failure released the arena already — retrying result()
@@ -385,148 +404,3 @@ class TestInFlightWindow:
     def test_rejects_zero_limit(self):
         with pytest.raises(ValueError):
             InFlightWindow(0)
-
-
-class TestBenchSmoke:
-    def test_bench_smoke_gate(self):
-        """The CI gate itself: bench.py --smoke on the CPU backend must
-        report pipelined == serial and nonzero stage observations."""
-        import json
-        import os
-
-        repo = Path(__file__).resolve().parent.parent
-        env = dict(os.environ, JAX_PLATFORMS="cpu")
-        proc = subprocess.run(
-            [sys.executable, str(repo / "bench.py"), "--smoke"],
-            capture_output=True, text=True, timeout=600, cwd=repo, env=env)
-        assert proc.returncode == 0, proc.stderr[-2000:]
-        out = json.loads(proc.stdout.strip().splitlines()[-1])
-        assert out["ok"] is True
-        assert out["pipelined_equals_serial"] is True
-        assert out["stage_histograms_observed"] is True
-        # streaming A/B regression gate (chaos satellite): a short
-        # end-to-end run must clear the checked-in floor so a round-5
-        # style CDC throughput collapse can never ship silently
-        assert out["streaming_above_floor"] is True, out
-        assert out["streaming_events_per_sec"] >= \
-            out["streaming_floor_events_per_sec"]
-        # supervision satellite: heartbeat instrumentation must cost <1%
-        # of the floor's per-event budget even at one beat per event
-        # (the streaming run above already measured the REAL pipeline
-        # with supervision live against the same floor)
-        assert out["heartbeat_overhead_under_1pct"] is True, out
-        assert out["heartbeat_overhead_ratio_at_floor"] < 0.01
-        # static-analysis satellite: the whole-program etl-lint pass must
-        # complete inside its wall-clock budget so it stays cheap enough
-        # to gate every PR
-        assert out["static_analysis_under_budget"] is True, out
-        assert out["static_analysis_seconds"] < \
-            out["static_analysis_budget_s"]
-        # IR-tier satellite (ISSUE 16): the compiled-program contract
-        # pass (`--programs --mesh`) must run CLEAN — exit 0 over every
-        # enumerable canonical layout, single-device AND forced-8-shard
-        # mesh — and inside its own wall-clock budget
-        assert out["ir_analysis_clean"] is True, out
-        assert out["ir_analysis_under_budget"] is True, out
-        assert out["ir_analysis_seconds"] < out["ir_analysis_budget_s"]
-        # columnar-egress satellites (ISSUE 6): ZERO TableRow
-        # constructions on the streamed CDC hot path (the decode engine's
-        # batches must reach the destination columnar fetch-to-wire), and
-        # every destination encoder above its isolation floor so an
-        # egress regression names the guilty encoder
-        assert out["streaming_zero_row_materialization"] is True, out
-        assert out["streaming_table_rows_constructed"] == 0
-        assert out["egress_encoders_above_floor"] is True, out
-        assert out["egress_failures"] == []
-        # workload-diversity satellite (ISSUE 7): the mixed-profile slice
-        # (update-heavy + truncate-storm) must deliver a VERIFIED end
-        # state above its per-workload floor, so a regression that only
-        # bites non-insert traffic fails CI instead of hiding behind the
-        # insert-CDC streaming floor
-        assert out["workload_profiles_above_floor"] is True, out
-        assert out["workload_failures"] == []
-        # mesh satellite (ISSUE 8): sharded decode on the FORCED 8-way
-        # host-platform mesh must be byte-identical to single-device
-        # decode (the subprocess gate — this process's backend stays at
-        # one device)
-        assert out["mesh_check_ok"] is True, out
-        assert out["mesh_sharded_equals_single"] is True
-        assert out["mesh_shards"] == 8
-        # multi-pipeline tenancy gate (ISSUE 8): ≥2 concurrent verified
-        # streams through the shared admission scheduler, aggregate
-        # above the floor, scheduler drained with no leaked tickets
-        assert out["multi_pipeline_ok"] is True, out
-        assert out["multi_pipeline_streams"] >= 2
-        assert out["multi_pipeline_all_verified"] is True
-        assert out["multi_pipeline_scheduler_drained"] is True
-        assert out["multi_pipeline_events_per_sec"] >= \
-            out["multi_pipeline_floor_events_per_sec"]
-        assert out["multi_pipeline_admission_grants"] > 0
-        assert set(out["workload_events_per_sec"]) >= \
-            {"update_heavy_default", "truncate_storm"}
-        # sharded scale-out gates (ISSUE 9): the K=2 pod-kill chaos
-        # scenario must hold every invariant (survivors unaffected,
-        # victim reconverges, per-shard + cross-shard-union checks), and
-        # the K=2 sharded bench slice (one worker process per shard)
-        # must clear the aggregate floor with every slice verified
-        assert out["sharded_chaos_ok"] is True, out["sharded_chaos"]
-        assert out["sharded_chaos"]["union_matches"] is True
-        assert out["sharded_ok"] is True, out
-        assert out["sharded_shards"] == 2
-        assert out["sharded_all_verified"] is True
-        assert out["sharded_union_covers_all_tables"] is True
-        assert out["sharded_events_per_sec"] >= \
-            out["sharded_floor_events_per_sec"]
-        # program-cache coldstart gate (ISSUE 12): the warm restart must
-        # compile ZERO fresh XLA programs — its first durable batch is
-        # served from disk-loaded executables, and the cold run's
-        # compile count is bounded by canonical layouts, not tables
-        assert out["coldstart_ok"] is True, out["coldstart_failures"]
-        assert out["coldstart_warm_zero_compiles"] is True
-        assert out["coldstart_failures"] == []
-        # autoscale gates (ISSUE 13): the policy reaction-time gate
-        # (seeded surge -> scale-up within the tick budget, scale-down
-        # only after the cooldown, deterministic trace) AND the
-        # end-to-end elasticity chaos scenario (a live K=2 fleet scales
-        # to 3 under flowing traffic via the controller and back after
-        # the cooldown, invariants across both rebalances)
-        assert out["autoscale_ok"] is True, out["autoscale_failures"]
-        assert out["autoscale_reaction_ticks"] <= 3
-        assert out["autoscale_deterministic"] is True
-        assert out["autoscale_chaos_ok"] is True, out["autoscale_chaos"]
-        assert out["autoscale_chaos"]["union_matches"] is True
-        # fleet converge gate (ISSUE 18): the 100-pipeline declarative
-        # reconcile — empty -> steady and through one add/remove/resize
-        # edit within the working-tick budget, every runtime actuation
-        # backed 1:1 by an applied journal record (zero
-        # double-actuations), and a deterministic actuation trace
-        assert out["fleet_ok"] is True, out["fleet_failures"]
-        assert out["fleet_converge_ticks"] <= \
-            out["fleet_converge_ticks_max"]
-        assert out["fleet_edit_converge_ticks"] <= \
-            out["fleet_converge_ticks_max"]
-        assert out["fleet_double_actuations"] == 0
-        assert out["fleet_deterministic"] is True
-        # windowed-ack gate (ISSUE 14): the same deterministic backlog
-        # through the default write window vs a forced window=1 run —
-        # speedup above the floor, byte-identical delivery, the
-        # one-in-flight contract at window=1, provable overlap
-        assert out["ack_window_ok"] is True, out["ack_window_failures"]
-        assert out["ack_window_speedup"] >= \
-            out["ack_window_speedup_floor"]
-        assert out["ack_window_max_pending"] >= 2
-        assert out["ack_window_failures"] == []
-        # poison-resilience gates (ISSUE 15): the clean-vs-poisoned A/B
-        # (throughput ratio above the floor, bisection probe writes
-        # within the 2·log2(batch) bound, union invariant verified) AND
-        # the dead-letter chaos scenario (poison rows quarantine their
-        # table while survivors deliver everything; replay +
-        # unquarantine restores exact committed truth)
-        assert out["poison_ok"] is True, out["poison_failures"]
-        assert out["poison_throughput_ratio"] >= \
-            out["poison_ratio_floor"]
-        assert out["poison_probe_writes"] <= out["poison_probe_bound"]
-        assert out["poison_dlq_entries"] >= 1
-        assert out["poison_failures"] == []
-        assert out["dlq_chaos_ok"] is True, out["dlq_chaos"]
-        assert out["dlq_chaos"]["quarantined_tables"] == [16384]

@@ -15,8 +15,7 @@ columnar encoders on every destination format. Covered here:
      copy and CDC shapes;
   4. the engine seam: `ColumnarBatch.device_egress` attach on the host
      dispatch route, encoder-dependent field selection, config gating,
-     and `DeviceEgress.concat` all-or-nothing merging;
-  5. `bench.py --egress --device` floor wiring (egress_floors).
+     and `DeviceEgress.concat` all-or-nothing merging.
 """
 
 from __future__ import annotations
@@ -539,24 +538,3 @@ class TestDeviceEgressConcat:
         cb = CoalescedBatch([ev1, ev2])
         assert cb.egress is not None
         assert cb.egress.n_rows == 128
-
-
-# ---------------------------------------------------------------------------
-# 5. bench floor wiring
-# ---------------------------------------------------------------------------
-
-
-class TestBenchFloors:
-    def test_egress_floors_present(self):
-        import json
-        from pathlib import Path
-
-        doc = json.loads((Path(__file__).resolve().parents[1]
-                          / "BENCH_FLOOR.json").read_text())
-        floors = doc.get("egress_floors")
-        assert floors, "egress_floors missing from BENCH_FLOOR.json"
-        assert "device_tsv_rows_per_sec" in floors
-        assert "device_json_rows_per_sec" in floors
-        # the acceptance gate: streamed-CDC floor raised 4x with device
-        # egress live (ISSUE 17)
-        assert doc["table_streaming_events_per_sec_floor"] >= 160000

@@ -446,23 +446,26 @@ class TestExactlyOnceChaos:
         assert outs[0] == outs[1]
 
 
-# -- satellite 5: the bench harness slice -------------------------------------
+# -- satellite 5: the restart leg's arithmetic ---------------------------------
 
 
 class TestExactlyOnceBenchHarness:
     async def test_run_exactly_once_smoke_slice(self):
-        """One small pass of the full A/B + restart-leg harness: the
-        gate arithmetic (zero dups, loss, re-stream <= unacked suffix,
-        seam coverage) holds at smoke size."""
-        from etl_tpu.benchmarks import harness
+        """One hard kill inside the write-vs-progress gap, counted: no
+        row twice, none lost, what the restart re-streamed and the sink
+        deduped is at most the suffix that was unacked at the kill, the
+        restart asked the sink for its high-water mark, and no CDC write
+        went round the committed seam."""
+        from etl_tpu.chaos.exactly_once import _run_window
+        from etl_tpu.chaos.invariants import InvariantReport
 
-        out = await harness.run_exactly_once(n_events=400, tx_size=20,
-                                             repeats=1)
-        assert out["failures"] == [], out
-        assert out["ok"] is True
-        assert out["transactional"]["uncoordinated_writes"] == 0
-        leg = out["restart"]
-        assert leg["duplicate_rows"] == 0
-        assert leg["rows_delivered"] == 400
-        assert leg["restreamed_deduped_rows"] <= leg["unacked_suffix_rows"]
-        assert leg["recover_calls"] >= 1
+        report = InvariantReport()
+        w = await _run_window("pre_progress", 5, report)
+        # zero loss, no uncoordinated write and the suffix bound are
+        # violations of the report; the counts are asserted beside it
+        assert report.ok, report.violations
+        assert w["max_duplication"] == 1
+        assert w["delivered_events"] > 0
+        assert w["unacked_suffix_rows"] >= 1
+        assert w["dedup_skipped_rows"] <= w["unacked_suffix_rows"]
+        assert w["recover_calls"] >= 1

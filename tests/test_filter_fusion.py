@@ -19,7 +19,6 @@ import random
 import numpy as np
 import pytest
 
-from etl_tpu.benchmarks.harness import _filtered_batches_identical
 from etl_tpu.models import (ColumnSchema, Oid, ReplicatedTableSchema,
                             TableName, TableSchema)
 from etl_tpu.models.lsn import Lsn
@@ -29,6 +28,7 @@ from etl_tpu.ops.predicate import (And, Cmp, Not, NullTest, Or, RowFilter,
                                    parse_row_filter)
 from etl_tpu.postgres.codec.pgoutput import (TUPLE_NULL, TUPLE_TEXT,
                                              TupleData)
+from etl_tpu.testing.batches import batches_identical
 
 rng = random.Random(1234)
 
@@ -71,9 +71,9 @@ def decode_all_engines(rts, staged):
 
 def assert_all_identical(rts, staged, expected_survivors=None):
     xla, pal, host, orc = decode_all_engines(rts, staged)
-    assert _filtered_batches_identical(xla, pal), "pallas != xla"
-    assert _filtered_batches_identical(xla, host), "host-XLA != xla"
-    assert _filtered_batches_identical(xla, orc), "oracle != xla"
+    assert batches_identical(xla, pal), "pallas != xla"
+    assert batches_identical(xla, host), "host-XLA != xla"
+    assert batches_identical(xla, orc), "oracle != xla"
     if expected_survivors is not None:
         assert xla.source_rows is not None
         assert list(xla.source_rows) == list(expected_survivors)
@@ -318,6 +318,54 @@ class TestSelectivityEdges:
         assert_all_identical(rts, staged, expected)
 
 
+class TestFetchedBytes:
+    """What the fusion is for: a filtered program fetches the survivors,
+    not the batch. A count of bytes (etl_decode_fetched_bytes_total),
+    never a time."""
+
+    #: over the measured keep fraction: the keep mask (1 bit/row), the
+    #: survivor-count words and staging.slice_rows' fetch granularity
+    #: (max(R/16, 256) rows)
+    SLACK = 0.11
+    N = 4096
+
+    @staticmethod
+    def _fetched(dec, staged):
+        from etl_tpu.telemetry.metrics import (
+            ETL_DECODE_FETCHED_BYTES_TOTAL, registry)
+
+        before = registry.get_counter(ETL_DECODE_FETCHED_BYTES_TOTAL)
+        batch = dec.decode(staged)
+        return batch, \
+            registry.get_counter(ETL_DECODE_FETCHED_BYTES_TOTAL) - before
+
+    @pytest.mark.parametrize("keep", [0.1, 0.5, 0.9])
+    def test_filtered_fetch_is_bounded_by_selectivity(self, keep):
+        vals = np.random.RandomState(11).randint(-10**6, 10**6, self.N)
+        staged = stage_texts(
+            [[str(i), str(int(v)), f"n-{i}"] for i, v in enumerate(vals)],
+            3)
+        cols = [Oid.INT8, Oid.INT4, Oid.TEXT]
+        threshold = int(-10**6 + 2 * 10**6 * keep)
+        sql = f"c1 < {threshold}"
+        want = int((vals < threshold).sum())
+        # the device program and its host-XLA twin, each against its own
+        # unfiltered program
+        for label, route in (
+                ("xla", dict(device_min_rows=1)),
+                ("host", dict(device_min_rows=10**9, host_min_rows=1))):
+            _, plain = self._fetched(
+                DeviceDecoder(make_rts(cols), mesh=None, **route), staged)
+            batch, filtered = self._fetched(
+                DeviceDecoder(make_rts(cols, sql), mesh=None, **route),
+                staged)
+            assert batch.num_rows == want, label
+            assert plain > 0, label
+            assert filtered / plain <= want / self.N + self.SLACK, \
+                f"{label}: fetched {filtered} of {plain} bytes at " \
+                f"keep {want / self.N:.3f}"
+
+
 # ---------------------------------------------------------------------------
 # fallback bookkeeping in the compacted index space
 # ---------------------------------------------------------------------------
@@ -366,7 +414,7 @@ class TestFallbackRemap:
         xla = DeviceDecoder(rts, device_min_rows=10**9, host_min_rows=1,
                             mesh=None).decode(staged)
         orc = oracle_decoder(rts).decode(staged)
-        assert _filtered_batches_identical(xla, orc)
+        assert batches_identical(xla, orc)
         assert list(xla.source_rows) == expected
 
     def test_update_runs_are_never_filtered(self):
@@ -406,7 +454,7 @@ class TestMeshShardedIdentity:
             .decode(staged)
         sharded = DeviceDecoder(rts, device_min_rows=0, mesh=mesh,
                                 mesh_min_rows=0).decode(staged)
-        assert _filtered_batches_identical(single, sharded)
+        assert batches_identical(single, sharded)
         allows = rts.row_predicate.compile_texts(rts.table_schema)
         expected = [i for i, r in enumerate(rows) if allows(r)]
         assert list(sharded.source_rows) == expected
@@ -483,7 +531,7 @@ class TestPipelinedFiltering:
                        for _ in range(3)]
             for h in handles:
                 got = h.result()
-                assert _filtered_batches_identical(serial, got)
+                assert batches_identical(serial, got)
                 assert list(got.source_rows) == list(range(250, 1000))
         finally:
             pipe.close()

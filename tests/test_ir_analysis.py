@@ -3,8 +3,9 @@
 Falsifiability: every one of the six compiled-program contracts must
 FIRE on a deliberately-violating program — a contract that cannot fail
 verifies nothing. The clean repo-wide gate (the catalog passing all
-contracts) lives in bench --smoke / test_decode_pipeline's smoke
-asserts; here each checker sees a program built to break it.
+contracts, single-device and on the forced-8-shard mesh) is
+`TestRepoCatalogClean`; before it each checker sees a program built to
+break it.
 
 Determinism: two runs over the same layout set must produce
 byte-identical findings (fingerprints, ordering) and path sets —
@@ -214,6 +215,20 @@ class TestDeterminism:
                          tuple(paths)))
         assert runs[0] == runs[1]
         assert runs[0][1], "mesh pass must enumerate mesh variants"
+        # and the repo's mesh variants hold every contract
+        assert json.loads(runs[0][0]) == []
+
+
+class TestRepoCatalogClean:
+    def test_every_enumerable_program_holds_every_contract(self):
+        """`python -m etl_tpu.analysis --programs`: every canonical
+        layout the catalog enumerates, at the production row buckets,
+        lowered through the production jit constructor, violates none of
+        the six contracts (the baseline holds no programs/ entry). The
+        mesh variants are held by the subprocess test above."""
+        findings, paths = analyze_local()
+        assert paths, "the catalog enumerated no program"
+        assert [f.to_dict() for f in findings] == []
 
 
 # ---------------------------------------------------------------------------

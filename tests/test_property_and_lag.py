@@ -81,32 +81,3 @@ class TestSlotLag:
             await conn.close()
         finally:
             await server.stop()
-
-
-class TestBenchHarnessSmoke:
-    """The driver captures BENCH_r{N}.json by running bench.py at the end
-    of every round — a broken harness silently costs the round's
-    measurement, so the streaming and lag-vs-rate paths get CI-sized
-    smoke coverage here (tiny event counts, CPU engine)."""
-
-    async def test_table_streaming_smoke(self):
-        from etl_tpu.benchmarks.harness import run_table_streaming
-
-        out = await run_table_streaming(n_events=2000, engine="cpu")
-        assert out["mode"] == "table_streaming"
-        assert out["throughput_events"] == 2000  # no loss
-        assert out["end_to_end_events_per_second"] > 0
-        assert out["replication_lag_p50_ms"] is not None
-        assert out["replication_lag_p95_ms"] >= out["replication_lag_p50_ms"]
-
-    async def test_lag_vs_rate_smoke(self):
-        from etl_tpu.benchmarks.harness import run_lag_vs_rate
-
-        out = await run_lag_vs_rate(engine="cpu", fractions=(0.5,),
-                                    probe_events=2000, per_rate_cap=4000)
-        assert out["mode"] == "lag_vs_rate"
-        assert out["max_events_per_second"] > 0
-        (row,) = out["rates"]
-        assert row["fraction"] == 0.5
-        assert row["events"] >= 3000 and row["p50_ms"] is not None
-        assert row["p95_ms"] >= row["p50_ms"]

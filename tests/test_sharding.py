@@ -10,7 +10,7 @@ Covers ISSUE 9's acceptance bars in-tree:
   - K=2 sharded pipelines over ONE fake source: per-shard delivery,
     delivery isolation, sibling tables never purged;
   - ShardCoordinator K=2→3: the fence-LSN handoff loses nothing;
-  - the chaos pod-kill scenario (also gated in bench.py --smoke);
+  - the chaos pod-kill scenario;
   - shard-aware K8s/local orchestration fan-out.
 """
 

@@ -25,7 +25,7 @@ from etl_tpu.chaos.corpus import (WORKLOAD_MATRIX, WORKLOAD_MATRIX_PROFILES,
                                   get_scenario)
 from etl_tpu.chaos.invariants import reconstruct_final_view
 from etl_tpu.chaos.runner import run_scenario
-from etl_tpu.chaos.scenario import FaultKind
+from etl_tpu.chaos.scenario import FaultKind, Scenario
 from etl_tpu.models.cell import TOAST_UNCHANGED
 from etl_tpu.models.event import (DeleteEvent, InsertEvent, TruncateEvent,
                                   UpdateEvent)
@@ -457,59 +457,47 @@ class TestChaosWorkloadMatrix:
 
 
 class TestBenchWiring:
-    def test_workload_floors_published_and_gated(self):
-        """Every profile has a floor in BENCH_FLOOR.json and the smoke
-        slice names >=2 profiles covering update + truncate traffic."""
-        repo = Path(__file__).resolve().parent.parent
-        floors = json.loads((repo / "BENCH_FLOOR.json").read_text())
-        wfloors = floors["workload_floors"]
-        assert set(wfloors) == set(ALL_PROFILES)
-        assert all(v > 0 for v in wfloors.values())
-        smoke = floors["workload_smoke_profiles"]
-        assert len(smoke) >= 2
-        assert "update_heavy_default" in smoke
-        assert "truncate_storm" in smoke
-        assert all(p in wfloors for p in smoke)
+    """A profile through the whole `Pipeline` with no fault injected:
+    the chaos runner's plain drive, held to its end-state check."""
+
+    @staticmethod
+    def _plain(profile: str, txs: int) -> Scenario:
+        return Scenario(name=f"plain__{profile}",
+                        description="no faults: deliver and verify",
+                        workload=profile, txs=txs)
 
     async def test_workload_streaming_verifies_end_state(self):
-        """The bench harness's per-profile run delivers AND verifies (a
-        throughput number over silently-wrong deliveries is worse than
-        none). One fast profile keeps this inside the tier-1 budget."""
-        from etl_tpu.benchmarks import harness
+        """Delivered AND verified: the sink's reconstructed final view
+        equals the generator's committed truth, with no restart and no
+        fault to explain a difference."""
+        run = await run_scenario(self._plain("delete_heavy_default", 24),
+                                 SEED)
+        assert run.ok, run.describe()
+        assert run.restarts == [] and run.fault_firings == 0
+        stats = run.report.stats
+        assert stats["lost_rows"] == 0 and stats["expected_rows"] > 0
+        assert stats["delivered_events"] >= 120
+        assert stats["max_duplication"] == 1
 
-        out = await harness.run_workload_streaming(
-            "delete_heavy_default", seed=SEED, target_ops=120)
-        assert out["verified"] is True
-        assert out["row_ops"] >= 120
-        assert out["events_per_second"] > 0
-
-    async def test_workload_streaming_reports_verification_failure(self,
-                                                                   monkeypatch):
-        """A destination view that never matches the committed truth must
-        come back as verified=False (and shut the pipeline down), not
-        hang into an unhandled TimeoutError — the failure report run_smoke
-        and the OPERATIONS runbook gate on."""
+    async def test_workload_streaming_reports_verification_failure(
+            self, monkeypatch):
+        """A destination view that never matches the committed truth
+        comes back as a failed run that has shut its pipeline down — not
+        a hang, not an unhandled TimeoutError."""
         from etl_tpu import workloads
-        from etl_tpu.benchmarks import harness
+        from etl_tpu.chaos.invariants import _pipeline_thread_count
+        from etl_tpu.chaos.runner import _wait_until
 
-        real = workloads.WorkloadGenerator.delivered
-        state = {"warmed": False}
-
-        def delivered(self, dest):
-            # let the warmup wave verify once, then report a permanent
-            # mismatch for the measured window
-            if state["warmed"]:
-                return False
-            if real(self, dest):
-                state["warmed"] = True
-                return True
-            return False
-
+        threads = _pipeline_thread_count()
         monkeypatch.setattr(workloads.WorkloadGenerator, "delivered",
-                            delivered)
-        out = await harness.run_workload_streaming(
-            "insert_heavy", seed=SEED, target_ops=60, verify_timeout_s=3)
-        assert out["verified"] is False
+                            lambda self, dest: False)
+        run = await run_scenario(self._plain("insert_heavy", 6), SEED,
+                                 timeout_s=4.0)
+        assert not run.ok
+        assert any("did not complete" in v
+                   for v in run.report.violations), run.report.violations
+        await _wait_until(lambda: _pipeline_thread_count() <= threads,
+                          2.0, "pipeline threads lingering")
 
 
 class TestReviewRegressions:
