@@ -36,6 +36,7 @@ from ..models.default_expression import column_default_sql
 from ..models.schema import (ReplicatedTableSchema, SchemaDiff, TableId,
                              TableName)
 from ..models.table_row import ColumnarBatch
+from ..native import native_available
 from ..analysis.annotations import transactional_commit
 from ..telemetry import spans
 from ..telemetry.metrics import (ETL_CLICKHOUSE_RENDER_SECONDS,
@@ -489,6 +490,9 @@ class ClickHouseDestination(Destination):
     # -- Destination ------------------------------------------------------------
 
     async def startup(self) -> None:
+        # the TSV assembly runs on the loop and never builds the native
+        # library (ops/egress.assemble_rows): load it here, off the loop
+        await asyncio.to_thread(native_available)
         await self._execute(
             f"CREATE DATABASE IF NOT EXISTS `{self.config.database}`")
 

@@ -36,6 +36,7 @@ from ..models.event import (ChangeType, DeleteEvent, Event, InsertEvent,
 from ..models.pgtypes import CellKind
 from ..models.schema import ReplicatedTableSchema, TableId
 from ..models.table_row import ColumnarBatch
+from ..native import native_available
 from .base import CommitRange, Destination, WriteAck, expand_batch_events
 from ..models.default_expression import column_default_sql
 from .bigquery import encode_value  # same JSON value encoding rules
@@ -445,6 +446,9 @@ class SnowflakeDestination(Destination):
         return await with_retries(attempt, self.retry, retryable)
 
     async def startup(self) -> None:
+        # the NDJSON assembly runs on the loop and never builds the native
+        # library (ops/egress.assemble_rows): load it here, off the loop
+        await asyncio.to_thread(native_available)
         await self._sql(
             f'CREATE SCHEMA IF NOT EXISTS "{self.config.schema}"')
 

@@ -1,12 +1,15 @@
-"""Native host components: the pgoutput framer, the CopyData block scan
-and the COPY chunk scan (C, via ctypes).
+"""Native host components: the pgoutput framer, the CopyData block scan,
+the COPY chunk scan and the assembly of a columnar write's body (C, via
+ctypes).
 
 Builds `framer.c` with the system compiler on first import (cached as
 `_framer-<hash>.so`); falls back to a pure-Python walker with identical
 outputs when no compiler is available. `frame_pgoutput` is the framer's
 entry point (see ops/wal.py for the staging layer that consumes it);
 `scan_copy_data` is the COPY stream's (postgres/wire.py `copy_out`);
-`scan_copy_chunk` is the copy staging's (ops/staging.py `stage_copy_chunk`).
+`scan_copy_chunk` is the copy staging's (ops/staging.py `stage_copy_chunk`);
+`assemble_rows` and `int_text_fixed` are the destinations' line assembly
+(ops/egress.py, under the same names).
 """
 
 from __future__ import annotations
@@ -30,6 +33,12 @@ COPY_SCAN_MORE, COPY_SCAN_SLOW = 0, 1
 # count that is not rows x columns; the count right and a row ragged
 COPY_STAGE_OK, COPY_STAGE_COUNT, COPY_STAGE_RAGGED = 0, 1, 2
 _COPY_STAGE_FULL = 3  # more rows than the outputs it was given hold
+# the piece kinds of assemble_rows and the integer kinds of int_text_fixed
+# (framer.c); INT_TEXT_WIDTH bytes hold every int64
+_PIECE_KINDS = {"const": 0, "fixed": 1, "var": 2}
+_INT_TEXT_KINDS = {np.dtype(np.int16): 0, np.dtype(np.int32): 1,
+                   np.dtype(np.uint32): 2, np.dtype(np.int64): 3}
+INT_TEXT_WIDTH = 21
 
 _lib = None
 _build_error: str | None = None
@@ -104,6 +113,20 @@ def _load() -> ctypes.CDLL | None:
             ctypes.c_void_p, ctypes.c_void_p,  # offsets, lengths [max,C]
             ctypes.c_void_p, ctypes.c_void_p,  # nulls [max,C], fallback
             ctypes.c_void_p,  # res[3]
+        ]
+        lib.etl_int_text_fixed.restype = ctypes.c_int32
+        lib.etl_int_text_fixed.argtypes = [
+            ctypes.c_void_p, ctypes.c_int64, ctypes.c_int32,  # vals, n, kind
+            ctypes.c_void_p, ctypes.c_void_p,  # buf [n,21], lens [n]
+        ]
+        lib.etl_assemble_rows.restype = ctypes.c_int64
+        lib.etl_assemble_rows.argtypes = [
+            ctypes.c_int64, ctypes.c_int32,  # n, n_pieces
+            ctypes.c_void_p, ctypes.c_void_p,  # kind, data [n_pieces]
+            ctypes.c_void_p, ctypes.c_void_p,  # aux, width [n_pieces]
+            ctypes.c_int64, ctypes.c_void_p,  # n_over, over_rows
+            ctypes.c_char_p, ctypes.c_void_p,  # over_data, over_off
+            ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,  # out,cap,rows
         ]
         _lib = lib
     except Exception as e:  # pragma: no cover - depends on toolchain
@@ -347,6 +370,116 @@ def scan_copy_chunk(chunk: bytes, n_cols: int):
             break
     return (status, res[0], res[1], offsets, lengths, nulls,
             fallback[:res[2]].copy())
+
+
+def int_text_fixed(arr: np.ndarray):
+    """One C pass over a column of integers: (buf uint8[n, 21], lens
+    int64[n]) — row r the digits of arr[r] as `str(int)` writes them,
+    left-aligned and zero-padded, and how many there are. None where the
+    library is not loaded or the dtype is not one of int16 / int32 /
+    uint32 / int64 in native byte order: the caller then runs its numpy
+    twin, which gives the same.
+
+    Runs on the event loop (a destination renders a write before its
+    request goes out), so it never builds the library: the destination
+    has loaded it off the loop (`native_available()`, at `startup`)."""
+    lib = _lib
+    kind = _INT_TEXT_KINDS.get(arr.dtype)
+    if lib is None or kind is None or arr.ndim != 1:
+        return None
+    vals = np.ascontiguousarray(arr)
+    n = vals.shape[0]
+    buf = np.empty((n, INT_TEXT_WIDTH), dtype=np.uint8)
+    lens = np.empty(n, dtype=np.int64)
+    lib.etl_int_text_fixed(_ptr(vals), n, kind, _ptr(buf), _ptr(lens))
+    return buf, lens
+
+
+def assemble_rows(n: int, pieces: list, override: "dict | None"):
+    """One C pass over the rows of a columnar write's body: the pieces of
+    ops/egress.py's protocol (`const`, `fixed`, `var`) copied row by row
+    into one buffer, an overridden row replaced whole. Returns (out
+    uint8[total], row_offsets int64[n + 1]), or None where the library is
+    not loaded: the caller then runs its numpy twin, which gives the same.
+
+    What C wants is made here, not by the callers: a `fixed` buffer that
+    is a view — a column range of a wider buffer, as device egress hands
+    over — is copied to contiguous rows; lengths and offsets of another
+    dtype (int32 from the device or from Arrow) or layout are widened to
+    contiguous int64. The output is sized exactly, from a sum per piece,
+    so nothing is trimmed or copied afterwards; a table the pass refuses
+    (a length over its width, offsets outside the values: the sums were
+    of something else) raises ValueError, where the numpy twin would read
+    a neighbour's bytes.
+
+    Runs on the event loop, so it never builds the library: see
+    `int_text_fixed`."""
+    lib = _lib
+    if lib is None:
+        return None
+    m = len(pieces)
+    kinds = (ctypes.c_int32 * m)()
+    data = (ctypes.c_void_p * m)()
+    aux = (ctypes.c_void_p * m)()
+    width = (ctypes.c_int64 * m)()
+    keys = sorted(override) if override else []
+    n_over = len(keys)
+    if n_over and (keys[0] < 0 or keys[-1] >= n):
+        raise IndexError(f"override row outside 0..{n - 1}")
+    rows = np.array(keys, dtype=np.int64)
+    over_data = b"".join([override[r] for r in keys])
+    over_off = np.zeros(n_over + 1, dtype=np.int64)
+    np.cumsum(np.fromiter((len(override[r]) for r in keys), np.int64,
+                          n_over), out=over_off[1:])
+    total = len(over_data)
+    keep = []  # what the pointers point into, alive until the call returns
+    for j, p in enumerate(pieces):
+        kinds[j] = _PIECE_KINDS[p[0]]
+        if p[0] == "const":
+            vals = np.ascontiguousarray(p[1], dtype=np.uint8)
+            width[j] = vals.size
+            total += vals.size * (n - n_over)
+        elif p[0] == "fixed":
+            vals = p[1]
+            if vals.dtype != np.uint8 or vals.ndim != 2 \
+                    or vals.shape[0] < n:
+                raise ValueError(f"fixed piece {j}: {vals.dtype}"
+                                 f"{vals.shape} for {n} rows")
+            vals = np.ascontiguousarray(vals[:n])
+            lens = np.ascontiguousarray(p[2], dtype=np.int64)
+            if lens.shape != (n,):
+                raise ValueError(f"fixed piece {j}: {lens.shape} lengths "
+                                 f"for {n} rows")
+            width[j] = vals.shape[1]
+            total += int(lens.sum())
+            if n_over:
+                total -= int(lens[rows].sum())
+            aux[j] = lens.ctypes.data
+            keep.append(lens)
+        else:
+            vals = np.ascontiguousarray(p[1], dtype=np.uint8)
+            offs = np.ascontiguousarray(p[2], dtype=np.int64)
+            if offs.shape != (n + 1,):
+                raise ValueError(f"var piece {j}: {offs.shape} offsets "
+                                 f"for {n} rows")
+            width[j] = vals.size
+            total += int(offs[n] - offs[0])
+            if n_over:
+                total -= int((offs[rows + 1] - offs[rows]).sum())
+            aux[j] = offs.ctypes.data
+            keep.append(offs)
+        data[j] = vals.ctypes.data
+        keep.append(vals)
+    # a total under 0 (lengths that lie) is numpy's ValueError here
+    out = np.empty(total, dtype=np.uint8)
+    starts = np.empty(n + 1, dtype=np.int64)
+    wrote = lib.etl_assemble_rows(
+        n, m, kinds, data, aux, width, n_over, _ptr(rows), over_data,
+        _ptr(over_off), _ptr(out), total, _ptr(starts))
+    if wrote != total:
+        raise ValueError("assemble_rows: a piece's lengths or offsets do "
+                         "not describe its bytes")
+    return out, starts
 
 
 def pack_bmat(data, offsets, lengths, col_idx, widths, bmat, lens_out) -> bool:

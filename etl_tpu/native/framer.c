@@ -495,3 +495,130 @@ int32_t etl_stage_copy_chunk(const uint8_t *buf, int64_t buf_len,
     if (n_delims != n_rows * (int64_t)n_cols) return COPY_STAGE_COUNT;
     return ragged ? COPY_STAGE_RAGGED : COPY_STAGE_OK;
 }
+
+/* Render a column of integers as decimal text, one value a row
+ * (ops/egress.py `int_text_fixed`): row r of `buf` (n rows of
+ * INT_TEXT_WIDTH bytes, the caller's) gets the digits of vals[r] as
+ * `str(int)` writes them — a '-' first where it is negative, no leading
+ * zeros, "0" for zero — left-aligned and zero-padded, and lens[r] how
+ * many bytes that is. 21 bytes hold every int64 (INT64_MIN is 20) and
+ * are the width the numpy twin's `astype("U21")` gives.
+ *
+ * `kind` says what `vals` points at; another value writes nothing and
+ * returns -1. Numpy twin: ops/egress.py `_int_text_fixed_np`. */
+#define INT_TEXT_WIDTH 21
+#define INT_TEXT_I16 0
+#define INT_TEXT_I32 1
+#define INT_TEXT_U32 2
+#define INT_TEXT_I64 3
+
+int32_t etl_int_text_fixed(const void *vals, int64_t n, int32_t kind,
+                           uint8_t *buf, int64_t *lens) {
+    if (kind < INT_TEXT_I16 || kind > INT_TEXT_I64) return -1;
+    for (int64_t r = 0; r < n; r++) {
+        int64_t v;
+        switch (kind) {
+        case INT_TEXT_I16: v = ((const int16_t *)vals)[r]; break;
+        case INT_TEXT_I32: v = ((const int32_t *)vals)[r]; break;
+        case INT_TEXT_U32: v = ((const uint32_t *)vals)[r]; break;
+        default: v = ((const int64_t *)vals)[r]; break;
+        }
+        /* the magnitude in unsigned arithmetic: -INT64_MIN does not fit */
+        uint64_t mag = v < 0 ? (uint64_t)0 - (uint64_t)v : (uint64_t)v;
+        uint8_t digits[20];
+        int32_t nd = 0;
+        do {
+            digits[nd++] = (uint8_t)('0' + mag % 10);
+            mag /= 10;
+        } while (mag);
+        uint8_t *row = buf + r * INT_TEXT_WIDTH;
+        int32_t len = 0;
+        if (v < 0) row[len++] = '-';
+        while (nd) row[len++] = digits[--nd];
+        lens[r] = len;
+        memset(row + len, 0, (size_t)(INT_TEXT_WIDTH - len));
+    }
+    return 0;
+}
+
+/* Assemble the body of a columnar write — one line a row — in one pass
+ * over the rows (ops/egress.py `assemble_rows`; a ClickHouse TSV insert
+ * is 12 pieces a row, a Snowflake NDJSON line some 4 a column).
+ *
+ * A row is the bytes of `n_pieces` pieces, in order. Piece j is
+ *
+ *   PIECE_CONST  data[j]: width[j] bytes, the same for every row
+ *   PIECE_FIXED  data[j]: n rows of width[j] bytes, left-aligned, of
+ *                which row r gives its first aux[j][r]
+ *   PIECE_VAR    data[j]: width[j] bytes, of which row r gives
+ *                [aux[j][r], aux[j][r + 1])  (aux[j]: n + 1 offsets)
+ *
+ * An overridden row — `over_rows`, ascending, `n_over` of them — takes
+ * no byte of any piece and instead over_data[over_off[i], over_off[i+1])
+ * verbatim. The bytes go to `out` back to back and row r starts at
+ * row_offsets[r]; row_offsets[n] is the total, which is also returned.
+ *
+ * The caller sizes `out` exactly, from the pieces' lengths. A length
+ * under 0 or over its piece's width, offsets that fall or leave the
+ * values, override rows out of order or range, or a byte past `out_cap`
+ * stop the pass and return -1: no read outside a piece as described, no
+ * write past out_cap bytes or n + 1 offsets. Numpy twin: ops/egress.py
+ * `_assemble_rows_np`. */
+#define PIECE_CONST 0
+#define PIECE_FIXED 1
+#define PIECE_VAR 2
+
+int64_t etl_assemble_rows(int64_t n, int32_t n_pieces, const int32_t *kind,
+                          const uint8_t *const *data,
+                          const int64_t *const *aux, const int64_t *width,
+                          int64_t n_over, const int64_t *over_rows,
+                          const uint8_t *over_data, const int64_t *over_off,
+                          uint8_t *out, int64_t out_cap,
+                          int64_t *row_offsets) {
+    int64_t pos = 0, oi = 0;
+    for (int32_t j = 0; j < n_pieces; j++) {
+        if (kind[j] < PIECE_CONST || kind[j] > PIECE_VAR || width[j] < 0)
+            return -1;
+    }
+    for (int64_t r = 0; r < n; r++) {
+        row_offsets[r] = pos;
+        if (oi < n_over && over_rows[oi] <= r) {
+            if (over_rows[oi] != r) return -1;  /* below r: not ascending */
+            int64_t a = over_off[oi], len = over_off[oi + 1] - a;
+            if (a < 0 || len < 0 || len > out_cap - pos) return -1;
+            if (len) memcpy(out + pos, over_data + a, (size_t)len);
+            pos += len;
+            oi++;
+            continue;
+        }
+        for (int32_t j = 0; j < n_pieces; j++) {
+            const uint8_t *src;
+            int64_t len;
+            switch (kind[j]) {
+            case PIECE_CONST:
+                src = data[j];
+                len = width[j];
+                break;
+            case PIECE_FIXED:
+                src = data[j] + r * width[j];
+                len = aux[j][r];
+                if (len < 0 || len > width[j]) return -1;
+                break;
+            default: {
+                int64_t a = aux[j][r];
+                len = aux[j][r + 1] - a;
+                if (a < 0 || len < 0 || a + len > width[j]) return -1;
+                src = data[j] + a;
+                break;
+            }
+            }
+            if (len > out_cap - pos) return -1;
+            if (len == 1) out[pos] = src[0];  /* the separators */
+            else if (len) memcpy(out + pos, src, (size_t)len);
+            pos += len;
+        }
+    }
+    if (oi != n_over) return -1;  /* a row at or past n */
+    row_offsets[n] = pos;
+    return pos;
+}

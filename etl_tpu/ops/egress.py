@@ -46,7 +46,11 @@ import threading
 
 import numpy as np
 
+from .. import native
 from ..models.pgtypes import CellKind
+from ..telemetry.metrics import (ETL_EGRESS_ASSEMBLED_ROWS_TOTAL,
+                                 ETL_EGRESS_NATIVE_ASSEMBLED_ROWS_TOTAL,
+                                 registry)
 
 log = logging.getLogger("etl_tpu.ops")
 
@@ -638,9 +642,11 @@ def materialize(egress_out: tuple, plan, dense, n: int,
 #       | ("var",   values uint8[total], offsets int64[n+1])
 #
 # A destination builds one piece per wire token (field text, separator,
-# JSON key, metadata column) and `assemble_rows` scatters them into one
-# contiguous buffer with two cumsums and one fancy-index store per piece
-# — no per-row Python.
+# JSON key, metadata column) and `assemble_rows` copies them into one
+# contiguous buffer — one C pass over the rows where the native library
+# is loaded (native/framer.c `etl_assemble_rows`), and where it is not
+# two cumsums and one fancy-index store per piece; no per-row Python
+# either way.
 
 def const_piece(b: bytes) -> tuple:
     return ("const", np.frombuffer(b, dtype=np.uint8))
@@ -678,8 +684,18 @@ def patch_rows_fixed(buf: np.ndarray, lens: np.ndarray, rows: np.ndarray,
 
 
 def int_text_fixed(arr: np.ndarray) -> tuple:
-    """Host twin of the device int renderers: same digits as str(int)."""
+    """Host twin of the device int renderers: same digits as str(int).
+    One C pass where the native library is loaded (`native.
+    int_text_fixed`), numpy where it is not or the dtype is not the C
+    pass's: the same (uint8[n, 21] zero-padded, int64[n]) either way."""
     a = np.asarray(arr)
+    done = native.int_text_fixed(a)
+    return done if done is not None else _int_text_fixed_np(a)
+
+
+def _int_text_fixed_np(a: np.ndarray) -> tuple:
+    """`native.int_text_fixed` in numpy, for a process without the native
+    library."""
     n = a.shape[0]
     if n == 0:
         return np.zeros((0, 21), dtype=np.uint8), np.zeros(0, np.int64)
@@ -726,11 +742,26 @@ def timestamp_text_fixed(micros: np.ndarray) -> tuple:
 
 def assemble_rows(n: int, pieces: list,
                   override: "dict | None" = None) -> tuple:
-    """Scatter `pieces` into one contiguous byte buffer, one row per
+    """Copy `pieces` into one contiguous byte buffer, one row per
     line. `override` maps row index → full replacement bytes for that
     row (the oracle-rendered untrusted/special rows) — overridden rows
     take NO bytes from any piece. Returns (out uint8[total],
-    row_offsets int64[n+1])."""
+    row_offsets int64[n+1]). One C pass over the rows where the native
+    library is loaded (`native.assemble_rows`), numpy where it is not:
+    the same two arrays either way."""
+    done = native.assemble_rows(n, pieces, override)
+    registry.counter_inc(ETL_EGRESS_ASSEMBLED_ROWS_TOTAL, n)
+    if done is not None:
+        registry.counter_inc(ETL_EGRESS_NATIVE_ASSEMBLED_ROWS_TOTAL, n)
+        return done
+    return _assemble_rows_np(n, pieces, override)
+
+
+def _assemble_rows_np(n: int, pieces: list,
+                      override: "dict | None" = None) -> tuple:
+    """`native.assemble_rows` in numpy, for a process without the native
+    library: per piece some ten passes over int64 index arrays, one entry
+    per output byte, where the C pass copies each row's bytes once."""
     m = len(pieces)
     L = np.zeros((n, m), dtype=np.int64)
     for j, p in enumerate(pieces):

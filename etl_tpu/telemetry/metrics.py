@@ -183,6 +183,13 @@ ETL_EGRESS_DEVICE_BATCHES_TOTAL = "etl_egress_device_batches_total"
 # at zero
 ETL_EGRESS_DEVICE_FAILURES_TOTAL = "etl_egress_device_failures_total"
 ETL_EGRESS_WRITES_TOTAL = "etl_egress_writes_total"
+# rows whose wire line `ops/egress.assemble_rows` built (one increment a
+# call, none a row), and those of them the one C pass built
+# (native/framer.c `etl_assemble_rows`): the ratio is 1 wherever the
+# native library loaded and 0 in a process without a C compiler
+ETL_EGRESS_ASSEMBLED_ROWS_TOTAL = "etl_egress_assembled_rows_total"
+ETL_EGRESS_NATIVE_ASSEMBLED_ROWS_TOTAL = \
+    "etl_egress_native_assembled_rows_total"
 # program store (ops/program_store.py): cache hits by layer (memory =
 # the in-process _SHARED_FN_CACHE, disk = a deserialized AOT
 # executable), misses by reason (absent = never compiled on this

@@ -1,4 +1,5 @@
-"""Fuzz targets for the codec parsers + native framer and COPY scans.
+"""Fuzz targets for the codec parsers + native framer, COPY scans and
+line assembly.
 
 Reference parity: cargo-fuzz targets `parse_copy_row`, `parse_text_cell`,
 `numeric_text_roundtrip`, `parse_bytea_hex_string`
@@ -502,6 +503,80 @@ def fuzz_stage_copy_chunk(rng: random.Random, _ignored=None) -> None:
     check_stage_copy_chunk(bytes(chunk), n_cols)
 
 
+def fuzz_assemble_rows(rng: random.Random, _ignored=None) -> None:
+    """Random piece tables — every kind of piece, zero-length fields,
+    int32 and int64 lengths and offsets, views that are strided or
+    read-only, overridden rows — through `ops/egress.assemble_rows`, and
+    random integers through `int_text_fixed`: the C pass (native/framer.c
+    `etl_assemble_rows`, `etl_int_text_fixed`) against the numpy bodies,
+    array for array. A process without the native library runs the numpy
+    bodies alone."""
+    import numpy as np
+
+    from .. import native
+    from ..ops import egress as eg
+
+    native.native_available()  # what a destination does at start-up
+    nrng = np.random.default_rng(rng.getrandbits(32))
+    n = rng.choice((0, 1, 2, 7, 64, 500)) if rng.random() < 0.9 \
+        else rng.randint(0, 3000)
+
+    def lens_of(hi):
+        lens = nrng.integers(0, hi + 1, n)
+        if rng.random() < 0.5:
+            lens[nrng.random(n) < 0.3] = 0
+        return lens.astype(rng.choice((np.int32, np.int64)))
+
+    def piece():
+        c = rng.random()
+        if c < 0.35:
+            return eg.const_piece(rng.randbytes(rng.choice((0, 1, 1, 2, 9))))
+        if c < 0.7:
+            w = rng.choice((1, 5, 21, 50))
+            wide = nrng.integers(0, 256, (n + 2, w + 6), dtype=np.uint8)
+            buf = rng.choice((
+                lambda: wide[:n, :w].copy(), lambda: wide[:n, 3:3 + w],
+                lambda: wide[1:n + 1, :w], lambda: wide[:n, :w][::-1],
+                lambda: np.asfortranarray(wide[:n, :w])))()
+            if rng.random() < 0.2:
+                buf.flags.writeable = False
+            return eg.fixed_piece(buf, lens_of(w))
+        lens = lens_of(rng.choice((0, 3, 84)))
+        first = rng.choice((0, 0, 5))
+        offs = np.zeros(n + 1, dtype=lens.dtype)
+        np.cumsum(lens, out=offs[1:])
+        values = nrng.integers(0, 256, first + int(offs[-1]) + 2,
+                               dtype=np.uint8)
+        return ("var", values, offs + first)
+
+    pieces = [piece() for _ in range(rng.randint(0, 9))]
+    override = None
+    if n and rng.random() < 0.4:
+        rows = range(n) if rng.random() < 0.1 else \
+            rng.sample(range(n), rng.randint(1, min(n, 6)))
+        override = {r: rng.randbytes(rng.choice((0, 1, 30))) for r in rows}
+    want = eg._assemble_rows_np(n, pieces, override)
+    got = native.assemble_rows(n, pieces, override)
+    for g, w in zip(got or want, want):
+        assert g.dtype == w.dtype and np.array_equal(g, w), \
+            "C assembly != numpy twin"
+
+    dtype = rng.choice((np.int16, np.int32, np.uint32, np.int64))
+    info = np.iinfo(dtype)
+    vals = nrng.integers(info.min, info.max, n, dtype=dtype, endpoint=True)
+    if n and rng.random() < 0.5:  # short values, and the powers of ten
+        vals[: n // 2] = (vals[: n // 2] % 200).astype(dtype) - 100 \
+            if info.min < 0 else vals[: n // 2] % 200
+        vals[0] = info.max - info.max % 10 ** rng.randint(0, 4)
+    want = eg._int_text_fixed_np(vals)
+    got = native.int_text_fixed(vals)
+    for g, w in zip(got or want, want):
+        assert g.dtype == w.dtype and g.shape == w.shape \
+            and np.array_equal(g, w), "C int text != numpy twin"
+    for i in range(min(n, 4)):
+        assert want[0][i, :want[1][i]].tobytes() == str(vals[i]).encode()
+
+
 _AVRO_FUZZ_DIR: str | None = None  # one temp dir per process, not per case
 
 
@@ -696,6 +771,7 @@ TARGETS = {
     "framer": fuzz_framer,
     "copy_stream": fuzz_copy_stream,
     "stage_copy_chunk": fuzz_stage_copy_chunk,
+    "assemble_rows": fuzz_assemble_rows,
     "avro_ocf": fuzz_avro_ocf,
     "pb_append_rows": fuzz_pb_append_rows,
     "snowpipe_batches": fuzz_snowpipe_batches,

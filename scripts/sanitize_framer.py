@@ -11,14 +11,18 @@ hammer it with
   1. the structured-mutation framer fuzzer (testing/fuzz.py `framer`
      target — valid pgoutput streams + byte mutations + truncations),
      the COPY stream fuzzer (`copy_stream` target — random message sizes,
-     tags, corrupt lengths and block cuts through etl_scan_copy_data) and
+     tags, corrupt lengths and block cuts through etl_scan_copy_data),
      the COPY chunk fuzzer (`stage_copy_chunk` target — rows of NULLs,
      escapes and near-miss bytes, mutated and cut, through
-     etl_stage_copy_chunk),
+     etl_stage_copy_chunk) and the line-assembly fuzzer (`assemble_rows`
+     target — random piece tables, views and overrides through
+     etl_assemble_rows, random integers through etl_int_text_fixed),
   2. the full differential test file (tests/test_native_framer.py), which
      also exercises etl_pack_bmat / etl_gather_string / nibble packing, and
-  3. a direct hammer of the pack/gather entry points and of
-     etl_stage_copy_chunk with outputs smaller than its rows.
+  3. a direct hammer of the pack/gather entry points, of
+     etl_stage_copy_chunk with outputs smaller than its rows, and of
+     etl_assemble_rows with tables that lie about their bytes and outputs
+     too small for them.
 
 Exit 0 = no sanitizer findings. Run:  python scripts/sanitize_framer.py
 [--seconds N] [--seed N]. CI-sized invocation lives in
@@ -113,8 +117,9 @@ def main(argv=None) -> int:
         return rc or 1
 
     # 2. structured-mutation fuzz under ASan/UBSan: the framer, then the
-    # CopyData block scan and the COPY chunk scan
-    for target in ("framer", "copy_stream", "stage_copy_chunk"):
+    # CopyData block scan, the COPY chunk scan and the line assembly
+    targets = ("framer", "copy_stream", "stage_copy_chunk", "assemble_rows")
+    for target in targets:
         fuzz_args = ["-m", "etl_tpu.testing.fuzz", "--target", target,
                      "--seconds", str(args.seconds)]
         if args.seed is not None:
@@ -137,8 +142,9 @@ def main(argv=None) -> int:
         return rc
 
     # 4. direct hammer of the pack/gather entry points (numpy-only):
-    # adversarial widths, truncated fields, and buffer-edge offsets; and
-    # of the COPY chunk scan with too few output rows
+    # adversarial widths, truncated fields, and buffer-edge offsets; of
+    # the COPY chunk scan with too few output rows; and of the line
+    # assembly with lengths, offsets and override rows that lie
     hammer_args = ["scripts/sanitize_framer.py", "--hammer",
                    "--seconds", str(args.seconds)]
     if args.seed is not None:
@@ -149,8 +155,9 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return rc
     print("sanitize_framer: no findings "
-          f"(fuzz 3 x {args.seconds:.0f}s + framer differentials + "
-          f"pack/gather/stage hammer under ASan+UBSan)")
+          f"(fuzz {len(targets)} x {args.seconds:.0f}s + framer "
+          f"differentials + pack/gather/stage/assemble hammer under "
+          f"ASan+UBSan)")
     return 0
 
 
@@ -159,7 +166,11 @@ def hammer(seconds: float, seed: int | None) -> int:
     calls over fuzz-framed batches, including adversarial gather widths and
     fields ending at the exact buffer boundary; then etl_stage_copy_chunk
     called past its binding, with outputs of fewer rows than the chunk has
-    and chunks that do not end in a newline."""
+    and chunks that do not end in a newline; then etl_assemble_rows past
+    its binding, with lengths over their width or under 0, offsets that
+    fall or leave the values, override rows out of order or range and an
+    output smaller than the rows: it has to stop, not read or write
+    outside what it was given."""
     import ctypes
     import random
     import time
@@ -239,9 +250,93 @@ def hammer(seconds: float, seed: int | None) -> int:
                 max_rows, p(np.empty(shape, np.int32)),
                 p(np.empty(shape, np.int32)), p(np.empty(shape, np.bool_)),
                 p(np.empty(max_rows, np.int64)), res)
+        hammer_assemble(native._lib, rng)
         cases += 1
     print(f"hammer: {cases} cases OK")
     return 0
+
+
+def hammer_assemble(lib, rng) -> None:
+    """One adversarial call of etl_assemble_rows and one of
+    etl_int_text_fixed. Every buffer is exactly as large as the table says
+    it is, so the sanitizer's red zone starts where a lie would reach."""
+    import ctypes
+
+    import numpy as np
+
+    import etl_tpu.native as native
+
+    n = rng.randint(0, 12)
+    m = rng.randint(0, 6)
+    lie = rng.random() < 0.7
+
+    def maybe_lie(a, lo, hi):
+        if lie and a.size and rng.random() < 0.5:
+            a[rng.randrange(a.size)] = rng.choice(
+                (lo - 1, hi + 1, -(1 << 40), 1 << 40, -1, hi))
+        return a
+
+    kinds = (ctypes.c_int32 * m)()
+    data = (ctypes.c_void_p * m)()
+    aux = (ctypes.c_void_p * m)()
+    width = (ctypes.c_int64 * m)()
+    keep, total = [], 0
+    for j in range(m):
+        kinds[j] = rng.choice((-1, 3, 77)) if lie and rng.random() < 0.05 \
+            else rng.choice((0, 1, 2))
+        w = rng.choice((0, 1, 4, 21))
+        if kinds[j] == 1:
+            vals = np.full((n, w), 65, dtype=np.uint8)
+            lens = maybe_lie(np.array(
+                [rng.randint(0, w) for _ in range(n)], dtype=np.int64), 0, w)
+            width[j], aux[j] = w, lens.ctypes.data
+            total += int(np.clip(lens, 0, w).sum())
+            keep.append(lens)
+        elif kinds[j] == 2:
+            lens = [rng.randint(0, w) for _ in range(n)]
+            offs = np.zeros(n + 1, dtype=np.int64)
+            np.cumsum(lens, out=offs[1:])
+            vals = np.full(int(offs[-1]), 66, dtype=np.uint8)
+            total += vals.size
+            width[j], aux[j] = vals.size, maybe_lie(
+                offs, 0, vals.size).ctypes.data
+            keep.append(offs)
+        else:
+            vals = np.full(w, 67, dtype=np.uint8)
+            width[j] = w if not (lie and rng.random() < 0.1) else -w - 1
+            total += w * n
+        data[j] = vals.ctypes.data
+        keep.append(vals)
+    n_over = rng.randint(0, min(n, 3))
+    rows = np.array(sorted(rng.sample(range(n), n_over)), dtype=np.int64)
+    if lie and n_over and rng.random() < 0.5:
+        rows[rng.randrange(n_over)] = rng.choice((-1, n, n + 5, 0))
+    texts = [b"r" * rng.randint(0, 5) for _ in range(n_over)]
+    over_off = np.zeros(n_over + 1, dtype=np.int64)
+    np.cumsum([len(t) for t in texts], out=over_off[1:])
+    total += int(over_off[-1])
+    over_off = maybe_lie(over_off, 0, int(over_off[-1]))
+    if lie and rng.random() < 0.5:
+        total = rng.randint(0, total)  # an output the rows do not fit
+    out = np.empty(total, dtype=np.uint8)
+    starts = np.empty(n + 1, dtype=np.int64)
+    p = native._ptr
+    wrote = lib.etl_assemble_rows(
+        n, m, kinds, data, aux, width, n_over, p(rows),
+        b"".join(texts), p(over_off), p(out), total, p(starts))
+    assert -1 <= wrote <= total, (wrote, total)
+    if not lie:
+        assert wrote >= 0 and starts[n] == wrote
+
+    kind = rng.choice((0, 1, 2, 3, 3, -1, 4))
+    itemsize = {0: 2, 1: 4, 2: 4}.get(kind, 8)
+    vals = np.frombuffer(rng.randbytes(n * itemsize), dtype=np.uint8)
+    buf = np.empty((n, 21), dtype=np.uint8)
+    lens = np.empty(n, dtype=np.int64)
+    rc = lib.etl_int_text_fixed(p(vals), n, kind, p(buf), p(lens))
+    assert rc == (0 if 0 <= kind <= 3 else -1)
+    if rc == 0 and n:
+        assert 1 <= lens.min() and lens.max() <= 20
 
 
 if __name__ == "__main__":

@@ -39,9 +39,10 @@ from etl_tpu.destinations.util import (change_type_batch,
                                        sequence_number_batch,
                                        sequence_number_buffer,
                                        string_array_from_fixed)
+from etl_tpu import native
 from etl_tpu.models import (ColumnSchema, ColumnarBatch, Oid,
                             ReplicatedTableSchema, TableName, TableSchema)
-from etl_tpu.models.cell import JSON_NULL, PgNumeric
+from etl_tpu.models.cell import JSON_NULL, TOAST_UNCHANGED, PgNumeric
 from etl_tpu.models.event import ChangeType, DecodedBatchEvent
 from etl_tpu.models.lsn import Lsn
 from etl_tpu.models.table_row import CellKind, TableRow
@@ -102,6 +103,67 @@ def _specials_rows(n=8):
     vals[8] = dt.datetime.max        # TIMESTAMP at the sentinel edge
     rows[5] = TableRow(vals)
     return rows
+
+
+def _toast_rows(n=16):
+    """Rows of an UPDATE that left its TOASTed columns alone: the string
+    and the JSON cell of every third row are unchanged-TOAST, which the
+    wire renders as NULL."""
+    rows = _kinds_rows(n)
+    for i in range(0, n, 3):
+        vals = list(rows[i].values)
+        vals[10] = vals[11] = TOAST_UNCHANGED
+        rows[i] = TableRow(vals)
+    return rows
+
+
+def _accounts_schema(tid=43010):
+    return _schema((
+        ColumnSchema("aid", Oid.INT4, nullable=False, primary_key_ordinal=1),
+        ColumnSchema("bid", Oid.INT4),
+        ColumnSchema("abalance", Oid.INT4),
+        ColumnSchema("filler", Oid.BPCHAR, modifier=88)),
+        tid=tid, name="pgbench_accounts")
+
+
+def _accounts_values(n=500):
+    """`pgbench_accounts` rows as the benchmark's CDC cells send them."""
+    return [[str(i + 1).encode(), str(i % 10 + 1).encode(),
+             str((i * 7919) % 2_000_000_000 - 10**9).encode(), b" " * 84]
+            for i in range(n)]
+
+
+def _untrusted(dev, rows):
+    """`dev` with `rows` marked as the decode's fallback rows are: their
+    device bytes may hold anything and must not reach the wire."""
+    fields = {}
+    for j, (buf, lens) in dev.fields.items():
+        buf = np.array(buf, copy=True)
+        buf[rows] = ord("?")
+        fields[j] = (buf, lens)
+    return eg.DeviceEgress(dev.encoder, dev.n_rows, fields,
+                           np.asarray(rows, dtype=np.int64))
+
+
+@pytest.fixture(params=["native", "numpy"])
+def assembly(request, monkeypatch):
+    """Both branches of `ops/egress.assemble_rows` / `int_text_fixed`: the
+    C pass, and the numpy bodies of a process whose native library did
+    not build (the framer and the pack then take their fallbacks too)."""
+    if request.param == "native":
+        if not native.native_available():
+            pytest.skip(f"no native library: {native._build_error}")
+    else:
+        monkeypatch.setattr(native, "_lib", None)
+        monkeypatch.setattr(native, "_build_error", "test: no compiler")
+    return request.param
+
+
+def _native_rows():
+    from etl_tpu.telemetry.metrics import (
+        ETL_EGRESS_NATIVE_ASSEMBLED_ROWS_TOTAL, registry)
+
+    return registry.get_counter(ETL_EGRESS_NATIVE_ASSEMBLED_ROWS_TOTAL)
 
 
 def _decoded_event(schema, batch, start=0):
@@ -303,6 +365,7 @@ class TestDeviceVsHostTwins:
 # ---------------------------------------------------------------------------
 
 
+@pytest.mark.usefixtures("assembly")
 class TestClickHouseTsvIdentity:
     def _seqs(self, n):
         lsns = np.arange(n, dtype=np.uint64) + 0x2000
@@ -313,17 +376,48 @@ class TestClickHouseTsvIdentity:
             lsns, zeros, ords)]
         return seq_buf, seq_strs
 
-    @pytest.mark.parametrize("rows_fn", [_kinds_rows, _specials_rows])
-    def test_copy_shape_identity(self, rows_fn):
+    @pytest.mark.parametrize("rows_fn", [_kinds_rows, _specials_rows,
+                                         _toast_rows])
+    def test_copy_shape_identity(self, rows_fn, assembly):
         schema = _kinds_schema()
         batch = ColumnarBatch.from_rows(schema, rows_fn())
         n = batch.num_rows
         seq_buf, seq_strs = self._seqs(n)
         oracle = render_batch_tsv_columnar(schema, batch, "UPSERT",
                                            seq_strs)
+        before = _native_rows()
         fast, used = render_batch_tsv_fast(schema, batch, "UPSERT",
                                            seq_buf)
         assert used is False  # host twins, no device buffers attached
+        assert fast == oracle
+        # the branch the parameter names is the branch that ran
+        assert (_native_rows() > before) == (assembly == "native")
+
+    @pytest.mark.parametrize("n", [1, 500, 2_000])
+    def test_pgbench_accounts_identity(self, n):
+        """The benchmark's ClickHouse cell: three int4 columns and a
+        char(84), as a 500-row transaction and as coalesced writes."""
+        schema = _accounts_schema()
+        batch = _engine_batch(schema, _accounts_values(n))
+        cts = np.full(n, int(ChangeType.INSERT), dtype=np.int8)
+        ct_arr = change_type_batch(cts)
+        seq_buf, seq_strs = self._seqs(n)
+        oracle = render_batch_tsv_columnar(
+            schema, batch, [c.decode() for c in ct_arr.tolist()], seq_strs)
+        fast, used = render_batch_tsv_fast(schema, batch, ct_arr, seq_buf)
+        assert used is False and fast == oracle
+
+    def test_untrusted_rows_take_the_oracle_line(self):
+        schema = _int_schema()
+        batch = _engine_batch(schema, _int_values(64),
+                              egress=eg.ENCODER_TSV)
+        dev = _untrusted(batch.device_egress, [0, 7, 8, 63])
+        seq_buf, seq_strs = self._seqs(64)
+        oracle = render_batch_tsv_columnar(schema, batch, "UPSERT",
+                                           seq_strs)
+        fast, used = render_batch_tsv_fast(schema, batch, "UPSERT",
+                                           seq_buf, egress=dev)
+        assert used is True and b"?" not in fast
         assert fast == oracle
 
     def test_cdc_shape_identity(self):
@@ -357,6 +451,7 @@ class TestClickHouseTsvIdentity:
         assert fast == oracle
 
 
+@pytest.mark.usefixtures("assembly")
 class TestSnowflakeNdjsonIdentity:
     def _labels_seqs(self, n):
         labels = ["delete" if i % 4 == 3 else "insert" for i in range(n)]
@@ -365,15 +460,38 @@ class TestSnowflakeNdjsonIdentity:
             np.arange(n, dtype=np.uint64))
         return labels, list(seqs)
 
-    @pytest.mark.parametrize("rows_fn", [_kinds_rows, _specials_rows])
-    def test_host_twin_identity(self, rows_fn):
+    @pytest.mark.parametrize("rows_fn", [_kinds_rows, _specials_rows,
+                                         _toast_rows])
+    def test_host_twin_identity(self, rows_fn, assembly):
         schema = _kinds_schema()
         batch = ColumnarBatch.from_rows(schema, rows_fn())
         labels, seqs = self._labels_seqs(batch.num_rows)
         oracle = encode_batch_ndjson(schema, batch, labels, seqs)
+        before = _native_rows()
         fast, used = encode_batch_ndjson_fast(schema, batch, labels,
                                               seqs)
         assert used is False
+        assert fast == oracle
+        assert (_native_rows() > before) == (assembly == "native")
+
+    def test_pgbench_accounts_identity(self):
+        schema = _accounts_schema()
+        batch = _engine_batch(schema, _accounts_values(500))
+        labels, seqs = self._labels_seqs(500)
+        oracle = encode_batch_ndjson(schema, batch, labels, seqs)
+        fast, _ = encode_batch_ndjson_fast(schema, batch, labels, seqs)
+        assert fast == oracle
+
+    def test_untrusted_rows_take_the_oracle_line(self):
+        schema = _int_schema()
+        batch = _engine_batch(schema, _int_values(64),
+                              egress=eg.ENCODER_JSON)
+        dev = _untrusted(batch.device_egress, [0, 7, 8, 63])
+        labels, seqs = self._labels_seqs(64)
+        oracle = encode_batch_ndjson(schema, batch, labels, seqs)
+        fast, used = encode_batch_ndjson_fast(schema, batch, labels, seqs,
+                                              egress=dev)
+        assert used is True and not any(b"?" in line for line in fast)
         assert fast == oracle
 
     def test_device_egress_identity(self):
