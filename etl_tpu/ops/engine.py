@@ -672,6 +672,10 @@ class DeviceDecoder:
             for spec in self._dense[250:]:
                 self._object.append(spec)
             self._dense = self._dense[:250]
+        # column counts for the cell counters (telemetry/metrics.py),
+        # taken once here so the increments multiply two integers
+        self.n_columns = len(cols)
+        self.n_device_kind_columns = len(self._dense)
         # publication row filter: compiled ONCE here (etl-lint rule 13
         # flags compile_row_filter on @hot_loop paths — a per-batch
         # compile would re-bind literals and re-trace per flush). An
@@ -1595,6 +1599,7 @@ class DeviceDecoder:
                 f"staged batch has {staged.n_cols} cols, schema expects "
                 f"{len(cols)}")
         from ..telemetry.metrics import (
+            ETL_DECODE_DEVICE_PARSED_CELLS_TOTAL,
             ETL_DECODE_ROUTED_DEVICE_ROWS_TOTAL,
             ETL_DECODE_ROUTED_HOST_ROWS_TOTAL,
             ETL_DECODE_ROUTED_ORACLE_ROWS_TOTAL, registry)
@@ -1613,6 +1618,9 @@ class DeviceDecoder:
             if self._telemetry:
                 registry.counter_inc(ETL_DECODE_ROUTED_DEVICE_ROWS_TOTAL,
                                      staged.n_rows)
+                registry.counter_inc(
+                    ETL_DECODE_DEVICE_PARSED_CELLS_TOTAL,
+                    staged.n_rows * self.n_device_kind_columns)
             return "device", self._specs(staged, self._widths(staged))
         if self._dense and staged.n_rows >= self.host_min_rows:
             specs = self._host_specs()

@@ -33,7 +33,10 @@ from ..postgres.codec import event as event_codec
 from ..postgres.codec import pgoutput
 from ..telemetry import spans
 from ..telemetry.metrics import (ETL_ASSEMBLER_SEAL_SECONDS,
-                                 ETL_ASSEMBLER_SEALED_ROWS_TOTAL, registry)
+                                 ETL_ASSEMBLER_SEALED_ROWS_TOTAL,
+                                 ETL_ASSEMBLER_TABLE_SWITCH_SEALS_TOTAL,
+                                 ETL_DECODE_CELLS_TOTAL,
+                                 ETL_DECODE_DEVICE_KIND_CELLS_TOTAL, registry)
 
 
 @dataclass
@@ -165,6 +168,8 @@ class EventAssembler:
             self.filled_since_ns = spans.now_ns()
         if self._run is None or self._run.table_id != schema.id \
                 or self._run.schema is not schema:
+            if self._run is not None and self._run.payloads:
+                registry.counter_inc(ETL_ASSEMBLER_TABLE_SWITCH_SEALS_TOTAL)
             self._seal_run()
             self._run = _Run(table_id=schema.id, schema=schema)
         r = self._run
@@ -193,6 +198,8 @@ class EventAssembler:
             self.filled_since_ns = spans.now_ns()
         if self._run is None or self._run.table_id != schema.id \
                 or self._run.schema is not schema:
+            if self._run is not None and self._run.payloads:
+                registry.counter_inc(ETL_ASSEMBLER_TABLE_SWITCH_SEALS_TOTAL)
             self._seal_run()
             self._run = _Run(table_id=schema.id, schema=schema)
         r = self._run
@@ -294,10 +301,13 @@ class EventAssembler:
         # the batch_id minted here rides the staged batch through every
         # decode span of the run (telemetry/spans.py)
         batch_id = spans.next_batch_id()
-        registry.counter_inc(ETL_ASSEMBLER_SEALED_ROWS_TOTAL,
-                             len(r.payloads))
+        n = len(r.payloads)
+        registry.counter_inc(ETL_ASSEMBLER_SEALED_ROWS_TOTAL, n)
+        registry.counter_inc(ETL_DECODE_CELLS_TOTAL, n * decoder.n_columns)
+        registry.counter_inc(ETL_DECODE_DEVICE_KIND_CELLS_TOTAL,
+                             n * decoder.n_device_kind_columns)
         with spans.span("assemble.seal", ETL_ASSEMBLER_SEAL_SECONDS,
-                        batch_id=batch_id, rows=len(r.payloads)):
+                        batch_id=batch_id, rows=n):
             self._stage_and_submit(r, decoder, batch_id)
 
     def _stage_and_submit(self, r: _Run, decoder: DeviceDecoder,

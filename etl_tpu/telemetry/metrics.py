@@ -61,6 +61,16 @@ ETL_DECODE_FILTER_SELECTIVITY = "etl_decode_filter_selectivity"
 ETL_DECODE_ROUTED_DEVICE_ROWS_TOTAL = "etl_decode_routed_device_rows_total"
 ETL_DECODE_ROUTED_HOST_ROWS_TOTAL = "etl_decode_routed_host_rows_total"
 ETL_DECODE_ROUTED_ORACLE_ROWS_TOTAL = "etl_decode_routed_oracle_rows_total"
+# the same question by cell (a row x a replicated column): of the cells of
+# every sealed CDC run, how many are of a kind the device program parses
+# (ops/engine.DEVICE_KINDS; the rest — NUMERIC, text — are gathered as
+# bytes and parsed on the host whatever the route), and how many of those
+# were in runs routed to the device. Integer increments beside the
+# sealed-rows and routed-rows counters, from column counts taken once
+# where the DeviceDecoder is built: nothing per row
+ETL_DECODE_CELLS_TOTAL = "etl_decode_cells_total"
+ETL_DECODE_DEVICE_KIND_CELLS_TOTAL = "etl_decode_device_kind_cells_total"
+ETL_DECODE_DEVICE_PARSED_CELLS_TOTAL = "etl_decode_device_parsed_cells_total"
 ETL_PROCESSED_BYTES_TOTAL = "etl_processed_bytes_total"
 # decode pipeline stage timings (ops/pipeline.py): pack = host gather into
 # the staging arena, dispatch = jit call (device work starts), fetch =
@@ -297,6 +307,12 @@ ETL_INTAKE_SEGMENT_SECONDS = "etl_intake_segment_seconds"
 ETL_APPLY_FRAME_WALK_SECONDS = "etl_apply_frame_walk_seconds"
 ETL_ASSEMBLER_SEAL_SECONDS = "etl_assembler_seal_seconds"
 ETL_ASSEMBLER_SEALED_ROWS_TOTAL = "etl_assembler_sealed_rows_total"
+# seals forced because the next row belongs to another table (or to
+# another schema object of the same table): the rest of
+# etl_assembler_seal_seconds' count are seals at a flush, at a control
+# event or by size
+ETL_ASSEMBLER_TABLE_SWITCH_SEALS_TOTAL = \
+    "etl_assembler_table_switch_seals_total"
 ETL_APPLY_DISPATCH_BLOCKED_SECONDS_TOTAL = \
     "etl_apply_dispatch_blocked_seconds_total"
 ETL_APPLY_FLUSH_FILL_SECONDS = "etl_apply_flush_fill_seconds"
