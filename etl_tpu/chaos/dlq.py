@@ -132,8 +132,8 @@ def _check_union(report: InvariantReport, expected: dict,
                             f"delivered but never committed")
 
 
-def _check_common(run: DlqRun, *, gen, store, inner, leak_probe,
-                  dup_budget: int) -> None:
+async def _check_common(run: DlqRun, *, gen, store, inner, leak_probe,
+                        dup_budget: int) -> None:
     """Duplication, monotonic-LSN, and leak checks shared by both
     scenarios (the zero-loss half is the union check — quarantined
     tables deliberately under-deliver to the destination)."""
@@ -154,8 +154,14 @@ def _check_common(run: DlqRun, *, gen, store, inner, leak_probe,
             if b < a:
                 run.report.fail(f"monotonic-lsn: progress key {key!r} "
                                 f"regressed {a} -> {b}")
-    if _pipeline_thread_count() > leak_probe.pipeline_threads:
-        run.report.fail("no-leaks: decode-pipeline worker threads leaked")
+    try:
+        # decode workers exit asynchronously after close(): the grace
+        # every other scenario gives them (chaos/ack_window.py)
+        await _wait_until(
+            lambda: _pipeline_thread_count() <= leak_probe.pipeline_threads,
+            3.0, "no-leaks: decode-pipeline worker threads leaked")
+    except TimeoutError as e:
+        run.report.fail(str(e))
     from ..ops.staging import ARENA_POOL
 
     if ARENA_POOL.outstanding > leak_probe.arenas_outstanding:
@@ -307,8 +313,8 @@ async def run_dlq_poison(seed: int = 7, steps: int = 22,
                  reconstruct_final_view(inner, gen.table_ids),
                  _dlq_view(entries, gen.table_ids))
     _check_probe_bound(run)
-    _check_common(run, gen=gen, store=store, inner=inner,
-                  leak_probe=leak_probe, dup_budget=1)
+    await _check_common(run, gen=gen, store=store, inner=inner,
+                        leak_probe=leak_probe, dup_budget=1)
 
     # operator round trip: replay the DLQ through the destination seam
     # (the "fixed destination" is the unwrapped inner), lift the
@@ -455,9 +461,9 @@ async def run_dlq_bisection_crash(seed: int = 7, steps: int = 16,
     _check_probe_bound(run)
     # budget: the crash re-streams the in-flight window once — the
     # healthy complement of the interrupted isolation may deliver twice
-    _check_common(run, gen=gen, store=store, inner=inner,
-                  leak_probe=leak_probe,
-                  dup_budget=1 + len(run.restarts))
+    await _check_common(run, gen=gen, store=store, inner=inner,
+                        leak_probe=leak_probe,
+                        dup_budget=1 + len(run.restarts))
     run.duration_s = time.monotonic() - t_start
     return run
 

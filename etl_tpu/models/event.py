@@ -143,9 +143,10 @@ class SchemaChangeEvent:
 
 
 class DecodedBatchEvent:
-    """TPU-path event: a contiguous same-table run of changes decoded on
-    device into columnar form. `change_types[i]` and `tx_ordinals[i]` /
-    `commit_lsns[i]` give each row its identity in the WAL order.
+    """TPU-path event: one table's changes between two seals, in WAL
+    order, decoded on device into columnar form. `change_types[i]` and
+    `tx_ordinals[i]` / `commit_lsns[i]` give each row its identity in the
+    WAL order (other tables' rows may lie between two of them).
 
     `batch` / `old_batch` resolve lazily: the assembler hands the event an
     in-flight device decode (`pending`, an object with `.result()`), so the

@@ -6,12 +6,22 @@ event list for the destination write.
 
 - CPU engine: each message decodes immediately via the codec oracle
   (reference-architecture per-tuple path, codec/event.rs).
-- TPU engine: row-message payloads accumulate as raw bytes per contiguous
-  same-table run; at flush, each run is framed (native framer), staged and
-  decoded on device in one batch, emitted as `DecodedBatchEvent`s. Control
-  events (Begin/Commit/Relation/Truncate/SchemaChange) stay host-decoded
-  and act as run barriers — mirroring the reference's per-table batching
-  between barriers (bigquery/core.rs:956-978).
+- TPU engine: row-message payloads accumulate as raw bytes in one open run
+  per table — the open GROUP, tables in the order of their first row. The
+  group is sealed as a whole: each run is framed (native framer), staged
+  and decoded in one batch and emitted as one `DecodedBatchEvent`, run
+  after run, contiguous in the event list. It seals at a flush, at a
+  control event (Relation/Truncate/SchemaChange, and the CPU engine's
+  Begin/Commit, stay host-decoded barriers), when one run reaches
+  `seal_rows` and when the group reaches the byte seal — the reference's
+  per-table batching between barriers (bigquery/core.rs:956-978).
+
+Delivery order (docs/decode-pipeline.md): flushes leave in WAL order and
+each covers one contiguous stretch of WAL — a size-bounded flush cuts
+between groups, never inside one, so no row is delivered in a later flush
+than a row with higher `(commit_lsn, tx_ordinal)`. Inside a flush a
+table's rows are in WAL order; the tables of a group are not interleaved
+as the WAL interleaved them. Every row carries its own coordinates.
 """
 
 from __future__ import annotations
@@ -34,25 +44,26 @@ from ..postgres.codec import pgoutput
 from ..telemetry import spans
 from ..telemetry.metrics import (ETL_ASSEMBLER_SEAL_SECONDS,
                                  ETL_ASSEMBLER_SEALED_ROWS_TOTAL,
-                                 ETL_ASSEMBLER_TABLE_SWITCH_SEALS_TOTAL,
                                  ETL_DECODE_CELLS_TOTAL,
                                  ETL_DECODE_DEVICE_KIND_CELLS_TOTAL, registry)
 
 
 @dataclass
 class _Run:
-    """A contiguous run of row messages for one table."""
+    """One table's open rows, in WAL order: what one seal stages."""
 
     table_id: TableId
     schema: ReplicatedTableSchema
+    #: not the first run of its group — a flush may not cut before it
+    joined: bool = False
     payloads: list[bytes] = field(default_factory=list)
     start_lsns: list[int] = field(default_factory=list)
     commit_lsns: list[int] = field(default_factory=list)
     tx_ordinals: list[int] = field(default_factory=list)
-    nbytes: int = 0  # size-hint bytes (64/row + payload), the seal bound
+    nbytes: int = 0  # size-hint bytes (64/row + payload)
 
 
-#: seal an open run once it reaches this many rows. Two effects: decode
+#: seal the open group once a run reaches this many rows. Two effects: decode
 #: dispatch starts while the stream keeps flowing (the device/host XLA
 #: call overlaps further WAL intake instead of bunching at flush), and
 #: staged batches never exceed the 16384-row bucket — so the decode
@@ -84,14 +95,15 @@ class EventAssembler:
         # bound into every DeviceDecoder this loop creates so decoded
         # batches carry device-rendered wire buffers (`device_egress`)
         self.egress_encoder = egress_encoder
-        # byte seal (0 = off): seal the open run once its size-hint
+        # byte seal (0 = off): seal the open group once its size-hint
         # bytes reach this bound (scaled with the dynamic row seal the
-        # same ×-factor _scaled_max_bytes uses), so one contiguous run
-        # can never exceed the flush sizing — size-bounded flushes then
-        # cut at event granularity and the write window has batches to
-        # pipeline. The apply loop passes BatchConfig.max_size_bytes; at
-        # typical row widths the 16384-row seal binds first, so decode
-        # batch shapes are unchanged.
+        # same ×-factor _scaled_max_bytes uses), so one group — the
+        # least a flush can carry — can never exceed the flush sizing:
+        # size-bounded flushes then cut between groups and the write
+        # window has batches to pipeline. The apply loop passes
+        # BatchConfig.max_size_bytes; at typical row widths the
+        # 16384-row seal binds first, so decode batch shapes are
+        # unchanged.
         self.seal_bytes = seal_bytes
         # fair-admission wiring (ops/pipeline.AdmissionScheduler): this
         # loop's decode pipeline takes one tenant seat on the process-
@@ -102,18 +114,27 @@ class EventAssembler:
         self._lag_bytes = lag_bytes
         self._admission_capacity = admission_capacity
         self._events: list[Event] = []
-        # per-event (size_bytes, row_events) — lets flush(max_bytes=...)
-        # cut a WAL-ordered prefix at event granularity and keep the
+        # per-event (size_bytes, row_events, joined) — lets
+        # flush(max_bytes=...) cut a WAL-ordered prefix and keep the
         # remainder's accounting exact (the write window dispatches
-        # size-bounded batches instead of one backlog-sized mega write)
-        self._meta: list[tuple[int, int]] = []
+        # size-bounded batches instead of one backlog-sized mega write).
+        # `joined` marks an event sealed from the same group as the one
+        # before it: the cut never falls in front of such an event
+        self._meta: list[tuple[int, int, bool]] = []
         # commit watermarks: (n_events_covered, commit_end_lsn) — all
-        # events with index < n (counting the open run as one future
-        # event) belong to commits ending ≤ commit_end_lsn, so a prefix
-        # flush of ≥ n events may claim durability at that LSN once
-        # acked. The apply loop records one per commit boundary
-        # (note_commit_end); flush() consumes the covered prefix.
+        # events with index < n (counting each open run as the one
+        # future event it seals into) belong to commits ending ≤
+        # commit_end_lsn, so a prefix flush of ≥ n events may claim
+        # durability at that LSN once acked. The apply loop records one
+        # per commit boundary (note_commit_end); flush() consumes the
+        # covered prefix.
         self._commit_marks: list[tuple[int, int]] = []
+        # the open group: one run per table, in first-row order (every
+        # run holds at least one row), and its size-hint bytes
+        self._group: dict[TableId, _Run] = {}
+        self._group_bytes = 0
+        # the run _seal_run is to stage: _seal_group hands the group's
+        # runs over one at a time (None between seals)
         self._run: _Run | None = None
         self._decoders: dict[TableId, DeviceDecoder] = {}
         # one decode pipeline (worker thread + bounded in-flight window)
@@ -143,7 +164,8 @@ class EventAssembler:
         self.row_events = 0
 
     def __len__(self) -> int:
-        return len(self._events) + (len(self._run.payloads) if self._run else 0)
+        """The events a whole-window flush would return."""
+        return len(self._events) + len(self._group)
 
     # -- pushes ---------------------------------------------------------------
 
@@ -151,9 +173,9 @@ class EventAssembler:
         """Begin/Commit/Relation/Truncate/SchemaChange — barrier events."""
         if self.filled_since_ns is None:
             self.filled_since_ns = spans.now_ns()
-        self._seal_run()
+        self._seal_group()
         self._events.append(ev)
-        self._meta.append((size_hint, 0))
+        self._meta.append((size_hint, 0, False))
         self.size_bytes += size_hint
 
     @hot_loop
@@ -166,24 +188,20 @@ class EventAssembler:
         per CDC row — a host transfer here caps stream throughput."""
         if self.filled_since_ns is None:
             self.filled_since_ns = spans.now_ns()
-        if self._run is None or self._run.table_id != schema.id \
-                or self._run.schema is not schema:
-            if self._run is not None and self._run.payloads:
-                registry.counter_inc(ETL_ASSEMBLER_TABLE_SWITCH_SEALS_TOTAL)
-            self._seal_run()
-            self._run = _Run(table_id=schema.id, schema=schema)
-        r = self._run
+        r = self._open_run(schema)
         r.payloads.append(payload)
         r.start_lsns.append(int(start_lsn))
         r.commit_lsns.append(int(commit_lsn))
         r.tx_ordinals.append(tx_ordinal)
-        r.nbytes += 64 + len(payload)
-        self.size_bytes += 64 + len(payload)
+        nbytes = 64 + len(payload)
+        r.nbytes += nbytes
+        self._group_bytes += nbytes
+        self.size_bytes += nbytes
         self.row_events += 1
         if len(r.payloads) >= self.seal_rows \
                 or (self.seal_bytes
-                    and r.nbytes >= self._scaled_seal_bytes()):
-            self._seal_run()
+                    and self._group_bytes >= self._scaled_seal_bytes()):
+            self._seal_group()
 
     @hot_loop
     def push_raw_rows(self, payloads: list[bytes],
@@ -196,34 +214,44 @@ class EventAssembler:
         @hot_loop: one call per drained span on the saturated path."""
         if self.filled_since_ns is None:
             self.filled_since_ns = spans.now_ns()
-        if self._run is None or self._run.table_id != schema.id \
-                or self._run.schema is not schema:
-            if self._run is not None and self._run.payloads:
-                registry.counter_inc(ETL_ASSEMBLER_TABLE_SWITCH_SEALS_TOTAL)
-            self._seal_run()
-            self._run = _Run(table_id=schema.id, schema=schema)
-        r = self._run
         k = len(payloads)
+        if not k:
+            return 0
+        r = self._open_run(schema)
         if len(r.payloads) + k > self.seal_rows and r.payloads:
             # seal BEFORE extending: overshooting the cap would bump the
             # staged batch into the next (unwarmed) row bucket
-            self._seal_run()
-            self._run = r = _Run(table_id=schema.id, schema=schema)
+            self._seal_group()
+            r = self._open_run(schema)
         r.payloads.extend(payloads)
         r.start_lsns.extend(start_lsns)
         r.commit_lsns.extend([commit_lsn] * k)
         r.tx_ordinals.extend(range(tx_ordinal0, tx_ordinal0 + k))
         nbytes = sum(map(len, payloads))
         r.nbytes += 64 * k + nbytes
+        self._group_bytes += 64 * k + nbytes
         self.size_bytes += 64 * k + nbytes
         self.row_events += k
         if len(r.payloads) >= self.seal_rows \
                 or (self.seal_bytes
-                    and r.nbytes >= self._scaled_seal_bytes()):
+                    and self._group_bytes >= self._scaled_seal_bytes()):
             # byte overshoot of at most one span: the seal check runs per
             # span push, so a drained-window span lands whole
-            self._seal_run()
+            self._seal_group()
         return nbytes
+
+    def _open_run(self, schema: ReplicatedTableSchema) -> _Run:
+        """The open run of `schema`'s table, opened where the group has
+        none: a row of another table seals nothing. A run whose schema
+        object changed starts anew, after the group is sealed."""
+        r = self._group.get(schema.id)
+        if r is not None and r.schema is not schema:
+            self._seal_group()
+            r = None
+        if r is None:
+            r = self._group[schema.id] = _Run(
+                table_id=schema.id, schema=schema, joined=bool(self._group))
+        return r
 
     def _scaled_seal_bytes(self) -> int:
         """Byte seal scaled with the dynamic row seal — the same growth
@@ -263,7 +291,7 @@ class EventAssembler:
             if self.filled_since_ns is None:
                 self.filled_since_ns = spans.now_ns()
             self._events.append(ev)
-            self._meta.append((64 + len(payload), 1))
+            self._meta.append((64 + len(payload), 1, False))
             self.size_bytes += 64 + len(payload)
             self.row_events += 1
             return
@@ -272,10 +300,21 @@ class EventAssembler:
 
     # -- flush ----------------------------------------------------------------
 
-    def _seal_run(self) -> None:
-        if self._run is None or not self._run.payloads:
-            self._run = None
+    def _seal_group(self) -> None:
+        """Seal every open run, in the order of each run's first row, into
+        consecutive events: one contiguous stretch of WAL, which
+        flush_bounded never cuts."""
+        group = self._group
+        if not group:
             return
+        self._group = {}
+        self._group_bytes = 0
+        for run in group.values():
+            self._run = run
+            self._seal_run()
+
+    def _seal_run(self) -> None:
+        """Stage and submit `self._run` (one sealed run, one event)."""
         from ..chaos import failpoints
 
         # chaos site: fires once per sealed run (a decode batch is born)
@@ -376,20 +415,19 @@ class EventAssembler:
             old_is_key=wal.old_is_key, delete_is_key=wal.delete_is_key,
             batch_id=batch_id,
         ))
-        self._meta.append((64 * len(r.payloads) + sum(map(len, r.payloads)),
-                           len(r.payloads)))
+        self._meta.append((r.nbytes, len(r.payloads), r.joined))
 
     def note_commit_end(self, end_lsn: Lsn) -> None:
         """Record a commit watermark: every event assembled SO FAR
-        (counting the open run as the one event it seals into) belongs
-        to transactions whose commit ends ≤ `end_lsn`. The open run may
-        still grow past the mark — the sealed event then carries extra
-        later rows, which only makes the covered prefix a superset
-        (claiming durability at the mark stays exact). The apply loop
-        calls this once per commit boundary; flush() consumes marks with
-        the prefix they cover."""
-        n = len(self._events) \
-            + (1 if self._run is not None and self._run.payloads else 0)
+        (counting each open run as the one event it seals into) belongs
+        to transactions whose commit ends ≤ `end_lsn`. The open runs may
+        still grow past the mark, and later tables may join the group
+        behind them — the group then carries extra later rows, which
+        only makes the covered prefix a superset (claiming durability at
+        the mark stays exact; a cut never falls inside the group). The
+        apply loop calls this once per commit boundary; flush() consumes
+        marks with the prefix they cover."""
+        n = len(self._events) + len(self._group)
         lsn = int(end_lsn)
         if self._commit_marks and self._commit_marks[-1][0] == n:
             self._commit_marks[-1] = (n, max(self._commit_marks[-1][1], lsn))
@@ -397,31 +435,49 @@ class EventAssembler:
             self._commit_marks.append((n, lsn))
 
     def flush(self) -> list[Event]:
-        """Seal any open run, return and reset the assembled events
+        """Seal the open group, return and reset the assembled events
         (the whole window — legacy signature; the apply loop's
         size-bounded dispatch goes through `flush_bounded`)."""
         return self.flush_bounded()[0]
 
     def flush_bounded(self, max_bytes: "int | None" = None
                       ) -> "tuple[list[Event], Lsn | None, Lsn | None]":
-        """Seal any open run and return `(events, covered_commit_end,
+        """Seal the open group and return `(events, covered_commit_end,
         remaining_commit_end)`.
 
         With `max_bytes=None` (or everything fitting) the whole window
-        flushes — exact legacy behavior. Otherwise a WAL-ORDERED PREFIX
-        of events totalling ≤ max_bytes (always at least one event) is
-        returned and the remainder stays assembled, so the write window
-        dispatches size-bounded batches a backlog can pipeline instead
-        of one backlog-sized mega write.
+        flushes. Otherwise a WAL-ORDERED PREFIX of whole groups — a
+        control event is a group of its own — totalling ≤ max_bytes
+        (always at least one group) is returned and the remainder stays
+        assembled, so the write window dispatches size-bounded batches a
+        backlog can pipeline instead of one backlog-sized mega write.
+        The cut never parts a group: its events interleave in the WAL,
+        and no row may leave in a later flush than a row with higher
+        coordinates (the transactional sinks drop every row at or below
+        the last flush's highest).
 
         `covered_commit_end` is the highest commit watermark whose
         events are ALL inside the returned prefix (None = the flush
         covers no commit boundary — mid-transaction split);
         `remaining_commit_end` is the highest watermark still pending in
         the assembler (None = nothing awaits a future flush)."""
-        self._seal_run()
-        if max_bytes is None or self.size_bytes <= max_bytes \
-                or len(self._events) <= 1:
+        self._seal_group()
+        meta = self._meta
+        k = n = len(self._events)
+        cum = 0  # bytes of the prefix events[:k], where it is cut
+        if max_bytes is not None and self.size_bytes > max_bytes:
+            k = 0
+            while k < n:
+                size = meta[k][0]
+                end = k + 1
+                while end < n and meta[end][2]:
+                    size += meta[end][0]
+                    end += 1
+                if k and cum + size > max_bytes:
+                    break
+                cum += size
+                k = end
+        if k == n:
             events = self._events
             covered = Lsn(self._commit_marks[-1][1]) \
                 if self._commit_marks else None
@@ -432,17 +488,11 @@ class EventAssembler:
             self.row_events = 0
             self.filled_since_ns = None
             return events, covered, None
-        cum = 0
-        k = 0
-        n = len(self._events)
-        while k < n and (k == 0 or cum + self._meta[k][0] <= max_bytes):
-            cum += self._meta[k][0]
-            k += 1
         events = self._events[:k]
         self._events = self._events[k:]
-        self._meta = self._meta[k:]
+        self._meta = meta[k:]
         self.size_bytes -= cum
-        self.row_events = sum(r for _, r in self._meta)
+        self.row_events = sum(m[1] for m in self._meta)
         self.filled_since_ns = spans.now_ns()
         covered = None
         while self._commit_marks and self._commit_marks[0][0] <= k:
@@ -455,8 +505,8 @@ class EventAssembler:
     def close(self) -> None:
         """Stop the decode pipeline's worker (apply-loop teardown).
         Already-flushed DecodedBatchEvents stay resolvable — close only
-        fences new submits, and _seal_run re-creates the pipeline if a
-        resumed loop reuses this assembler."""
+        fences new submits, and _stage_and_submit re-creates the
+        pipeline if a resumed loop reuses this assembler."""
         if self._pipeline is not None:
             self._pipeline.close()
             self._pipeline = None

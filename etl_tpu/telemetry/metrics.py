@@ -307,10 +307,10 @@ ETL_INTAKE_SEGMENT_SECONDS = "etl_intake_segment_seconds"
 ETL_APPLY_FRAME_WALK_SECONDS = "etl_apply_frame_walk_seconds"
 ETL_ASSEMBLER_SEAL_SECONDS = "etl_assembler_seal_seconds"
 ETL_ASSEMBLER_SEALED_ROWS_TOTAL = "etl_assembler_sealed_rows_total"
-# seals forced because the next row belongs to another table (or to
-# another schema object of the same table): the rest of
-# etl_assembler_seal_seconds' count are seals at a flush, at a control
-# event or by size
+# seals forced because the next row belongs to another table: never
+# incremented since the assembler keeps one open run per table (PR 34) —
+# every seal is now one at a flush, at a control event or by size. The
+# series stays for what reads it (docs/OPERATIONS.md)
 ETL_ASSEMBLER_TABLE_SWITCH_SEALS_TOTAL = \
     "etl_assembler_table_switch_seals_total"
 ETL_APPLY_DISPATCH_BLOCKED_SECONDS_TOTAL = \

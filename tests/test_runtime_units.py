@@ -209,7 +209,7 @@ class TestAssemblerBulkPush:
                                   [100 + i for i in range(10)], 500, 0)
         assert nbytes == sum(len(p) for p in payloads)
         assert a1.size_bytes == a2.size_bytes
-        r1, r2 = a1._run, a2._run
+        r1, r2 = a1._group[schema.id], a2._group[schema.id]
         assert r1.payloads == r2.payloads
         assert r1.start_lsns == r2.start_lsns
         assert r1.commit_lsns == r2.commit_lsns
@@ -347,7 +347,7 @@ class TestDynamicSeal:
         payloads = [pgoutput.encode_insert(7, [b"1"])] * n
         a.push_raw_rows(payloads, schema, list(range(n)), 999, 0)
         # the run is still OPEN (one future DecodedBatchEvent, not two)
-        assert a._run is not None and len(a._run.payloads) == n
+        assert len(a._group[schema.id].payloads) == n
 
     def test_scaled_flush_threshold_tracks_seal(self):
         from etl_tpu.config import BatchConfig, PipelineConfig

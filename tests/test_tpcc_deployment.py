@@ -434,17 +434,20 @@ def _schemas():
 
 
 # (the pushes, as "a"/"b" rows, "|" a control event, "B" a two-row bulk
-# push of b), then what the counters must have moved by
+# push of b), then what the counters must have moved by. Since PR 34 a
+# table has one open run however the pushes interleave ("ababab" seals
+# two runs of three rows), a row of another table seals nothing (`switch`
+# stays 0) and a control event is still a barrier
 STREAMS = [
-    ("aab", {"switch": 1, "seals": 2, "rows": 3, "cells": 8,
+    ("aab", {"switch": 0, "seals": 2, "rows": 3, "cells": 8,
              "device_kind": 4}),
-    ("ababab", {"switch": 5, "seals": 6, "rows": 6, "cells": 15,
+    ("ababab", {"switch": 0, "seals": 2, "rows": 6, "cells": 15,
                 "device_kind": 9}),
     ("aa|aa", {"switch": 0, "seals": 2, "rows": 4, "cells": 12,
                "device_kind": 4}),
     ("aaaa", {"switch": 0, "seals": 1, "rows": 4, "cells": 12,
               "device_kind": 4}),
-    ("aBBa", {"switch": 2, "seals": 3, "rows": 6, "cells": 14,
+    ("aBBa", {"switch": 0, "seals": 2, "rows": 6, "cells": 14,
               "device_kind": 10}),
     ("a|b", {"switch": 0, "seals": 2, "rows": 2, "cells": 5,
              "device_kind": 3}),
@@ -492,23 +495,29 @@ def test_a_size_seal_is_no_table_switch():
     from etl_tpu.postgres.codec import pgoutput
     from etl_tpu.runtime.assembler import EventAssembler
 
-    _, ints = _schemas()
+    mixed, ints = _schemas()
     payload = pgoutput.encode_insert(8, [b"1", b"2024-01-01 00:00:00"])
     before = _counters()
     a = EventAssembler(BatchEngine.TPU)
     a.seal_rows = 4
     try:
+        # the other table's one row rides in the group the first size
+        # seal takes: sealed with it, and still no table switch
+        a.push_raw_row(pgoutput.encode_insert(7, [b"1", b"2.50", b"x"]),
+                       mixed, Lsn(99), Lsn(900), 0)
         for i in range(10):
-            a.push_raw_row(payload, ints, Lsn(100 + i), Lsn(900), i)
-        a.push_raw_rows([payload] * 3, ints, [200, 201, 202], 900, 10)
+            a.push_raw_row(payload, ints, Lsn(100 + i), Lsn(900), 1 + i)
+        # sealed: mixed 1, ints 4, ints 4; one run open
+        assert len(a) == 3 + 1
+        a.push_raw_rows([payload] * 3, ints, [200, 201, 202], 900, 11)
         a.flush()
     finally:
         a.close()
     after = _counters()
     assert after["switch"] == before["switch"]
-    assert after["seals"] - before["seals"] == 4
-    assert after["rows"] - before["rows"] == 13
-    assert after["cells"] - before["cells"] == 26
+    assert after["seals"] - before["seals"] == 5
+    assert after["rows"] - before["rows"] == 14
+    assert after["cells"] - before["cells"] == 29
 
 
 @pytest.mark.parametrize("rows,route", [(3, "oracle"), (8, "device")])
@@ -533,24 +542,35 @@ def test_device_parsed_cells_count_device_routed_batches(rows, route):
 # ---------------------------------------------------------------------------
 
 
-def test_the_cell_rehearses_correct_with_its_metrics():
+def test_the_cell_rehearses_correct_with_its_metrics(tmp_path):
+    # the cell's own mix with five times its rehearsal backlog: since
+    # PR 34 a CPU left to itself drains the file's 20,000 events a second
+    # of rehearsal, and a run that sends them all is `backlog_exhausted`
+    with open(TRAFFIC_PATH) as f:
+        traffic = json.load(f)
+    traffic["rehearsal"]["backlog_events_per_second"] *= 5
+    mix = tmp_path / "standard-mix-drain.json"
+    mix.write_text(json.dumps(traffic))
     out = subprocess.run(
         [sys.executable, os.path.join(BENCH, "run.py"), "--workload", CELL,
          "--seed", "2147483659", "--seconds", "2", "--trace", "1",
-         "--rehearse"], capture_output=True, text=True, timeout=600,
+         "--rehearse", "--traffic-file", str(mix)],
+        capture_output=True, text=True, timeout=600,
         env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert out.returncode == 0, out.stderr[-2000:]
     line = json.loads(out.stdout.strip().splitlines()[-1])
     assert line["correct"] is True and line["failed"] == 0, line["checks"]
     assert all(v["value"] == 0 for v in line["checks"].values())
     m = {k: v["value"] for k, v in line["metrics"].items()}
-    assert m["rehearsal.tpcc_runs_per_transaction_mean"] \
-        == pytest.approx(4.0, abs=0.25)
-    assert m["rehearsal.tpcc_table_switch_seal_share_pct"] > 90
+    # one run per table per flush, not one per table switch (PR 34: 4.0
+    # runs a transaction and 98% table-switch seals before it)
+    assert 0 < m["rehearsal.tpcc_runs_per_transaction_mean"] < 1.5
+    assert m["rehearsal.tpcc_table_switch_seal_share_pct"] == 0
+    assert m["rehearsal.tpcc_rows_per_run_mean"] > 9
     assert 0 < m["rehearsal.tpcc_device_kind_cell_share_pct"] < 100
     for name in ("tpcc_rows_per_run_mean", "tpcc_seal_s_per_mrow",
                  "tpcc_rows_per_flush_mean",
                  "tpcc_device_parsed_cell_share_pct",
                  "drain_rows_per_seal_p50", "drain_dispatch_blocked_pct"):
         assert "rehearsal." + name in m, sorted(m)
-    assert m["rehearsal.drain_rows_per_seal_p50"] == 1
+    assert m["rehearsal.drain_rows_per_seal_p50"] > 1
