@@ -39,8 +39,10 @@ from ..models.table_row import ColumnarBatch
 from ..native import native_available
 from ..analysis.annotations import transactional_commit
 from ..telemetry import spans
-from ..telemetry.metrics import (ETL_CLICKHOUSE_RENDER_SECONDS,
-                                 ETL_CLICKHOUSE_REQUEST_SECONDS)
+from ..telemetry.metrics import (ETL_CLICKHOUSE_BOXED_CELLS_TOTAL,
+                                 ETL_CLICKHOUSE_RENDER_SECONDS,
+                                 ETL_CLICKHOUSE_RENDERED_CELLS_TOTAL,
+                                 ETL_CLICKHOUSE_REQUEST_SECONDS, registry)
 from .base import CommitRange, Destination, WriteAck
 from .base import expand_batch_events
 from .util import (CDC_DELETE, CDC_UPSERT, CHANGE_SEQUENCE_COLUMN,
@@ -295,7 +297,18 @@ def _column_piece_tsv(col, dev, oracle_rows: set):
     per-value renderer. Rows neither source can render verbatim
     (temporal specials, strings needing escapes go per-value inside the
     piece; whole-row cases land in `oracle_rows`). Returns
-    (piece, used_device)."""
+    (piece, used_device). Counts the column's cells, and those of them
+    that went value by value through Python: one increment a column,
+    none a row."""
+    piece, used_device, boxed = _column_piece(col, dev, oracle_rows)
+    registry.counter_inc(ETL_CLICKHOUSE_RENDERED_CELLS_TOTAL, len(col))
+    if boxed:
+        registry.counter_inc(ETL_CLICKHOUSE_BOXED_CELLS_TOTAL, boxed)
+    return piece, used_device
+
+
+def _column_piece(col, dev, oracle_rows: set):
+    """(piece, used_device, boxed cells) of `_column_piece_tsv`."""
     from ..ops import egress as eg
 
     n = len(col)
@@ -320,7 +333,7 @@ def _column_piece_tsv(col, dev, oracle_rows: set):
             oracle_rows.update(np.flatnonzero(specials).tolist())
         if dev is not None:
             buf, lens = eg.patch_rows_fixed(dev[0], dev[1], nulls, _TSV_NULL)
-            return eg.fixed_piece(buf, lens), True
+            return eg.fixed_piece(buf, lens), True, 0
         if kind is CellKind.BOOL:
             buf, lens = eg.bool_text_fixed(data)
         elif kind is CellKind.DATE:
@@ -330,13 +343,14 @@ def _column_piece_tsv(col, dev, oracle_rows: set):
         else:
             buf, lens = eg.int_text_fixed(data)
         buf, lens = eg.patch_rows_fixed(buf, lens, nulls, _TSV_NULL)
-        return eg.fixed_piece(buf, lens), False
+        return eg.fixed_piece(buf, lens), False, 0
     if col.is_dense and kind in (CellKind.F32, CellKind.F64):
         data = col.data.tolist()  # Python floats: str() matches row path
         items = [_TSV_NULL] * n
-        for i in np.flatnonzero(valid).tolist():
+        present = np.flatnonzero(valid).tolist()
+        for i in present:
             items[i] = str(data[i]).encode()
-        return eg.var_from_texts(items), False
+        return eg.var_from_texts(items), False, len(present)
     if col.is_arrow and kind is CellKind.STRING \
             and col.lazy_text_oid is None and col.data.offset == 0:
         bufs = col.data.buffers()
@@ -356,18 +370,20 @@ def _column_piece_tsv(col, dev, oracle_rows: set):
                 out, starts = eg.assemble_rows(
                     n, [piece], {int(i): _TSV_NULL for i in nulls})
                 piece = ("var", out, starts)
-            return piece, False
+            return piece, False, 0
         texts = col.data.to_pylist()
         items = [_TSV_NULL] * n
-        for i in np.flatnonzero(valid).tolist():
+        present = np.flatnonzero(valid).tolist()
+        for i in present:
             items[i] = _tsv_escape(texts[i]).encode()
-        return eg.var_from_texts(items), False
+        return eg.var_from_texts(items), False, len(present)
     # generic fallback (NUMERIC/TIME/JSON/bytes/arrays/lazy-text): box the
     # value, reuse the row-path renderer — same stance as _column_texts
     items = [_TSV_NULL] * n
-    for i in np.flatnonzero(valid).tolist():
+    present = np.flatnonzero(valid).tolist()
+    for i in present:
         items[i] = render_value(col.value(i), kind).encode()
-    return eg.var_from_texts(items), False
+    return eg.var_from_texts(items), False, len(present)
 
 
 @hot_loop
