@@ -724,6 +724,11 @@ async def _pipeline_phase(seed: int, copy_rows: int, cdc_events: int,
         await asyncio.wait_for(
             store.notify_on(TID_ACCOUNTS, TableStateType.READY), 600)
         copy_s = time.perf_counter() - t_started
+        # a compile the copy's last batches started is the copy's: where it
+        # outlived the copy, the warm-up's first wave would meet it on the
+        # oracle with no compile of its own to explain it
+        while engine.background_compiles_inflight():
+            await asyncio.sleep(0.05)
         c1, m1 = _counters(), meter_read()
         check(dest.copy == fold_columns(*copy_cols),
               f"copy delivered {dest.copy}, the generator made "

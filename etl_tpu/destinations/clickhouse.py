@@ -290,16 +290,96 @@ def _count_egress_write(used_device: bool) -> None:
     count_egress_write(used_device)
 
 
+# byte classes of a NUMERIC cell's text: what `_numeric_rows_by_value`
+# tests a spelling by, and what each byte past the sign weighs in its
+# one sum a cell (a point 1: one is allowed; a second sign or a byte
+# outside `0-9 . -` 2: none is)
+_NUM_NONZERO, _NUM_ZERO, _NUM_POINT, _NUM_SIGN, _NUM_OTHER = range(5)
+_NUMERIC_CLASS = np.full(256, _NUM_OTHER, dtype=np.uint8)
+_NUMERIC_CLASS[ord("1"):ord("9") + 1] = _NUM_NONZERO
+_NUMERIC_CLASS[ord("0")] = _NUM_ZERO
+_NUMERIC_CLASS[ord(".")] = _NUM_POINT
+_NUMERIC_CLASS[ord("-")] = _NUM_SIGN
+_NUMERIC_WEIGHT = np.array([0, 0, 1, 2, 2], dtype=np.uint8)
+
+
+def _arrow_text_buffers(arr, n: int):
+    """(values uint8[], offsets int32[n + 1]) of an unsliced Arrow string
+    array, as views of its buffers."""
+    bufs = arr.buffers()
+    offs = np.frombuffer(bufs[1], dtype=np.int32, count=n + 1) \
+        if bufs[1] is not None else np.zeros(n + 1, dtype=np.int32)
+    vals = np.frombuffer(bufs[2], dtype=np.uint8) if bufs[2] is not None \
+        else np.zeros(0, dtype=np.uint8)
+    return vals, offs
+
+
+def _numeric_rows_by_value(vals, offs, valid) -> np.ndarray:
+    """The present rows of a lazy-text NUMERIC column whose bytes may
+    differ from `render_value(col.value(i), kind)`: every cell that is not
+    spelt `-?(0|[1-9][0-9]*)(\\.[0-9]+)?`. That form is what Postgres'
+    `numeric_out` writes for a finite value and what
+    `PgNumeric.pg_text()` (`format(Decimal(text), "f")`) gives back
+    digit for digit, sign and scale kept (`-0.00` too). `NaN`,
+    `Infinity`, an exponent, a `+`, whitespace, a redundant leading zero
+    (`007` parses to `7`), a bare `.5` or `5.`, an empty string and
+    anything malformed are named here and parsed (or refused) as before.
+    Conservative, never optimistic: a form not proven safe goes by value.
+    Two table lookups and one sum over the column's bytes, the rest per
+    row: a row's bytes never decide for another row."""
+    n = valid.size
+    base, end = int(offs[0]), int(offs[n])
+    size = end - base
+    # one class past the end, so that a cell's first, second and last
+    # byte can be read without testing its length first
+    cls = np.empty(size + 1, dtype=np.uint8)
+    np.take(_NUMERIC_CLASS, vals[base:end], out=cls[:size])
+    cls[size] = _NUM_OTHER
+    bounds = offs.astype(np.int64) - base
+    lo, hi = bounds[:-1], bounds[1:]
+    signed = cls.take(lo) == _NUM_SIGN
+    digits = hi - lo - signed  # bytes past the sign; under 1: no number
+    first = lo + signed
+    lead = cls.take(np.minimum(first, size))
+    after_lead = cls.take(np.minimum(first + 1, size))
+    last = cls.take(np.maximum(hi - 1, 0))
+    # a cell ends where the next starts, so one sum per start is a sum
+    # per cell (an empty cell reads its neighbour's: it is refused by
+    # `digits` whatever that is)
+    weight = _NUMERIC_WEIGHT.take(cls)
+    weight[size] = 0
+    weighed = np.add.reduceat(weight, lo, dtype=np.int64)
+    ok = ((digits > 0) & (lead <= _NUM_ZERO) & (last <= _NUM_ZERO)
+          & ((lead == _NUM_NONZERO) | (digits == 1)
+             | (after_lead == _NUM_POINT))
+          & (weighed <= 1 + 2 * signed))
+    return np.flatnonzero(valid & ~ok)
+
+
+def _text_var_piece(n: int, vals, offs, override: dict):
+    """The `var` piece of a text column's Arrow buffers, the rows of
+    `override` (NULLs as `\\N`, cells rendered by value) replaced."""
+    from ..ops import egress as eg
+
+    piece = ("var", vals, offs.astype(np.int64))
+    if override:
+        out, starts = eg.assemble_rows(n, [piece], override)
+        piece = ("var", out, starts)
+    return piece
+
+
 def _column_piece_tsv(col, dev, oracle_rows: set):
     """One column's TSV field bytes as an assembly piece (ops/egress.py
     piece protocol). Sources, in order: the device-rendered buffer
-    (`dev`), the numpy host twin, a zero-copy Arrow slice, or the
-    per-value renderer. Rows neither source can render verbatim
-    (temporal specials, strings needing escapes go per-value inside the
-    piece; whole-row cases land in `oracle_rows`). Returns
-    (piece, used_device). Counts the column's cells, and those of them
-    that went value by value through Python: one increment a column,
-    none a row."""
+    (`dev`), the numpy host twin, the Arrow buffers as they stand (clean
+    strings; the decoder's unparsed NUMERIC text), or the per-value
+    renderer. What a source cannot render verbatim goes value by value:
+    a temporal special takes its whole row to `oracle_rows`, a text
+    column with a value that needs an escape goes per value for all its
+    rows, a NUMERIC column for the rows `_numeric_rows_by_value` names
+    and no others. Returns (piece, used_device). Counts the column's
+    cells, and those of them that went value by value through Python:
+    one increment a column, none a row."""
     piece, used_device, boxed = _column_piece(col, dev, oracle_rows)
     registry.counter_inc(ETL_CLICKHOUSE_RENDERED_CELLS_TOTAL, len(col))
     if boxed:
@@ -353,11 +433,7 @@ def _column_piece(col, dev, oracle_rows: set):
         return eg.var_from_texts(items), False, len(present)
     if col.is_arrow and kind is CellKind.STRING \
             and col.lazy_text_oid is None and col.data.offset == 0:
-        bufs = col.data.buffers()
-        offs = np.frombuffer(bufs[1], dtype=np.int32, count=n + 1) \
-            if bufs[1] is not None else np.zeros(n + 1, dtype=np.int32)
-        vals = np.frombuffer(bufs[2], dtype=np.uint8) if bufs[2] is not None \
-            else np.zeros(0, dtype=np.uint8)
+        vals, offs = _arrow_text_buffers(col.data, n)
         region = vals[offs[0]:offs[n]]
         clean = True
         for b in _TSV_ESCAPE_BYTES:
@@ -365,20 +441,28 @@ def _column_piece(col, dev, oracle_rows: set):
                 clean = False
                 break
         if clean:
-            piece = ("var", vals, offs.astype(np.int64))
-            if nulls.size:
-                out, starts = eg.assemble_rows(
-                    n, [piece], {int(i): _TSV_NULL for i in nulls})
-                piece = ("var", out, starts)
-            return piece, False, 0
+            return _text_var_piece(n, vals, offs, {
+                int(i): _TSV_NULL for i in nulls}), False, 0
         texts = col.data.to_pylist()
         items = [_TSV_NULL] * n
         present = np.flatnonzero(valid).tolist()
         for i in present:
             items[i] = _tsv_escape(texts[i]).encode()
         return eg.var_from_texts(items), False, len(present)
-    # generic fallback (NUMERIC/TIME/JSON/bytes/arrays/lazy-text): box the
-    # value, reuse the row-path renderer — same stance as _column_texts
+    if col.is_arrow and kind is CellKind.NUMERIC \
+            and col.lazy_text_oid is not None and col.data.offset == 0:
+        # the decoder's exact Postgres text is the TSV field: only the
+        # rows whose spelling `pg_text` could change are parsed
+        vals, offs = _arrow_text_buffers(col.data, n)
+        by_value = _numeric_rows_by_value(vals, offs, valid).tolist()
+        override = {int(i): _TSV_NULL for i in nulls}
+        for i in by_value:
+            override[i] = render_value(col.value(i), kind).encode()
+        return _text_var_piece(n, vals, offs, override), False, len(by_value)
+    # generic fallback (TIME/JSON/bytes/arrays, the other lazy-text kinds,
+    # a NUMERIC column held as Python objects or as a sliced Arrow array):
+    # box the value, reuse the row-path renderer — same stance as
+    # _column_texts
     items = [_TSV_NULL] * n
     present = np.flatnonzero(valid).tolist()
     for i in present:

@@ -335,10 +335,12 @@ ETL_COPY_STREAM_SLOW_MESSAGES_TOTAL = "etl_copy_stream_slow_messages_total"
 ETL_CLICKHOUSE_RENDER_SECONDS = "etl_clickhouse_render_seconds"
 ETL_CLICKHOUSE_REQUEST_SECONDS = "etl_clickhouse_request_seconds"
 # cells (a row x a column) of columnar ClickHouse writes, and those of them
-# rendered value by value in Python instead of as one column piece (NUMERIC /
-# TIME / JSON / bytes / arrays / lazy text, floats, the text columns of a
-# batch the per-row oracle decoded, a text column with a value that needs a
-# TSV escape; NULLs never): one increment a column a batch, none a row
+# rendered value by value in Python instead of as one column piece (TIME /
+# JSON / bytes / arrays / lazy text but NUMERIC, floats, the NUMERIC and text
+# columns of a batch the per-row oracle decoded, a text column with a value
+# that needs a TSV escape, the cells of a lazy-text NUMERIC column that are
+# not spelt as `numeric_out` spells a finite value; NULLs never): one
+# increment a column a batch, none a row
 ETL_CLICKHOUSE_RENDERED_CELLS_TOTAL = "etl_clickhouse_rendered_cells_total"
 ETL_CLICKHOUSE_BOXED_CELLS_TOTAL = "etl_clickhouse_boxed_cells_total"
 ETL_EVENT_LOOP_LAG_SECONDS = "etl_event_loop_lag_seconds"

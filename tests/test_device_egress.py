@@ -451,6 +451,195 @@ class TestClickHouseTsvIdentity:
         assert fast == oracle
 
 
+def _numeric_schema(tid=43020):
+    return _schema((
+        ColumnSchema("pk", Oid.INT8, nullable=False, primary_key_ordinal=1),
+        ColumnSchema("num", Oid.NUMERIC),
+        ColumnSchema("s", Oid.TEXT)), tid=tid, name="numerics")
+
+
+def _numeric_batch(texts, toast=(), storage="lazy"):
+    """(pk, num, s) with the NUMERIC column as the decoder hands it over:
+    `lazy` — its exact Postgres text in an Arrow array with
+    `lazy_text_oid` set; `sliced` — the same behind a non-zero Arrow
+    offset; `objects` — a list of parsed values, as the per-row oracle
+    and `_cpu_fixup` leave it. `toast` rows are unchanged-TOAST cells."""
+    from etl_tpu.models.table_row import Column
+    from etl_tpu.postgres.codec.text import parse_cell_text
+
+    schema = _numeric_schema()
+    cols = schema.replicated_columns
+    n = len(texts)
+    valid = np.array([t is not None for t in texts], dtype=np.bool_)
+    unchanged = None
+    if toast:
+        unchanged = np.zeros(n, dtype=np.bool_)
+        unchanged[list(toast)] = True
+        valid &= ~unchanged
+    if storage == "objects":
+        num = Column(cols[1], [None if t is None
+                               else parse_cell_text(t, Oid.NUMERIC)
+                               for t in texts], valid, unchanged)
+    else:
+        arr = pa.array(texts, type=pa.string())
+        if storage == "sliced":
+            arr = pa.array(["0.5"] + list(texts), type=pa.string()).slice(1)
+            assert arr.offset == 1
+        num = Column(cols[1], arr, valid, unchanged,
+                     lazy_text_oid=Oid.NUMERIC)
+    return schema, ColumnarBatch(schema, [
+        Column(cols[0], np.arange(n, dtype=np.int64) - 3,
+               np.ones(n, dtype=np.bool_)),
+        num,
+        Column(cols[2], pa.array(["s%d" % i for i in range(n)],
+                                 type=pa.string()),
+               np.ones(n, dtype=np.bool_))])
+
+
+# name -> (the column's texts, unchanged-TOAST rows, storage, the cells
+# that go value by value)
+_NUMERIC_CASES = {
+    "plain": (["1.00", "12.34", "100", "4242.4200"], (), "lazy", 0),
+    "negatives": (["-1.50", "-9999999999.99", "-7"], (), "lazy", 0),
+    "zero": (["0"], (), "lazy", 0),
+    "zero_with_scale": (["0.0000", "1.0"], (), "lazy", 0),
+    "negative_zero": (["-0.00", "-0", "-0.5"], (), "lazy", 0),
+    "one_digit": (["7", "0", "5", "-3"], (), "lazy", 0),
+    "hundreds_of_digits": (["9" * 400 + "." + "1" * 300, "-" + "8" * 500,
+                            "0." + "0" * 200 + "1"], (), "lazy", 0),
+    "nulls": (["1.5", None, "2.5", None, None], (), "lazy", 0),
+    "toast_unchanged": (["1.5", "2.5", "3.5", None], (1, 3), "lazy", 0),
+    "nan": (["1.0", "NaN", "2.0"], (), "lazy", 1),
+    "infinity": (["Infinity", "3"], (), "lazy", 1),
+    "negative_infinity": (["3", "-Infinity"], (), "lazy", 1),
+    "exponent": (["1e5", "1E+2", "2.5e-3", "10"], (), "lazy", 3),
+    "plus_sign": (["+1", "1"], (), "lazy", 1),
+    "leading_zeros": (["007", "00", "00.5", "-01", "0.5", "10"], (),
+                      "lazy", 4),
+    "whitespace": ([" 1", "1 ", "1"], (), "lazy", 2),
+    "bare_point": ([".5", "5.", "-.5", "0.5"], (), "lazy", 3),
+    "underscore_and_unicode_digits": (["1_0", "\u0663", "10"], (), "lazy",
+                                      2),
+    "specials_beside_nulls": ([None, "NaN", None, "-0.10", "+2", None],
+                              (), "lazy", 2),
+    "empty_batch": ([], (), "lazy", 0),
+    "all_null": ([None] * 5, (), "lazy", 0),
+    "sliced_arrow": (["1.00", None, "NaN", "-2.50"], (), "sliced", 3),
+    "object_list": (["1.00", None, "NaN", "-2.50"], (3,), "objects", 2),
+}
+
+
+def _boxed_cells():
+    from etl_tpu.telemetry.metrics import (
+        ETL_CLICKHOUSE_BOXED_CELLS_TOTAL,
+        ETL_CLICKHOUSE_RENDERED_CELLS_TOTAL, registry)
+
+    return (registry.get_counter(ETL_CLICKHOUSE_RENDERED_CELLS_TOTAL),
+            registry.get_counter(ETL_CLICKHOUSE_BOXED_CELLS_TOTAL))
+
+
+@pytest.mark.usefixtures("assembly")
+class TestClickHouseNumericVerbatim:
+    """A NUMERIC column that arrives as the decoder's exact Postgres text
+    is the TSV field as it stands; only a cell whose spelling
+    `PgNumeric.pg_text()` could change is parsed (PR 37)."""
+
+    def _both(self, schema, batch, cdc=False):
+        n = batch.num_rows
+        lsns = np.arange(n, dtype=np.uint64) + 0x3000
+        zeros = np.zeros(n, dtype=np.uint64)
+        seq_buf = sequence_number_buffer(lsns, zeros, zeros)
+        seqs = [s.decode() for s in sequence_number_batch(lsns, zeros,
+                                                          zeros)]
+        labels = ct = "UPSERT"
+        if cdc:
+            ct = change_type_batch(np.array(
+                [int(ChangeType.DELETE) if i % 3 == 2
+                 else int(ChangeType.INSERT) for i in range(n)],
+                dtype=np.int8))
+            labels = [c.decode() for c in ct.tolist()]
+        rendered, boxed = _boxed_cells()
+        fast, used = render_batch_tsv_fast(schema, batch, ct, seq_buf)
+        after = _boxed_cells()
+        assert used is False
+        assert fast == render_batch_tsv_columnar(schema, batch, labels,
+                                                 seqs)
+        assert after[0] - rendered == n * len(batch.columns)
+        return fast, after[1] - boxed
+
+    @pytest.mark.parametrize("case", list(_NUMERIC_CASES))
+    def test_identity_and_boxed_cells(self, case):
+        texts, toast, storage, by_value = _NUMERIC_CASES[case]
+        schema, batch = _numeric_batch(texts, toast, storage)
+        fast, boxed = self._both(schema, batch)
+        assert boxed == by_value
+        fields = [line.split(b"\t")[1] for line in fast.split(b"\n")[:-1]]
+        assert len(fields) == len(texts)
+        for i, (t, f) in enumerate(zip(texts, fields)):
+            if t is None or i in toast:
+                assert f == b"\\N"
+            elif by_value == 0:
+                assert f == t.encode()  # the decoder's bytes, verbatim
+        _, boxed = self._both(schema, batch, cdc=True)
+        assert boxed == by_value
+
+    def test_one_nan_in_a_bulk_boxes_one_cell(self):
+        n = 16_384
+        texts = ["%d.%02d" % (i * 37 % 10_000, i % 100) for i in range(n)]
+        schema, batch = _numeric_batch(texts)
+        _, boxed = self._both(schema, batch)
+        assert boxed == 0
+        texts[9_999] = "NaN"
+        schema, batch = _numeric_batch(texts)
+        fast, boxed = self._both(schema, batch)
+        assert boxed == 1  # not 16,384
+        assert fast.split(b"\n")[9_999].split(b"\t")[1] == b"NaN"
+
+    def test_malformed_text_is_refused_as_before(self):
+        """An empty string or a stray sign is no number: the parse that
+        refuses it today still sees it (by value), on both renders."""
+        from etl_tpu.models.errors import EtlError
+
+        for bad in ("", "-", "1-2", "1.2.3", "--1", "."):
+            schema, batch = _numeric_batch(["1.0", bad])
+            seq_buf = sequence_number_buffer(*[np.zeros(2, np.uint64)] * 3)
+            with pytest.raises(EtlError, match="invalid numeric"):
+                render_batch_tsv_fast(schema, batch, "UPSERT", seq_buf)
+            with pytest.raises(EtlError, match="invalid numeric"):
+                render_batch_tsv_columnar(schema, batch, "UPSERT",
+                                          ["x", "y"])
+
+    def test_the_predicate_against_the_parse(self):
+        """Seeded random cells over the alphabet and beyond it: a row the
+        predicate leaves verbatim re-spells to itself, and every row spelt
+        as `numeric_out` spells is left verbatim."""
+        import random
+        import re
+        from decimal import Decimal
+
+        from etl_tpu.destinations.clickhouse import (_arrow_text_buffers,
+                                                     _numeric_rows_by_value)
+
+        rng = random.Random(37)
+        canonical = re.compile(r"-?(0|[1-9][0-9]*)(\.[0-9]+)?")
+        for alphabet in ("0123456789.-", "0123456789.-+eE Na_"):
+            texts = [None if rng.random() < 0.1 else "".join(
+                rng.choice(alphabet) for _ in range(rng.randint(0, 6)))
+                for _ in range(4_000)]
+            arr = pa.array(texts, type=pa.string())
+            valid = np.array([t is not None for t in texts])
+            by_value = set(_numeric_rows_by_value(
+                *_arrow_text_buffers(arr, len(texts)), valid).tolist())
+            for i, t in enumerate(texts):
+                if t is None:
+                    assert i not in by_value
+                elif canonical.fullmatch(t):
+                    assert i not in by_value, t
+                    assert format(Decimal(t), "f") == t
+                else:
+                    assert i in by_value, t
+
+
 @pytest.mark.usefixtures("assembly")
 class TestSnowflakeNdjsonIdentity:
     def _labels_seqs(self, n):
