@@ -86,11 +86,11 @@ def _load() -> ctypes.CDLL | None:
             ctypes.c_void_p, ctypes.c_int32, ctypes.c_void_p,
             ctypes.c_void_p,  # bad_rows
         ]
-        lib.etl_gather_string.restype = ctypes.c_int64
-        lib.etl_gather_string.argtypes = [
-            ctypes.c_void_p, ctypes.c_int64,
-            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,  # off,len,valid
-            ctypes.c_int64, ctypes.c_int32, ctypes.c_int32,  # R, C, col
+        lib.etl_gather_strings.restype = ctypes.c_int64
+        lib.etl_gather_strings.argtypes = [
+            ctypes.c_void_p, ctypes.c_int64,  # data, data_len
+            ctypes.c_void_p, ctypes.c_int64, ctypes.c_int32,  # off [R,C], R, C
+            ctypes.c_void_p, ctypes.c_int32, ctypes.c_void_p,  # cols, k, lens
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,  # aoff,vals,cap
         ]
         lib.etl_frame_pgoutput.argtypes = [
@@ -497,17 +497,33 @@ def pack_bmat(data, offsets, lengths, col_idx, widths, bmat, lens_out) -> bool:
     return True
 
 
-def gather_string(data, offsets, lengths, valid, col,
-                  arrow_offsets, values) -> int:
-    """C fast path for Arrow string gather; -2 if unavailable."""
+def gather_strings(data, offsets, cols, lens, arrow_offsets, values) -> int:
+    """C fast path for the Arrow gather of k string columns in one call:
+    `offsets` int32 [R, C] contiguous, `cols` int32 [k], `lens` int32 [k,
+    R] (the bytes to take, 0 where none), `arrow_offsets` int32 [k, R + 1]
+    contiguous; the columns' values one after another in `values`, each
+    column's offsets from 0. Returns the bytes written, -1 where `values`
+    is too small or a column is out of range; -2 if unavailable."""
     lib = _load()
     if lib is None:
         return -2
     p = _ptr
+    offsets = np.ascontiguousarray(offsets, dtype=np.int32)
+    cols = np.ascontiguousarray(cols, dtype=np.int32)
+    lens = np.ascontiguousarray(lens, dtype=np.int32)
     R, C = offsets.shape
-    return lib.etl_gather_string(p(data), len(data), p(offsets), p(lengths),
-                                 p(valid), R, C, col, p(arrow_offsets),
-                                 p(values), len(values))
+    if lens.shape != (len(cols), R) \
+            or arrow_offsets.shape != (len(cols), R + 1) \
+            or arrow_offsets.dtype != np.int32 \
+            or not arrow_offsets.flags.c_contiguous \
+            or any(a.dtype != np.uint8 or not a.flags.c_contiguous
+                   for a in (data, values)):
+        raise ValueError(f"gather_strings: lens {lens.shape}, offsets "
+                         f"{arrow_offsets.shape} for {len(cols)} columns "
+                         f"of {R} rows, or buffers not contiguous bytes")
+    return lib.etl_gather_strings(p(data), len(data), p(offsets), R, C,
+                                  p(cols), len(cols), p(lens),
+                                  p(arrow_offsets), p(values), len(values))
 
 
 def pack_bmat_nibble(data, offsets, lengths, col_idx, widths, bmat,

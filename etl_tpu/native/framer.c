@@ -209,29 +209,33 @@ void etl_pack_bmat(const uint8_t *data, int64_t data_len,
     }
 }
 
-/* Gather one string column into Arrow layout: contiguous values + int32
- * offsets[n_rows+1]. valid[r]==0 rows contribute zero bytes. Returns total
- * bytes written, or -1 if it would exceed cap. */
-int64_t etl_gather_string(const uint8_t *data, int64_t data_len,
-                          const int32_t *offsets, const int32_t *lengths,
-                          const uint8_t *valid, int64_t n_rows,
-                          int32_t n_cols, int32_t col,
-                          int32_t *arrow_offsets, uint8_t *values,
-                          int64_t cap) {
+/* Gather k string columns into Arrow layout, one call for all of them:
+ * column j (cols[j] of the n_cols) takes lens[j * n_rows + r] bytes of row r
+ * (0 for a NULL, an unchanged TOAST or a masked row), its values follow
+ * column j-1's in `values`, and its int32 offsets arrow_offsets[j *
+ * (n_rows + 1) ...] start again at 0. Returns total bytes written, or -1
+ * if they would exceed cap or a column lies outside n_cols. */
+int64_t etl_gather_strings(const uint8_t *data, int64_t data_len,
+                           const int32_t *offsets, int64_t n_rows,
+                           int32_t n_cols, const int32_t *cols, int32_t k,
+                           const int32_t *lens, int32_t *arrow_offsets,
+                           uint8_t *values, int64_t cap) {
     int64_t pos = 0;
-    arrow_offsets[0] = 0;
-    for (int64_t r = 0; r < n_rows; r++) {
-        if (valid[r]) {
-            int32_t len = lengths[r * n_cols + col];
+    for (int32_t j = 0; j < k; j++) {
+        int32_t col = cols[j];
+        if (col < 0 || col >= n_cols) return -1;
+        int32_t *ao = arrow_offsets + j * (n_rows + 1);
+        int64_t base = pos;
+        ao[0] = 0;
+        for (int64_t r = 0; r < n_rows; r++) {
+            int32_t len = lens[j * n_rows + r];
             int64_t off = offsets[r * n_cols + col];
             if (len < 0 || off < 0 || off + len > data_len) len = 0;
             if (pos + len > cap) return -1;
-            const uint8_t *src = data + off;
-            uint8_t *dst = values + pos;
-            for (int32_t k = 0; k < len; k++) dst[k] = src[k];
+            if (len) memcpy(values + pos, data + off, (size_t)len);
             pos += len;
+            ao[r + 1] = (int32_t)(pos - base);
         }
-        arrow_offsets[r + 1] = (int32_t)pos;
     }
     return pos;
 }

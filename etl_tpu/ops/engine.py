@@ -672,6 +672,16 @@ class DeviceDecoder:
             for spec in self._dense[250:]:
                 self._object.append(spec)
             self._dense = self._dense[:250]
+        # object columns by how their cells leave a decode: text kinds as
+        # Arrow text, gathered in one pass; the rest parsed value by value
+        self._text = [s for s in self._object
+                      if s.kind in self._LAZY_TEXT_KINDS]
+        self._parsed = [s for s in self._object
+                        if s.kind not in self._LAZY_TEXT_KINDS]
+        # what the CPU fixup parses where the staged bytes are the exact
+        # text (no COPY escapes): the text columns already hold every row
+        self._exact_fixup_cols = sorted(
+            s.index for s in self._dense + self._parsed)
         # column counts for the cell counters (telemetry/metrics.py),
         # taken once here so the increments multiply two integers
         self.n_columns = len(cols)
@@ -1175,46 +1185,54 @@ class DeviceDecoder:
         packed = self._pack_stage(staged, specs, host)
         return self._dispatch_stage(staged, specs, packed, host), packed
 
-    def _gather_string_arrow(self, staged: StagedBatch, spec: _ColSpec,
-                             valid: np.ndarray):
-        """Vectorized scatter-gather of a string column into an Arrow array:
-        no per-row Python objects — the columnar-native fast path."""
+    def _gather_text_arrow(self, staged: StagedBatch, specs: list,
+                           valid: np.ndarray) -> list:
+        """Vectorized scatter-gather of text columns into Arrow arrays, all
+        of them in one native call: no per-row Python objects — the
+        columnar-native fast path. `valid` is bool [C, n_rows]: the rows
+        of each column whose bytes are taken."""
         import pyarrow as pa
 
-        from ..native import gather_string
+        from ..native import gather_strings
 
         n = staged.n_rows
-        lens = np.where(valid[:n], staged.lengths[:n, spec.index], 0)
-        total = int(lens.sum())
-        if total == 0:  # all-null/empty: both buffers must still be defined
-            return pa.StringArray.from_buffers(
-                n, pa.py_buffer(np.zeros(n + 1, dtype=np.int32)),
-                pa.py_buffer(np.zeros(0, dtype=np.uint8)),
-                pa.array(valid[:n]).buffers()[1] if n else None)
-        arrow_offsets = np.empty(n + 1, dtype=np.int32)
+        cols = np.fromiter((s.index for s in specs), np.int32, len(specs))
+        # [k, n]: one column a row, as the gather and Arrow read them
+        ok = valid[cols]
+        lens = np.where(ok, staged.lengths[:n].T[cols], np.int32(0))
+        ends = np.cumsum(lens.sum(axis=1, dtype=np.int64)).tolist()
+        total = ends[-1]
+        arrow_offsets = np.empty((len(specs), n + 1), dtype=np.int32)
         values = np.empty(total, dtype=np.uint8)
-        wrote = gather_string(
-            staged.data, np.ascontiguousarray(staged.offsets[:n]),
-            np.ascontiguousarray(staged.lengths[:n]),
-            np.ascontiguousarray(valid[:n], dtype=np.uint8), spec.index,
-            arrow_offsets, values)
-        if wrote != total:
-            # numpy fallback (no native lib)
-            offs = staged.offsets[:n, spec.index].astype(np.int32)
-            lens32 = lens.astype(np.int32)
-            arrow_offsets[0] = 0
-            np.cumsum(lens32, out=arrow_offsets[1:])
-            if total:
-                starts_rep = np.repeat(offs, lens32)
-                prefix_rep = np.repeat(arrow_offsets[:-1], lens32)
-                idx = np.arange(total, dtype=np.int32)
-                idx -= prefix_rep
-                idx += starts_rep
-                values = staged.data[idx]
-        validity = pa.array(valid[:n]).buffers()[1]
-        # py_buffer over the ndarrays directly — no tobytes() copies
-        return pa.StringArray.from_buffers(
-            n, pa.py_buffer(arrow_offsets), pa.py_buffer(values), validity)
+        wrote = gather_strings(staged.data, staged.offsets[:n], cols, lens,
+                               arrow_offsets, values)
+        bits = np.packbits(ok, axis=1, bitorder="little")
+        whole = ok.all(axis=1).tolist()
+        out = []
+        for j, spec in enumerate(specs):
+            col_values = values[(ends[j - 1] if j else 0):ends[j]]
+            if wrote != total:
+                # numpy fallback (no native lib)
+                offs = staged.offsets[:n, spec.index].astype(np.int32)
+                lens32 = lens[j].astype(np.int32)
+                col_offsets = arrow_offsets[j]
+                col_offsets[0] = 0
+                np.cumsum(lens32, out=col_offsets[1:])
+                col_total = int(col_offsets[-1])
+                if col_total:
+                    starts_rep = np.repeat(offs, lens32)
+                    prefix_rep = np.repeat(col_offsets[:-1], lens32)
+                    idx = np.arange(col_total, dtype=np.int32)
+                    idx -= prefix_rep
+                    idx += starts_rep
+                    col_values = staged.data[idx]
+            # py_buffer over the ndarrays directly — no tobytes() copies;
+            # a column with a value in every row needs no validity bitmap
+            out.append(pa.StringArray.from_buffers(
+                n, pa.py_buffer(arrow_offsets[j]), pa.py_buffer(col_values),
+                None if whole[j] else pa.py_buffer(bits[j]),
+                0 if whole[j] else -1))
+        return out
 
     # object kinds whose Postgres text IS the exact destination form
     # (Arrow/numeric-as-text stance, models/table_row.to_arrow): keep them
@@ -1225,18 +1243,12 @@ class DeviceDecoder:
     })
 
     def _decode_object_column(self, staged: StagedBatch, spec: _ColSpec,
-                              valid: np.ndarray) -> Any:
+                              valid: np.ndarray) -> list:
+        """A host-side kind with no exact text form (bytes, arrays, and
+        device kinds past the packer's 250 columns), parsed value by value
+        in the rows of `valid` (bool [n_rows])."""
         col = self.schema.replicated_columns[spec.index]
         n = staged.n_rows
-        if spec.kind in self._LAZY_TEXT_KINDS:
-            # safe on the COPY path too: stage_copy_chunk routes every row
-            # containing a backslash beyond bare-\N nulls to
-            # cpu_fallback_rows, and the caller masks those out of `valid`
-            # — the remaining rows' raw bytes ARE the exact text (the
-            # per-row Python loop here measured 10× the whole decode)
-            return self._gather_string_arrow(staged, spec, valid)
-        # STRING never reaches here: it is in _LAZY_TEXT_KINDS, so the
-        # Arrow-gather path above always returns first
         out: list[Any] = [None] * n
         offs = staged.offsets[:, spec.index]
         lens = staged.lengths[:, spec.index]
@@ -1248,16 +1260,20 @@ class DeviceDecoder:
         return out
 
     def _cpu_fixup(self, staged: StagedBatch, rows: np.ndarray,
-                   columns: list[Column]) -> None:
-        """Re-decode flagged rows with the CPU oracle and patch columns."""
+                   columns: list[Column], redo) -> int:
+        """Re-decode flagged rows with the CPU oracle and patch the columns
+        at the positions `redo`; returns how many cells it parsed (those
+        neither NULL nor unchanged TOAST)."""
         from ..models.table_row import _to_dense  # late: avoid cycle
         from ..postgres.codec.copy_text import unescape_copy_field
 
         cols = self.schema.replicated_columns
-        for c in columns:
+        for j in redo:
+            c = columns[j]
             if c.is_arrow and rows.size:
-                # rare: fixup needs mutability — densify, PARSING lazy text
-                # so the column's value type stays consistent across rows
+                # COPY escapes: fixup needs mutability — densify, PARSING
+                # lazy text so the column's value type stays consistent
+                # across rows
                 if c.lazy_text_oid is not None:
                     oid = c.lazy_text_oid
                     c.data = [None if v is None else parse_cell_text(v, oid)
@@ -1265,12 +1281,15 @@ class DeviceDecoder:
                     c.lazy_text_oid = None
                 else:
                     c.data = c.data.to_pylist()
+        parsed = 0
         for i in rows:
-            for j, col in enumerate(cols):
+            for j in redo:
+                col = cols[j]
                 c = columns[j]
                 raw = staged.field_bytes(int(i), j)
                 if raw is None:
                     continue
+                parsed += 1
                 if staged.copy_escapes:
                     raw = unescape_copy_field(raw)
                 value = parse_cell_text(raw.decode("utf-8"), col.type_oid)
@@ -1290,6 +1309,7 @@ class DeviceDecoder:
                 else:
                     c.data[i] = value
                 c.validity[i] = value is not None
+        return parsed
 
     def _assemble(self, staged: StagedBatch, specs: tuple, packed_np,
                   bad_rows=None,
@@ -1309,69 +1329,86 @@ class DeviceDecoder:
 
         n = staged.n_rows
         cols = self.schema.replicated_columns
-        valid_full = ~staged.nulls & ~staged.toast
+        # [C, n]: a column's validity is a row of its own, taken once for
+        # the batch, as are the columns that hold an unchanged TOAST cell
+        valid_full = np.ascontiguousarray(
+            ~(staged.nulls[:n] | staged.toast[:n]).T)
+        toast = staged.toast[:n]
+        toasted = toast.any(axis=0).tolist()
 
         columns: list[Column] = [None] * len(cols)  # type: ignore[list-item]
-        fallback = set(int(r) for r in staged.cpu_fallback_rows)
+        # the rows the CPU oracle decodes: one mask for the batch
+        fallback = np.zeros(n, dtype=np.bool_)
+        escaped = staged.cpu_fallback_rows
+        if len(escaped):
+            fallback[escaped[escaped < n]] = True
         if packed_np is None and self._dense:
             # small batch: every row goes to the oracle once; skip the
             # per-column width/ok machinery entirely
-            fallback.update(range(n))
+            fallback[:] = True
         if bad_rows is not None:
             # nibble pack flagged bytes outside the symbol alphabet
-            fallback.update(np.flatnonzero(bad_rows[:n]).tolist())
+            fallback |= bad_rows[:n] != 0
         if packed_np is not None:
             for spec, (_, _, w, _) in zip(self._dense, specs):
                 if staged.max_field_len(spec.index) > w:
-                    too_big = staged.lengths[:n, spec.index] > w
-                    fallback.update(np.flatnonzero(too_big).tolist())
+                    fallback |= staged.lengths[:n, spec.index] > w
 
         pspecs = plan.specs if plan is not None else specs
         layout = layout_for_specs(pspecs) if packed_np is not None else None
         for j, spec in enumerate(self._dense):
-            valid = valid_full[:n, spec.index].copy()
-            toast_col = staged.toast[:n, spec.index]
+            i = spec.index
             if packed_np is None:
                 # small batch: host decode of every row via the oracle
                 data = np.zeros(n, dtype=dense_dtype(spec.kind))
             else:
                 slot = plan.slot_of[j] if plan is not None else j
                 ok, comps = unpack_host(layout, packed_np, slot, n)
-                bad = ~ok & valid
-                if bad.any():
-                    fallback.update(np.flatnonzero(bad).tolist())
+                fallback |= ~ok & valid_full[i]
                 data = _combine(spec.kind, comps)
-            columns[spec.index] = Column(
-                cols[spec.index], data, valid,
-                toast_col if toast_col.any() else None)
+            columns[i] = Column(cols[i], data, valid_full[i],
+                                toast[:, i] if toasted[i] else None)
 
-        for spec in self._object:
-            valid = valid_full[:, spec.index]
-            toast_col = staged.toast[:n, spec.index]
-            data_list = self._decode_object_column(
-                staged, spec,
-                valid & ~np.isin(np.arange(staged.row_capacity),
-                                 list(fallback)) if fallback else valid)
-            lazy_oid = None
-            if spec.kind in self._LAZY_TEXT_KINDS \
-                    and spec.kind is not CellKind.STRING:
-                lazy_oid = cols[spec.index].type_oid
-            columns[spec.index] = Column(
-                cols[spec.index], data_list, valid[:n].copy(),
-                toast_col if toast_col.any() else None,
-                lazy_text_oid=lazy_oid)
+        # Without COPY escapes a text column's staged bytes ARE its exact
+        # text in every row, the fallback rows' too, so it is gathered
+        # whole and the fixup leaves it alone. With them, stage_copy_chunk
+        # routes every row holding a backslash beyond bare-\N nulls to
+        # cpu_fallback_rows: those rows are masked out here and the fixup
+        # unescapes and parses them in every column (the per-row Python
+        # loop measured 10× the whole decode where it took every row).
+        any_fallback = bool(fallback.any())
+        exact = not staged.copy_escapes
+        taken = valid_full & ~fallback if any_fallback else valid_full
+        text = self._gather_text_arrow(
+            staged, self._text, valid_full if exact else taken) \
+            if self._text else []
+        for spec, data in zip(self._text, text):
+            i = spec.index
+            columns[i] = Column(
+                cols[i], data, valid_full[i],
+                toast[:, i] if toasted[i] else None,
+                lazy_text_oid=None if spec.kind is CellKind.STRING
+                else cols[i].type_oid)
+        for spec in self._parsed:
+            i = spec.index
+            columns[i] = Column(
+                cols[i], self._decode_object_column(staged, spec, taken[i]),
+                valid_full[i], toast[:, i] if toasted[i] else None)
 
         from ..telemetry.metrics import (
+            ETL_DECODE_FIXUP_CELLS_TOTAL,
             ETL_DEVICE_DECODE_FALLBACK_ROWS_TOTAL, registry)
 
         rows_arr = np.zeros(0, dtype=np.int64)
-        if fallback:
-            rows_arr = np.asarray(sorted(r for r in fallback if r < n),
-                                  dtype=np.int64)
-            self._cpu_fixup(staged, rows_arr, columns)
+        if any_fallback:
+            rows_arr = np.flatnonzero(fallback).astype(np.int64, copy=False)
+            parsed = self._cpu_fixup(staged, rows_arr, columns,
+                                     self._exact_fixup_cols if exact
+                                     else range(len(cols)))
             if self._telemetry:
                 registry.counter_inc(ETL_DEVICE_DECODE_FALLBACK_ROWS_TOTAL,
                                      len(rows_arr))
+                registry.counter_inc(ETL_DECODE_FIXUP_CELLS_TOTAL, parsed)
         return ColumnarBatch(self.schema, columns), rows_arr
 
     def _shard_health(self, shard_bad) -> None:

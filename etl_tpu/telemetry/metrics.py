@@ -40,6 +40,10 @@ ETL_TABLES_ERRORED = "etl_tables_errored"
 ETL_DEVICE_DECODE_ROWS_TOTAL = "etl_device_decode_rows_total"
 ETL_DEVICE_DECODE_FALLBACK_ROWS_TOTAL = \
     "etl_device_decode_fallback_rows_total"
+# cells the CPU fixup of a decode (ops/engine._cpu_fixup) parsed value by
+# value — neither NULL nor unchanged TOAST —, on every route: one increment
+# a call, none a row
+ETL_DECODE_FIXUP_CELLS_TOTAL = "etl_decode_fixup_cells_total"
 # fused publication row filtering (ops/predicate.py + the fused decode
 # program): rows the predicate compacted out of decode output, the bytes
 # the packed-result fetch actually moved over the device→host link

@@ -18,7 +18,7 @@ hammer it with
      target — random piece tables, views and overrides through
      etl_assemble_rows, random integers through etl_int_text_fixed),
   2. the full differential test file (tests/test_native_framer.py), which
-     also exercises etl_pack_bmat / etl_gather_string / nibble packing, and
+     also exercises etl_pack_bmat / etl_gather_strings / nibble packing, and
   3. a direct hammer of the pack/gather entry points, of
      etl_stage_copy_chunk with outputs smaller than its rows, and of
      etl_assemble_rows with tables that lie about their bytes and outputs
@@ -162,7 +162,7 @@ def main(argv=None) -> int:
 
 
 def hammer(seconds: float, seed: int | None) -> int:
-    """Child mode: randomized pack_bmat / pack_bmat_nibble / gather_string
+    """Child mode: randomized pack_bmat / pack_bmat_nibble / gather_strings
     calls over fuzz-framed batches, including adversarial gather widths and
     fields ending at the exact buffer boundary; then etl_stage_copy_chunk
     called past its binding, with outputs of fewer rows than the chunk has
@@ -225,16 +225,20 @@ def hammer(seconds: float, seed: int | None) -> int:
                                     np.array(widths, np.int32),
                                     np.zeros((R, nib_tw), np.uint8),
                                     lens_out, bad_rows)
-        # string gather with deliberately small capacity (must truncate,
-        # not overflow) and full capacity
+        # string gather of some columns, one out of range among them at
+        # times, with deliberately small capacity (must stop, not
+        # overflow) and full capacity
         for cap in (3, 1 << 16):
-            col = rng.randrange(n_cols)
-            valid = (framed.new_flag[:, col] == native.FLAG_VALUE) \
-                .astype(np.uint8)
-            aoff = np.zeros(R + 1, dtype=np.int32)
+            cols = np.array([rng.randrange(-1, n_cols + 1) if rng.random()
+                             < 0.1 else rng.randrange(n_cols)
+                             for _ in range(rng.randint(1, 4))], np.int32)
+            safe = np.clip(cols, 0, n_cols - 1)
+            lens = np.where(framed.new_flag[:, safe] == native.FLAG_VALUE,
+                            framed.new_len[:, safe], 0).T.astype(np.int32)
+            aoff = np.zeros((len(cols), R + 1), dtype=np.int32)
             vals = np.zeros(cap, dtype=np.uint8)
-            native.gather_string(data, framed.new_off, framed.new_len,
-                                 valid, col, aoff, vals)
+            native.gather_strings(data, framed.new_off, cols, lens, aoff,
+                                  vals)
         # the chunk scan may write max_rows rows and not one more: exact-
         # size outputs put the sanitizer's red zone right behind them
         chunk = b"".join(

@@ -462,8 +462,9 @@ def _numeric_batch(texts, toast=(), storage="lazy"):
     """(pk, num, s) with the NUMERIC column as the decoder hands it over:
     `lazy` — its exact Postgres text in an Arrow array with
     `lazy_text_oid` set; `sliced` — the same behind a non-zero Arrow
-    offset; `objects` — a list of parsed values, as the per-row oracle
-    and `_cpu_fixup` leave it. `toast` rows are unchanged-TOAST cells."""
+    offset; `objects` — a list of parsed values, as `_cpu_fixup` leaves
+    it after a COPY chunk's escaped rows. `toast` rows are unchanged-TOAST
+    cells."""
     from etl_tpu.models.table_row import Column
     from etl_tpu.postgres.codec.text import parse_cell_text
 

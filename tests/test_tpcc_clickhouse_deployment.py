@@ -313,24 +313,19 @@ def _edge_rows(deployment, name):
                    pgoutput.encode_delete(tid, rows[2])], rows
 
 
-def _boxed_cells(table, rows, route) -> int:
-    """The cells of `rows` that `_column_piece_tsv` renders value by value:
-    of a NUMERIC or a text column every cell that is not NULL where the
-    batch came from the per-row oracle (a run under
-    `DeviceDecoder.HOST_MIN_ROWS`: its NUMERIC columns hold parsed values
-    and its text columns are no Arrow arrays); from the host program no
-    NUMERIC cell spelt as `numeric_out` spells (every one here), and of
-    the text cells those of a column in which some text needs a TSV
-    escape."""
+def _boxed_cells(table, rows) -> int:
+    """The cells of `rows` that `_column_piece_tsv` renders value by value,
+    whichever route decoded them — the per-row oracle of a run under
+    `DeviceDecoder.HOST_MIN_ROWS` keeps NUMERIC and text columns as the
+    decoder's Arrow text, as the host program does: no NUMERIC cell spelt
+    as `numeric_out` spells (every one here), and of the text cells those
+    of a column in which some text needs a TSV escape."""
     boxed = 0
     for j, c in enumerate(table["columns"]):
         cells = [r[j] for r in rows if r[j] is not None]
-        if c["type"] == "numeric":
-            boxed += len(cells) if route == "oracle" else 0
-        elif c["type"] in ("bpchar", "varchar"):
-            if route == "oracle" or any(
-                    ch in v for v in cells for ch in b"\t\n\r\\"):
-                boxed += len(cells)
+        if c["type"] in ("bpchar", "varchar") and any(
+                ch in v for v in cells for ch in b"\t\n\r\\"):
+            boxed += len(cells)
     return boxed
 
 
@@ -342,18 +337,20 @@ def test_edge_rows_render_byte_identical(deployment, name, route, times):
     host program does, as in a coalesced flush)."""
     table, payloads, texts = _edge_rows(deployment, name)
     payloads, texts = payloads * times, texts * times
+    assert (len(payloads) < DeviceDecoder.HOST_MIN_ROWS) \
+        == (route == "oracle")
     batch, change_types = _decoded(table, payloads)
     names = [c["name"] for c in table["columns"]]
     text_column = batch.columns[[c["type"] for c in table["columns"]]
                                 .index("bpchar")]
-    assert text_column.is_arrow == (route == "host")
+    assert text_column.is_arrow
     rendered, boxed = _cells()
     fast, plain = _both_renders(table, batch, change_types)
     assert fast == plain
     lines = [line.split(b"\t") for line in fast.split(b"\n")[:-1]]
     after = _cells()
     assert after[0] - rendered == len(payloads) * len(names)
-    assert after[1] - boxed == _boxed_cells(table, texts, route)
+    assert after[1] - boxed == _boxed_cells(table, texts)
     at = names.index
     if name == "customer":
         assert lines[0][at("c_credit_lim")] == b"9999999999.99"
@@ -597,11 +594,11 @@ def test_the_cell_rehearses_correct_with_its_metrics(tmp_path):
             "drain_dispatch_blocked_pct", "pipeline_ready_s"):
         assert "rehearsal." + name in m, sorted(m)
     assert "rehearsal.tpcc_table_switch_seal_share_pct" not in m
-    # no NUMERIC cell of the host program's batches goes value by value
-    # (PR 37): what is left is a small run's NUMERIC and text cells, which
-    # the per-row oracle decoded — next to none where a rehearsal's
-    # flushes are large, a quarter of the cells on a loaded machine
-    assert 0 <= m["rehearsal.ch_boxed_cell_share_pct"] < 100
+    # no NUMERIC cell spelt as `numeric_out` spells goes value by value,
+    # and every route — the per-row oracle's small runs too — hands its
+    # NUMERIC and text columns over as Arrow text: what could still box is
+    # a text that needs a TSV escape, which the generator never draws
+    assert 0 <= m["rehearsal.ch_boxed_cell_share_pct"] < 0.05
     # a flush is one INSERT per table it holds rows of: more than the one
     # of a single-table stream, at most the eight published tables
     # (ROADMAP C16: a flush that holds two sealed groups sends more)

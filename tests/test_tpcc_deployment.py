@@ -174,8 +174,9 @@ def test_the_cell_is_in_the_benchmark_s_lists():
     assert drains <= listed
     assert {"compiles_in_window", "pipeline_ready_s"} <= listed
     mine = sorted(n for n in listed if n.startswith("tpcc_"))
-    # PR 33's seven and PR 38's two CPU twins (seal, the oracle's unpack)
-    assert len(mine) == 9
+    # the cell's seven, two CPU twins (seal, the oracle's unpack) and the
+    # cells the fixup parses per oracle row
+    assert len(mine) == 10
     for name in mine:
         with open(os.path.join(BENCH, "metrics", name + ".json")) as f:
             assert json.load(f)["reader"] == "counter_ratio"
@@ -575,3 +576,8 @@ def test_the_cell_rehearses_correct_with_its_metrics(tmp_path):
                  "drain_rows_per_seal_p50", "drain_dispatch_blocked_pct"):
         assert "rehearsal." + name in m, sorted(m)
     assert m["rehearsal.drain_rows_per_seal_p50"] > 1
+    # the per-row oracle parses the dense cells of its rows alone: no
+    # TPC-C table has more than eight int4 / timestamp columns (where the
+    # rehearsal's flushes sent no run to the oracle the metric is absent)
+    fixup = m.get("rehearsal.tpcc_fixup_cells_per_oracle_row_mean")
+    assert fixup is None or 0 < fixup <= 8
