@@ -21,8 +21,14 @@ type has a width. Its `generator` names the file of `deployments/` (absent:
 Column values by type: `bool` bool; `int2`/`int4`/`int8` integers; `float8`
 float64; `numeric` int64 scaled by the column's `scale`; `date` int32 days
 since 1970-01-01; `timestamp`/`timestamptz` int64 microseconds since
-1970-01-01 (UTC); `bpchar`/`varchar`/`text` numpy `S` arrays (a `bpchar`
-value without its padding), or one `bytes` value that every row shares.
+1970-01-01 (UTC); `bpchar`/`varchar`/`text`/`json`/`jsonb` numpy `S`
+arrays of the server's text (a `bpchar` value without its padding), one
+`bytes` value that every row shares, or `Coded`: int32 codes into a pool of
+distinct values held once, for text too wide to pad every row to the widest.
+
+`COMPARISONS` is the one place that says how the plain reference compares a
+column, by its type; a configuration with a type it lacks is refused when
+its tables are read.
 """
 
 from __future__ import annotations
@@ -38,7 +44,17 @@ WAL_STEP = 8
 BASE_LSN = 0x0100_0000  # where the source's WAL starts (slots are made here)
 INSERT, UPDATE, DELETE = ord("I"), ord("U"), ord("D")
 DEFAULT_GENERATOR = "pgbench_accounts"
-TEXT_TYPES = ("bpchar", "varchar", "text")
+# how the reference compares a column's values, by the column's type:
+#   typed    the generator's numbers exactly (a float8 bit for bit)
+#   numeric  as decimals
+#   text     byte for byte (bpchar with the server's padding)
+#   json     as JSON values: objects as unordered maps, arrays in order,
+#            numbers as exact decimals, JSON null apart from SQL NULL
+COMPARISONS = {
+    "bool": "typed", "int2": "typed", "int4": "typed", "int8": "typed",
+    "float8": "typed", "date": "typed", "timestamp": "typed",
+    "timestamptz": "typed", "numeric": "numeric", "bpchar": "text",
+    "varchar": "text", "text": "text", "json": "json", "jsonb": "json"}
 
 
 # ---------------------------------------------------------------------------
@@ -48,9 +64,30 @@ TEXT_TYPES = ("bpchar", "varchar", "text")
 
 def tables_of(config: dict) -> list:
     """The published tables of a configuration, in the order the log's
-    `table` indices count them."""
-    return list(config["tables"]) if "tables" in config \
+    `table` indices count them. A column whose type has no comparison is
+    refused here, by name, before anything renders or runs."""
+    tables = list(config["tables"]) if "tables" in config \
         else [config["table"]]
+    for table in tables:
+        for column in table.get("columns", ()):
+            if column["type"] not in COMPARISONS:
+                raise ValueError(
+                    f"{table['name']}.{column['name']}: type "
+                    f"{column['type']!r} has no comparison in the benchmark "
+                    f"(oplog.COMPARISONS: {', '.join(sorted(COMPARISONS))})")
+    return tables
+
+
+def comparison(column: dict) -> str:
+    """How the reference compares this column: `typed`, `numeric`, `text`
+    or `json` (COMPARISONS)."""
+    return COMPARISONS[column["type"]]
+
+
+def as_text(column: dict) -> bool:
+    """Whether the generator holds this column's values as their text:
+    `S` arrays, one shared `bytes` value, or `Coded`."""
+    return COMPARISONS[column["type"]] in ("text", "json")
 
 
 def key_indices(table: dict) -> list:
@@ -91,6 +128,61 @@ def load_generator(config: dict, config_path: str):
 # ---------------------------------------------------------------------------
 
 
+class Pool:
+    """Distinct text values, held once, with what is made of them once per
+    pool (a padded or escaped copy, their JSON spellings) kept beside."""
+
+    def __init__(self, values):
+        self.values = tuple(bytes(v) for v in values)
+        self.lengths = np.fromiter(map(len, self.values), dtype=np.int64,
+                                   count=len(self.values))
+        self._made: dict = {}
+
+    def __len__(self) -> int:
+        return len(self.values)
+
+    def made(self, key, fn):
+        """`fn(self)`, made once for this pool under `key`."""
+        if key not in self._made:
+            self._made[key] = fn(self)
+        return self._made[key]
+
+
+class Coded:
+    """Text values as int32 codes into a `Pool`: row i holds
+    `pool.values[codes[i]]`. A row costs 4 bytes, whatever the widths, so a
+    log of wide values (out-of-line TOAST text) is not padded to the widest
+    row. Picking rows picks codes; a renderer or the reference makes the
+    text of the rows at hand only. `np.asarray` gives the padded `S` array
+    (a key's few bytes; never a wide column's)."""
+
+    __slots__ = ("codes", "pool")
+
+    def __init__(self, codes, pool):
+        self.codes = np.asarray(codes, dtype=np.int32)
+        self.pool = pool if isinstance(pool, Pool) else Pool(pool)
+
+    def __len__(self) -> int:
+        return len(self.codes)
+
+    def __getitem__(self, rows) -> "Coded":
+        return Coded(self.codes[rows], self.pool)
+
+    def __array__(self, dtype=None, copy=None):
+        padded = self.pool.made("S", lambda p: np.array(
+            p.values or [b""], dtype="S"))
+        out = padded[self.codes]
+        return out if dtype is None else out.astype(dtype)
+
+    def __eq__(self, other):
+        return np.asarray(self) == np.asarray(other)
+
+    def __ne__(self, other):
+        return np.asarray(self) != np.asarray(other)
+
+    __hash__ = None
+
+
 @dataclass
 class Col:
     """One column of some rows. `null[i]`: row i holds NULL there;
@@ -114,7 +206,7 @@ def n_rows(cols: list) -> int:
     shared `bytes` value has to carry a mask to have a length)."""
     for c in cols:
         for a in (c.values, c.null, c.unchanged):
-            if isinstance(a, np.ndarray):
+            if isinstance(a, (np.ndarray, Coded)):
                 return len(a)
     return 0
 

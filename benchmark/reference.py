@@ -20,10 +20,14 @@ exact:
   wrong_rows          a row of `D` that differs from its event: the kind of
                       change, any column of the new image, the old or key
                       image, NULL where a value was sent or a value where
-                      NULL was. Integers, dates and timestamps compare
-                      exactly, text byte for byte, NUMERIC as decimals, a
-                      float8 bit for bit. An unchanged-TOAST cell may arrive
-                      marked unchanged or with the value it had
+                      NULL was. Each column compares as `oplog.COMPARISONS`
+                      says by its type: integers, dates and timestamps
+                      exactly, a float8 bit for bit, text byte for byte,
+                      NUMERIC as decimals, JSON as JSON values (objects as
+                      unordered maps, arrays in order, numbers as exact
+                      decimals; JSON null is not SQL NULL). An
+                      unchanged-TOAST cell may arrive marked unchanged or
+                      with the value it had
   state_mismatch_rows keys whose final state differs: replaying `D` in
                       delivery order over the snapshot (upsert by key,
                       delete by key, an update that moves a row deletes its
@@ -40,12 +44,13 @@ it. Copy rows carry no coordinates and are matched by primary key.
 
 from __future__ import annotations
 
+import json
 from decimal import Decimal
 
 import numpy as np
 
-from oplog import (DELETE, INSERT, TEXT_TYPES, UPDATE, char_width,
-                   key_indices, n_rows)
+from oplog import (DELETE, INSERT, UPDATE, Coded, as_text, char_width,
+                   comparison, key_indices, n_rows)
 
 LIMITS = {"missing_rows": 0, "wrong_rows": 0, "unknown_rows": 0,
           "misattributed_rows": 0, "state_mismatch_rows": 0}
@@ -76,9 +81,91 @@ def _expected_text(column: dict, values, rows, n: int):
         return values.decode().ljust(width or 0)
     if not n:
         return pa.array([], type=pa.string())
+    if isinstance(values, Coded):
+        # the text of the rows at hand, each distinct value decoded once
+        picked = values if rows is None else values[rows]
+        pool = picked.pool.made(("text", width), lambda p: pa.array(
+            [v.decode().ljust(width or 0) for v in p.values],
+            type=pa.string()))
+        return pa.DictionaryArray.from_arrays(
+            pa.array(picked.codes, type=pa.int32()), pool).dictionary_decode()
     picked = np.char.decode(values if rows is None else values[rows], "utf-8")
     return pa.array(np.char.ljust(picked, width) if width else picked,
                     type=pa.string())
+
+
+# ---------------------------------------------------------------------------
+# JSON by value
+# ---------------------------------------------------------------------------
+
+NOT_JSON = "\x00not JSON: "  # no JSON spelling starts with a NUL
+
+
+def json_spelling(value) -> str:
+    """The one spelling of a parsed JSON value, equal exactly where the
+    values are: objects with their keys sorted, arrays in order, strings
+    as JSON strings, numbers as exact decimals without trailing zeros (so
+    1.50, 1.5 and 15e-1 spell alike, and -0 as 0). `None` is JSON null;
+    a float is taken at its shortest repr."""
+    if value is None:
+        return "null"
+    if value is True or value is False:
+        return "true" if value else "false"
+    if isinstance(value, str):
+        return json.dumps(value)
+    if isinstance(value, (int, float, Decimal)):
+        return _json_number(Decimal(repr(value)) if isinstance(value, float)
+                            else Decimal(value))
+    if isinstance(value, (list, tuple)):
+        return "[" + ",".join(map(json_spelling, value)) + "]"
+    if isinstance(value, dict):
+        return "{" + ",".join(json.dumps(k) + ":" + json_spelling(value[k])
+                              for k in sorted(value)) + "}"
+    return NOT_JSON + type(value).__name__
+
+
+def _json_number(d: Decimal) -> str:
+    if not d.is_finite():
+        return NOT_JSON + str(d)
+    sign, digits, exp = d.as_tuple()
+    text = "".join(map(str, digits))
+    kept = text.rstrip("0")
+    if not kept:
+        return "0"
+    return ("-" if sign else "") + kept + "e" + str(exp + len(text)
+                                                     - len(kept))
+
+
+def _no_constant(name: str):
+    raise ValueError(name)
+
+
+def json_text_spelling(text) -> str:
+    """`json_spelling` of a JSON text (str or UTF-8 bytes); NOT_JSON and
+    the text where it is none."""
+    try:
+        return json_spelling(json.loads(
+            text, parse_float=Decimal, parse_int=Decimal,
+            parse_constant=_no_constant))
+    except (ValueError, RecursionError):
+        return NOT_JSON + (text if isinstance(text, str)
+                           else text.decode("utf-8", "replace"))
+
+
+def _expected_json(values, rows) -> np.ndarray:
+    """object[n]: the JSON spelling of each generator text at hand, each
+    distinct text read once."""
+    if isinstance(values, bytes):
+        return json_text_spelling(values)
+    if isinstance(values, Coded):
+        picked = values if rows is None else values[rows]
+        spelt = picked.pool.made("json", lambda p: np.array(
+            [json_text_spelling(v) for v in p.values], dtype=object))
+        return spelt[picked.codes]
+    picked = values if rows is None else values[rows]
+    distinct, inverse = np.unique(picked, return_inverse=True)
+    return np.array([json_text_spelling(v) for v in distinct.tolist()],
+                    dtype=object)[inverse.reshape(-1)]
 
 
 def _differs(column: dict, got, expected, rows, n: int) -> np.ndarray:
@@ -88,7 +175,8 @@ def _differs(column: dict, got, expected, rows, n: int) -> np.ndarray:
     want_null = expected.null if rows is None or expected.null is None \
         else expected.null[rows]
     kind = column["type"]
-    if kind in TEXT_TYPES:
+    how = comparison(column)
+    if how == "text":
         import pyarrow as pa
         import pyarrow.compute as pc
 
@@ -100,7 +188,16 @@ def _differs(column: dict, got, expected, rows, n: int) -> np.ndarray:
             ne = pc.fill_null(ne, True)
         bad = ne.to_numpy(zero_copy_only=False).astype(bool, copy=False) \
             if n else np.zeros(0, dtype=bool)
-    elif kind == "numeric":
+    elif how == "json":
+        # the sink's values as JSON spellings (`json_spelling`), None for
+        # a NULL: equal spellings, equal values
+        want = _expected_json(expected.values, rows)
+        have = values.to_pylist() if hasattr(values, "to_pylist") \
+            else list(values)
+        bad = np.fromiter((h != w for h, w in zip(have, np.broadcast_to(
+            np.asarray(want, dtype=object), (n,)).tolist())),
+            dtype=bool, count=n)
+    elif how == "numeric":
         scale = int(column.get("scale", 0))
         want = expected.values if rows is None else expected.values[rows]
         bad = np.fromiter(
@@ -151,7 +248,7 @@ def _key_numbers(table: dict, col_sets: list) -> list:
     parts = []
     for j, i in enumerate(key_indices(table)):
         arrays = [np.asarray(cols[j]) for cols in col_sets]
-        if table["columns"][i]["type"] in TEXT_TYPES \
+        if as_text(table["columns"][i]) \
                 or arrays[0].dtype.kind not in "iub":
             flat = np.concatenate([a.astype("S") if a.dtype.kind != "S"
                                    else a for a in arrays] or [[]])
@@ -215,7 +312,7 @@ class SnapshotIndex:
             return idx
         if not self.n:
             return np.full(len(got_key_cols[0]), -1, dtype=np.int64)
-        got = [_text_key(v) if self.table["columns"][i]["type"] in TEXT_TYPES
+        got = [_text_key(v) if as_text(self.table["columns"][i])
                else np.asarray(v) for i, v in zip(self.keys, got_key_cols)]
         mine, theirs = _key_numbers(self.table, [self.key_cols, got])
         order = np.argsort(mine, kind="stable")
@@ -399,8 +496,11 @@ def _merge(new, old, rows: np.ndarray, use_old: np.ndarray):
     a, b = new.pick(rows), old.pick(rows)
     if isinstance(a.values, bytes) or isinstance(b.values, bytes):
         values = a.values  # one value shared by every row, old or new
+    elif isinstance(a.values, Coded) and isinstance(b.values, Coded):
+        values = _coded_where(use_old, b.values, a.values)
     else:
-        values = np.where(use_old, b.values, a.values)
+        values = np.where(use_old, np.asarray(b.values),
+                          np.asarray(a.values))
 
     def mask(x, y):
         if x is None and y is None:
@@ -410,6 +510,14 @@ def _merge(new, old, rows: np.ndarray, use_old: np.ndarray):
                         zeros if x is None else x)
 
     return Col(values, mask(a.null, b.null), mask(a.unchanged, None))
+
+
+def _coded_where(cond: np.ndarray, x: Coded, y: Coded) -> Coded:
+    """`x` where `cond`, `y` elsewhere, still coded."""
+    if x.pool is y.pool:
+        return Coded(np.where(cond, x.codes, y.codes), x.pool)
+    return Coded(np.where(cond, x.codes, y.codes + len(x.pool)),
+                 x.pool.values + y.pool.values)
 
 
 def _old_images_differ(table, rows_got, at, ev, ev_at, op, kinds_at):
@@ -463,7 +571,7 @@ def _state_mismatches(table: dict, snap_cols, ev, mine, op_all, rows_got,
     op = op_all[events] if mine is not None else op_all[:upto]
 
     def plain(cols, rows):
-        return [_text_key(v) if table["columns"][i]["type"] in TEXT_TYPES
+        return [_text_key(v) if as_text(table["columns"][i])
                 else np.asarray(v)
                 for i, v in ((i, _take(cols[i][0], rows)) for i in keys)]
 

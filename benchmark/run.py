@@ -383,9 +383,12 @@ def _null_destination_class():
 def _plain_columns(table: dict, batches: list) -> list:
     """The kept columns of some batches of one table as (values, null,
     unchanged) in plain types: numpy for typed columns, a pyarrow string
-    array for text, the server's text for NUMERIC."""
+    array for text, the server's text for NUMERIC, the JSON spelling
+    (`reference.json_spelling`) for JSON."""
     import numpy as np
     import pyarrow as pa
+
+    import oplog
 
     out = []
     for i, column in enumerate(table["columns"]):
@@ -394,7 +397,9 @@ def _plain_columns(table: dict, batches: list) -> list:
         toast = np.concatenate([
             k[2] if k[2] is not None else np.zeros(len(k[1]), dtype=bool)
             for k in kept]) if any(k[2] is not None for k in kept) else None
-        if all(isinstance(k[0], np.ndarray) for k in kept):
+        if oplog.comparison(column) == "json":
+            values = _json_spellings(kept)
+        elif all(isinstance(k[0], np.ndarray) for k in kept):
             values = np.concatenate([k[0] for k in kept])
         elif column["type"] == "numeric" or any(
                 not isinstance(k[0], (pa.Array, pa.ChunkedArray))
@@ -414,6 +419,27 @@ def _plain_columns(table: dict, batches: list) -> list:
         null = None if valid.all() else \
             ~valid if toast is None else ~valid & ~toast
         out.append((values, null, toast))
+    return out
+
+
+def _json_spellings(kept: list) -> list:
+    """The JSON spelling of every kept cell of a JSON column, None where
+    there is no value: Arrow data is the server's text; anything else the
+    values the host parsed, where a string is a JSON string."""
+    import pyarrow as pa
+
+    import reference
+    from etl_tpu.models import JSON_NULL
+
+    out = []
+    for data, ok, _, _ in kept:
+        items = data.to_pylist() if hasattr(data, "to_pylist") \
+            else list(data)
+        as_text = isinstance(data, (pa.Array, pa.ChunkedArray))
+        out += [None if v is None or not good
+                else reference.json_text_spelling(v) if as_text
+                else reference.json_spelling(None if v is JSON_NULL else v)
+                for v, good in zip(items, ok.tolist())]
     return out
 
 

@@ -160,14 +160,16 @@ def _parse_tsv(table: dict, body: bytes) -> dict:
     for c in columns:
         col = tab[c["name"]].combine_chunks()
         null = col.is_null().to_numpy(zero_copy_only=False)
-        kind = c["type"]
-        if kind in reference.TEXT_TYPES:
+        kind, how = c["type"], oplog.comparison(c)
+        if how in ("text", "json"):
             if pc.any(pc.match_substring(col, "\\")).as_py():
                 col = pa.array([None if v is None else re.sub(
                     r"\\[\\tnr]", lambda m: _TSV_UNESCAPE[m.group(0)], v)
                     for v in col.to_pylist()], type=pa.string())
-            values = col
-        elif kind == "numeric" or kind not in arrow_type:
+            values = col if how == "text" else [
+                None if v is None else reference.json_text_spelling(v)
+                for v in col.to_pylist()]
+        elif how == "numeric":
             values = col.to_pylist()
         elif kind == "bool":
             values = pc.equal(col, "true").fill_null(False).to_numpy(

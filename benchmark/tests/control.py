@@ -29,6 +29,17 @@ answer altered in what the null sink holds, each caught by the number named:
   other_table       a row claims the coordinates of the other table's
                     event (misattributed_rows)
 
+and for the wide fixture (`docs-*.json` of `tests/data/`, JSON columns and
+an out-of-line TOAST body):
+
+  json_value_changed  one delivered JSON value is another valid one
+                      (wrong_rows)
+  toast_as_null       an unchanged-TOAST cell arrives as NULL (wrong_rows)
+  json_reserialised   every JSON value the sink keeps is spelled again
+                      with other spacing and key order: the same values,
+                      so the run stays `correct: true` — the comparison
+                      is by value
+
 On the chip it is run at the cell's own size on three seeds or more
 (PERF.md); `test_control.py` keeps it at rehearsal size.
 """
@@ -45,7 +56,9 @@ import run as harness  # noqa: E402
 
 FAULTS = ("ack_and_drop", "half_batch", "altered_value", "update_dropped",
           "delete_as_upsert", "updates_swapped", "numeric_digit",
-          "null_to_empty", "other_table")
+          "null_to_empty", "other_table", "json_value_changed",
+          "toast_as_null", "json_reserialised")
+JSON_OIDS = (114, 3802)
 
 
 def plant(fault: str) -> None:
@@ -100,6 +113,18 @@ def plant(fault: str) -> None:
             return batch
 
         DeviceDecoder._complete = altered
+    elif fault == "json_reserialised":
+        cls = type(harness.make_null_destination())
+        columns = cls._columns
+
+        def respelt(batch):
+            out = columns(batch)
+            for i, c in enumerate(batch.columns):
+                if c.schema.type_oid in JSON_OIDS:
+                    out[i] = _respelt(*out[i])
+            return out
+
+        cls._columns = staticmethod(respelt)
     elif fault in ALTER:
         cls = type(harness.make_null_destination())
         received = cls.received
@@ -123,14 +148,18 @@ def _reorder(rows: dict, order) -> None:
     """Deliver one table's rows in another order (or only some of them)."""
     import numpy as np
 
+    def taken(a, rows):
+        if isinstance(a, np.ndarray):
+            return a[rows]
+        listed = _listed(a)
+        return [listed[i] for i in rows]
+
     order = np.asarray(order)
     for k in ("change", "commit_lsn", "tx_ordinal", "delete_is_key"):
         if rows.get(k) is not None:
             rows[k] = rows[k][order]
-    rows["cols"] = [tuple(None if a is None else (
-        a[order] if isinstance(a, np.ndarray)
-        else [_listed(a)[i] for i in order.tolist()]) for a in c)
-        for c in rows["cols"]]
+    rows["cols"] = [tuple(None if a is None else taken(a, order.tolist())
+                          for a in c) for c in rows["cols"]]
     if rows.get("old") is not None:
         place = {int(r): i for i, r in enumerate(order.tolist())}
         held = [j for j, r in enumerate(rows["old"]["rows"].tolist())
@@ -139,10 +168,9 @@ def _reorder(rows: dict, order) -> None:
             [place[int(rows["old"]["rows"][j])] for j in held],
             dtype=np.int64)
         rows["old"]["is_key"] = rows["old"]["is_key"][held]
-        rows["old"]["cols"] = [tuple(None if a is None else (
-            a[held] if isinstance(a, np.ndarray)
-            else [_listed(a)[j] for j in held]) for a in c)
-            for c in rows["old"]["cols"]]
+        rows["old"]["cols"] = [tuple(None if a is None else taken(a, held)
+                                     for a in c)
+                               for c in rows["old"]["cols"]]
 
 
 def _update_dropped(tables, got) -> None:
@@ -214,11 +242,75 @@ def _other_table(tables, got) -> None:
     mine["tx_ordinal"][0] = theirs["tx_ordinal"][0]
 
 
+def _respelt(data, valid, toast, lazy) -> tuple:
+    """A JSON column's kept cells as JSON text spelled another way: keys in
+    reverse order, other spacing, non-ASCII unescaped."""
+    import json
+
+    import pyarrow as pa
+
+    from etl_tpu.models import JSON_NULL
+
+    def turned(v):
+        if isinstance(v, dict):
+            return {k: turned(v[k]) for k in sorted(v, reverse=True)}
+        if isinstance(v, list):
+            return [turned(x) for x in v]
+        return None if v is JSON_NULL else v
+
+    text = isinstance(data, (pa.Array, pa.ChunkedArray))
+    texts = [None if v is None or not ok else json.dumps(
+        turned(json.loads(v) if text else v), indent=1,
+        separators=(" ,", " :  "), ensure_ascii=False)
+        for v, ok in zip(_listed(data), valid.tolist())]
+    return pa.array(texts, type=pa.string()), valid, toast, lazy
+
+
+def _json_cells(tables, got):
+    """The cdc rows and the index of the first JSON column of the first
+    table that has one."""
+    import oplog
+
+    for table in tables:
+        for i, c in enumerate(table["columns"]):
+            if oplog.comparison(c) == "json":
+                return got[int(table["id"])]["cdc"], i
+    raise SystemExit("no JSON column in the configuration")
+
+
+def _json_value_changed(tables, got) -> None:
+    rows, i = _json_cells(tables, got)
+    values, null, toast = rows["cols"][i]
+    values = _listed(values)
+    at = next(k for k, v in enumerate(values) if v is not None)
+    values[at] = "[" + values[at] + "]"  # still JSON, another value
+    rows["cols"][i] = (values, null, toast)
+
+
+def _toast_as_null(tables, got) -> None:
+    import numpy as np
+
+    for table in tables:
+        rows = got[int(table["id"])]["cdc"]
+        for i, (values, null, toast) in enumerate(rows["cols"]):
+            if toast is not None and toast.any():
+                at = int(np.flatnonzero(toast)[0])
+                toast, null = toast.copy(), (
+                    np.zeros(len(toast), dtype=bool) if null is None
+                    else null.copy())
+                toast[at], null[at] = False, True
+                rows["cols"][i] = (values, null, toast)
+                return
+    raise SystemExit("no unchanged-TOAST cell in what the sink holds")
+
+
 ALTER = {"update_dropped": _update_dropped,
          "delete_as_upsert": _delete_as_upsert,
          "updates_swapped": _updates_swapped,
          "numeric_digit": _numeric_digit, "null_to_empty": _null_to_empty,
-         "other_table": _other_table}
+         "other_table": _other_table,
+         "json_value_changed": _json_value_changed,
+         "toast_as_null": _toast_as_null}
 
 
 def main(argv=None) -> int:

@@ -125,9 +125,10 @@ def test_the_cell_s_files_load_by_name():
     assert [m["name"] for m in cell.end_to_end] == ["cdc_events_per_s",
                                                     "setup_s"]
     names = [m["name"] for m in cell.per_layer]
-    assert sum(n.startswith("drain_") for n in names) == 21
-    assert sum(n.startswith("tpcc_") for n in names) == 7  # 6 + the render
-    assert sum(n.startswith("ch_") for n in names) == 7  # 4 + three new
+    # at least what PR 36 listed: later PRs add metrics
+    assert sum(n.startswith("drain_") for n in names) >= 21
+    assert sum(n.startswith("tpcc_") for n in names) >= 7  # 6 + the render
+    assert sum(n.startswith("ch_") for n in names) >= 7  # 4 + three new
     assert all(cell.readers[n]["reader"] for n in names)
     assert cell.config["destination"] == {"type": "clickhouse",
                                           "database": "default"}

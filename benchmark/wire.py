@@ -13,10 +13,19 @@ length[n] or None) column blocks laid side by side in a row matrix; the
 bytes of a row are the valid ones, in order.
 
 Text forms (`TEXT_BLOCKS`): bool, int2, int4, int8, float8, numeric(p,s),
-bpchar(n), varchar(n), text, date, timestamp, timestamptz — as a server
-with DateStyle ISO and TimeZone UTC prints them. A deployment's generator
-module may bring more under `TEXT_BLOCKS` of its own (`render_*` take them
-as `extra`): `name -> fn(column, values) -> (block, valid, length)`.
+bpchar(n), varchar(n), text, json, jsonb, date, timestamp, timestamptz — as
+a server with DateStyle ISO and TimeZone UTC prints them (json and jsonb
+are the generator's text, which is the server's). A deployment's generator
+module may bring its own text form of a type the reference compares
+(`oplog.COMPARISONS`) under `TEXT_BLOCKS` (`render_*` take them as
+`extra`): `name -> fn(column, values) -> (block, valid, length)`.
+
+Wide text is not padded into the matrix. A `Coded` value longer than
+SPLICE_BYTES stays out of its block and is spliced into the rendered rows
+afterwards, one Python step per such value (each is kilobytes). And every
+render is cut by bytes: its row matrices and masks, `_copy_escaped`'s
+doubled text included, stay under RENDER_BYTES, so a wide row renders in
+fewer rows at a time rather than in a larger matrix.
 """
 
 from __future__ import annotations
@@ -25,15 +34,23 @@ import threading
 
 import numpy as np
 
-from oplog import (DELETE, INSERT, TEXT_TYPES, UPDATE, WAL_STEP, char_width,
-                   key_indices)
+from oplog import (DELETE, INSERT, UPDATE, WAL_STEP, Coded, Pool, as_text,
+                   char_width, key_indices)
 
 PG_EPOCH_US = 946_684_800_000_000  # 2000-01-01 in unix microseconds
 # pg_type's well-known OIDs (a generator module may bring more, TYPE_OIDS)
 TYPE_OIDS = {"bool": 16, "int8": 20, "int2": 21, "int4": 23, "text": 25,
              "float8": 701, "bpchar": 1042, "varchar": 1043, "date": 1082,
-             "timestamp": 1114, "timestamptz": 1184, "numeric": 1700}
+             "timestamp": 1114, "timestamptz": 1184, "numeric": 1700,
+             "json": 114, "jsonb": 3802}
 DAY_US = 86_400_000_000
+# a Coded value longer than this is spliced into the rendered rows, not
+# laid into the row matrix (Postgres moves a value out of line at ~2 KB)
+SPLICE_BYTES = 1024
+# the most one render holds in row matrices and their masks at once: above
+# what the benchmark's cells' chunks need (PERF.md), so only a wider row
+# than theirs cuts a chunk into slices
+RENDER_BYTES = 2 << 30
 
 _POW10 = 10 ** np.arange(19, dtype=np.int64)
 _T5 = ((np.arange(100_000)[:, None] // 10 ** np.arange(4, -1, -1)) % 10
@@ -118,6 +135,8 @@ def _s_text_block(values):
 
 def _text_block(column: dict, values, n: int):
     kind = column["type"]
+    if isinstance(values, Coded):
+        return _coded_block(column, values, False)
     if isinstance(values, bytes):
         if kind == "bpchar":
             values = values.decode().ljust(char_width(column)).encode()
@@ -136,11 +155,84 @@ def _text_block(column: dict, values, n: int):
                            axis=1), length + pad)
 
 
+class _Spliced(tuple):
+    """A part (block, valid, length) whose rows `rows` hold the values
+    `texts` outside the block (no byte of theirs is valid there; `length`
+    counts them): long text, spliced into the rendered rows."""
+
+
+def _spliced(part: tuple, rows: np.ndarray, texts: list) -> tuple:
+    if not len(rows):
+        return tuple(part)
+    out = _Spliced(part)
+    out.rows, out.texts = rows, texts
+    return out
+
+
+def _only_rows(part: tuple, kept: np.ndarray, new: tuple) -> tuple:
+    """`new`, a part made from `part`, keeping the spliced values of the
+    rows that `kept` keeps."""
+    if not isinstance(part, _Spliced):
+        return new
+    keep = kept[part.rows]
+    return _spliced(new, part.rows[keep],
+                    [t for t, k in zip(part.texts, keep.tolist()) if k])
+
+
+def _copy_text(value: bytes) -> bytes:
+    """One value with COPY's backslash escapes."""
+    for raw, esc in ((b"\\", b"\\\\"), (b"\t", b"\\t"), (b"\n", b"\\n"),
+                     (b"\r", b"\\r"), (b"\b", b"\\b"), (b"\f", b"\\f"),
+                     (b"\v", b"\\v")):
+        value = value.replace(raw, esc)
+    return value
+
+
+def _coded_pool(column: dict, coded: Coded, copy: bool) -> Pool:
+    """The pool of the text the rows are sent as: bpchar padded, COPY's
+    escapes made — once per pool."""
+    pool = coded.pool
+    if column["type"] == "bpchar":
+        width = char_width(column)
+        pool = pool.made(("bpchar", width), lambda p: Pool(
+            [v.decode().ljust(width).encode() for v in p.values]))
+    if copy:
+        pool = pool.made("copy", lambda p: Pool(map(_copy_text, p.values)))
+    return pool
+
+
+def _coded_block(column: dict, coded: Coded, copy: bool) -> tuple:
+    """The text block of Coded values: those up to SPLICE_BYTES long in the
+    block, each longer one spliced."""
+    pool = _coded_pool(column, coded, copy)
+    short = pool.made("short", lambda p: np.array(
+        [v if len(v) <= SPLICE_BYTES else b"" for v in p.values] or [b""],
+        dtype="S"))
+    codes = coded.codes
+    block, valid, _ = _s_text_block(short[codes])
+    length = pool.lengths[codes]
+    long_rows = np.flatnonzero(length > SPLICE_BYTES)
+    return _spliced((block, valid, length), long_rows,
+                    [pool.values[c] for c in codes[long_rows].tolist()])
+
+
 def _date_block(days: np.ndarray) -> np.ndarray:
-    """uint8[n,10] 'YYYY-MM-DD' of days since 1970 (years 0001..9999)."""
-    text = np.datetime_as_string(
-        np.asarray(days).astype("M8[D]"), unit="D").astype("S10")
-    return text.view(np.uint8).reshape(len(text), 10)
+    """uint8[n,10] 'YYYY-MM-DD' of days since 1970 (years 0001..9999), in
+    integer arithmetic: the proleptic Gregorian calendar of 400-year eras
+    (H. Hinnant's `civil_from_days`)."""
+    z = np.asarray(days).astype(np.int64) + 719_468
+    era = z // 146_097
+    doe = z - era * 146_097
+    yoe = (doe - doe // 1460 + doe // 36_524 - doe // 146_096) // 365
+    doy = doe - (365 * yoe + yoe // 4 - yoe // 100)
+    mp = (5 * doy + 2) // 153
+    month = np.where(mp < 10, mp + 3, mp - 9)
+    out = np.empty((len(z), 10), dtype=np.uint8)
+    out[:, :4] = _T5[yoe + era * 400 + (month <= 2)][:, 1:]
+    out[:, 4] = out[:, 7] = ord("-")
+    out[:, 5:7] = _T2[month]
+    out[:, 8:10] = _T2[doy - (153 * mp + 2) // 5 + 1]
+    return out
 
 
 def _timestamp_text_block(v: np.ndarray, zone: bytes):
@@ -199,18 +291,66 @@ TEXT_BLOCKS = {
 }
 
 
-def text_block(column: dict, values, n: int, extra=None):
+def text_block(column: dict, values, n: int, extra=None, copy=False):
     """(bytes[n,w], valid or None, length[n] or None) of one column's
-    values as the server's text (length None: every row is w long)."""
+    values as the server's text (length None: every row is w long); with
+    `copy`, as COPY text, its escapes made."""
     kind = column["type"]
     if extra and kind in extra:
-        return extra[kind](column, values)
-    if kind in TEXT_TYPES:
-        return _text_block(column, values, n)
+        part = extra[kind](column, values)
+        return _copy_escaped(*part) if copy else part
+    if as_text(column):
+        if copy and isinstance(values, Coded):
+            return _coded_block(column, values, True)
+        part = _text_block(column, values, n)
+        return _copy_escaped(*part) if copy else part
     if kind not in TEXT_BLOCKS:
         raise KeyError(f"no text form for type {kind!r}: the deployment's "
                        f"generator has to bring one under TEXT_BLOCKS")
     return TEXT_BLOCKS[kind](column, values)
+
+
+# the widest text of each type that has one width bound (numeric adds its
+# scale and a point): '-' and 20 digits, float8's longest repr, ...
+_WIDEST = {"bool": 1, "int2": 21, "int4": 21, "int8": 21, "numeric": 21,
+           "float8": 24, "date": 10, "timestamp": 26, "timestamptz": 29}
+
+
+def width_bound(column: dict, values, extra=None, copy=False) -> int:
+    """Bytes of this column's block in a row matrix, at most, whichever of
+    the rows `values` holds are rendered."""
+    kind = column["type"]
+    if extra and kind in extra:
+        w = extra[kind](column, values)[0].shape[1]
+        return 2 * w if copy else w
+    if not as_text(column):
+        scale = int(column.get("scale", 0)) if kind == "numeric" else 0
+        return _WIDEST[kind] + (1 + scale if scale else 0)
+    if isinstance(values, Coded):
+        pool = _coded_pool(column, values, copy)
+        return int(max((x for x in pool.lengths if x <= SPLICE_BYTES),
+                       default=0))
+    if isinstance(values, bytes):
+        w = len(values.decode().ljust(char_width(column)).encode()) \
+            if kind == "bpchar" else len(values)
+    else:
+        w = values.dtype.itemsize \
+            + (char_width(column) if kind == "bpchar" else 0)
+    return 2 * w if copy else w
+
+
+def _tuple_bound(table: dict, cols: list, extra, only=None) -> int:
+    """Bytes of one TupleData's parts in a row matrix, at most."""
+    return 2 + sum(1 if only is not None and i not in only
+                   else 5 + width_bound(column, col.values, extra)
+                   for i, (column, col) in enumerate(zip(table["columns"],
+                                                         cols)))
+
+
+def _slice_rows(row_bound: int) -> int:
+    """Rows one render may take at once: a matrix and its mask, and as much
+    again for the parts it is made from."""
+    return max(1, RENDER_BYTES // (4 * max(1, row_bound)))
 
 
 def _copy_escaped(block, valid, length):
@@ -310,12 +450,45 @@ def _rows_matrix(parts: list, n: int, scratch: bool = True):
     return mat, mask
 
 
-def _rows_blob(parts: list, n: int):
+def _rows_blob(parts: list, n: int, scratch: bool = True):
     """Concatenate per-row pieces into one uint8 array plus row offsets."""
-    mat, mask = _rows_matrix(parts, n)
+    mat, mask = _rows_matrix(parts, n, scratch)
+    if any(isinstance(p, _Spliced) for p in parts):
+        return _splice(mat[mask], mask.sum(axis=1), parts, n)
     offsets = np.zeros(n + 1, dtype=np.int64)
     np.cumsum(mask.sum(axis=1), out=offsets[1:])
     return mat[mask], offsets
+
+
+def _splice(short: np.ndarray, row_len: np.ndarray, parts: list, n: int):
+    """The blob of `n` rows whose matrix bytes are `short` (`row_len` of
+    them a row) with the spliced values of `parts` put in their places,
+    and its row offsets."""
+    before = np.zeros(n, dtype=np.int64)  # a row's bytes ahead of a part
+    rows, ahead, texts = [], [], []
+    for part in parts:
+        if isinstance(part, _Spliced):
+            rows.append(part.rows)
+            ahead.append(before[part.rows])
+            texts += part.texts
+        before += _lengths([part], n)
+    rows, ahead = np.concatenate(rows), np.concatenate(ahead)
+    size = np.fromiter(map(len, texts), dtype=np.int64, count=len(texts))
+    row_len = row_len.astype(np.int64)
+    np.add.at(row_len, rows, size)
+    offsets = np.zeros(n + 1, dtype=np.int64)
+    np.cumsum(row_len, out=offsets[1:])
+    place = offsets[rows] + ahead
+    order = np.argsort(place, kind="stable")
+    # where each lands in `short`: its place less the spliced bytes before
+    cut = place[order] - (np.cumsum(size[order]) - size[order])
+    view = memoryview(short)
+    pieces, at = [], 0
+    for c, k in zip(cut.tolist(), order.tolist()):
+        pieces += [view[at:c], texts[k]]
+        at = c
+    pieces.append(view[at:])
+    return np.frombuffer(b"".join(pieces), dtype=np.uint8), offsets
 
 
 # ---------------------------------------------------------------------------
@@ -334,7 +507,8 @@ def _tuple_parts(table: dict, cols: list, n: int, extra, only=None) -> list:
         if only is not None and i not in only:
             parts.append(_const(n, b"n"))
             continue
-        block, valid, length = text_block(column, col.values, n, extra)
+        part = text_block(column, col.values, n, extra)
+        block, valid, length = part
         absent = col.null
         if col.unchanged is not None:
             absent = col.unchanged if absent is None \
@@ -343,7 +517,7 @@ def _tuple_parts(table: dict, cols: list, n: int, extra, only=None) -> list:
             parts += [_const(n, b"t"),
                       _const(n, block.shape[1].to_bytes(4, "big"))
                       if length is None else (_be(length, ">i4"), None, None),
-                      (block, valid, length)]
+                      part]
             continue
         if length is None:
             length = np.full(n, block.shape[1], dtype=np.int64)
@@ -356,8 +530,8 @@ def _tuple_parts(table: dict, cols: list, n: int, extra, only=None) -> list:
         parts += [(kind[:, None], None, None),
                   (_be(length, ">i4"), np.broadcast_to(sent[:, None], (n, 4)),
                    4 * sent),
-                  (block, np.broadcast_to(keep, block.shape),
-                   length * sent)]
+                  _only_rows(part, sent, (block, np.broadcast_to(
+                      keep, block.shape), length * sent))]
     return parts
 
 
@@ -393,10 +567,44 @@ def render_change_frames(table: dict, op: int, old_kind: int, new, old,
     (`op`, and `old_kind`: 0, 'K' or 'O'). Returns (bytes, offsets[n+1],
     payload_lengths[n])."""
     n = len(lsns)
+    step = _slice_rows(_change_bound(table, op, old_kind, new, old, extra))
+    if n > step:
+        pieces = [render_change_frames(
+            table, op, old_kind,
+            None if new is None else [c.pick(slice(lo, lo + step))
+                                      for c in new],
+            None if old is None else [c.pick(slice(lo, lo + step))
+                                      for c in old],
+            lsns[lo:lo + step], end_lsns[lo:lo + step], clock_us, extra)
+            for lo in range(0, n, step)]
+        return (*_joined([p[:2] for p in pieces]),
+                np.concatenate([p[2] for p in pieces]))
     parts, payload_len = _change_parts(table, op, old_kind, new, old, n,
                                        lsns, end_lsns, clock_us, extra)
     blob, offsets = _rows_blob(parts, n)
     return blob, offsets, payload_len
+
+
+def _change_bound(table: dict, op: int, old_kind: int, new, old,
+                  extra) -> int:
+    """Bytes of one change's frame in a row matrix, at most: the headers,
+    the old or key image and the new row."""
+    out = 30 + 5  # CopyData and XLogData headers; the change and relation
+    if old_kind:
+        out += 1 + _tuple_bound(
+            table, old, extra,
+            set(key_indices(table)) if old_kind == ord("K") else None)
+    if op != DELETE:
+        out += 1 + _tuple_bound(table, new, extra)
+    return out
+
+
+def _joined(pieces: list) -> tuple:
+    """One (blob, offsets) of consecutive rows' (blob, offsets) pieces."""
+    base = np.cumsum([0] + [len(b) for b, _ in pieces[:-1]])
+    return (np.concatenate([b for b, _ in pieces]),
+            np.concatenate([[0]] + [o[1:] + at
+                                    for (_, o), at in zip(pieces, base)]))
 
 
 def old_kinds(tables: list, stream) -> np.ndarray:
@@ -466,7 +674,9 @@ def render_transactions(tables: list, stream, kinds: np.ndarray, local,
     table's RELATION once if `relations` — their payloads — are given,
     every change, COMMIT), and the summed pgoutput payload bytes of their
     change messages. `kinds` = old_kinds(tables, stream), `local` =
-    stream.local_index(): both span the whole stream."""
+    stream.local_index(): both span the whole stream. Events whose frames
+    would hold more than RENDER_BYTES in matrices at once render in
+    slices, joined."""
     layout = stream.layout
     rows = layout.rows[k0:k1]
     starts = np.concatenate(([0], np.cumsum(rows)))
@@ -477,15 +687,64 @@ def render_transactions(tables: list, stream, kinds: np.ndarray, local,
     ends = np.repeat(layout.end_lsn[k0:k1], rows)
     group = (stream.table[e0:e0 + n].astype(np.int64) << 16) \
         | (stream.op[e0:e0 + n].astype(np.int64) << 8) | kinds[e0:e0 + n]
-    if not n:
-        keys = []
-    elif (group == group[0]).all():
-        keys = [int(group[0])]
+    keys = _keys(group)
+    bound = max((_change_bound(tables[t], op, old_kind,
+                               stream.events[t].new, stream.events[t].old,
+                               extra)
+                 for t, op, old_kind in map(_unkey, keys)), default=0)
+    step = _slice_rows(bound)
+    if n <= step:
+        blob, offsets, payload_bytes = _events_blob(
+            tables, stream, local, e0, group, keys, lsns, ends, clock_us,
+            extra)
     else:
-        keys = np.unique(group).tolist()
+        pieces = [_events_blob(tables, stream, local, e0 + lo,
+                               group[lo:lo + step],
+                               _keys(group[lo:lo + step]),
+                               lsns[lo:lo + step], ends[lo:lo + step],
+                               clock_us, extra)
+                  for lo in range(0, n, step)]
+        blob, offsets = _joined([p[:2] for p in pieces])
+        payload_bytes = sum(p[2] for p in pieces)
+    view = memoryview(blob)
+    pg_ts = (clock_us - PG_EPOCH_US).to_bytes(8, "big", signed=True)
+    bufs = []
+    for j, k in enumerate(range(k0, k1)):
+        b, c, e = (int(layout.begin_lsn[k]), int(layout.commit_lsn[k]),
+                   int(layout.end_lsn[k]))
+        head = xlog_frame(b, e, clock_us, b"B" + c.to_bytes(8, "big") + pg_ts
+                           + (1000 + k).to_bytes(4, "big"))
+        if relations and j == 0:
+            for payload in relations:
+                head += xlog_frame(b + WAL_STEP, e, clock_us, payload)
+        tail = xlog_frame(c, e, clock_us, b"C\x00" + c.to_bytes(8, "big")
+                           + e.to_bytes(8, "big") + pg_ts)
+        bufs.append(b"".join((
+            head, view[offsets[starts[j]]:offsets[starts[j + 1]]], tail)))
+    return bufs, payload_bytes
+
+
+def _keys(group: np.ndarray) -> list:
+    """The kinds of change (table << 16 | op << 8 | old kind) in `group`."""
+    if not len(group):
+        return []
+    if (group == group[0]).all():
+        return [int(group[0])]
+    return np.unique(group).tolist()
+
+
+def _unkey(key: int) -> tuple:
+    return key >> 16, (key >> 8) & 0xFF, key & 0xFF
+
+
+def _events_blob(tables: list, stream, local, e0: int, group: np.ndarray,
+                 keys: list, lsns, ends, clock_us: int, extra) -> tuple:
+    """(blob, offsets, payload bytes) of the events e0.. that `group`
+    spans, in WAL order."""
+    n = len(group)
     rendered = []
     for key in keys:
-        t, op, old_kind = key >> 16, (key >> 8) & 0xFF, key & 0xFF
+        t, op, old_kind = _unkey(key)
         if len(keys) == 1:
             # one table's one kind of change: its events are neighbours in
             # the table's own arrays too, and the blob needs no reordering
@@ -506,46 +765,40 @@ def render_transactions(tables: list, stream, kinds: np.ndarray, local,
     if len(rendered) == 1:
         _, parts, payload_len = rendered[0]
         blob, offsets = _rows_blob(parts, n)
-        payload_bytes = int(payload_len.sum())
-    elif rendered:
+        return blob, offsets, int(payload_len.sum())
+    if rendered:
         blob, offsets = _interleaved(rendered, n)
-        payload_bytes = int(sum(p.sum() for _, _, p in rendered))
-    else:
-        blob, offsets, payload_bytes = np.zeros(0, np.uint8), \
-            np.zeros(1, np.int64), 0
-    view = memoryview(blob)
-    pg_ts = (clock_us - PG_EPOCH_US).to_bytes(8, "big", signed=True)
-    bufs = []
-    for j, k in enumerate(range(k0, k1)):
-        b, c, e = (int(layout.begin_lsn[k]), int(layout.commit_lsn[k]),
-                   int(layout.end_lsn[k]))
-        head = xlog_frame(b, e, clock_us, b"B" + c.to_bytes(8, "big") + pg_ts
-                           + (1000 + k).to_bytes(4, "big"))
-        if relations and j == 0:
-            for payload in relations:
-                head += xlog_frame(b + WAL_STEP, e, clock_us, payload)
-        tail = xlog_frame(c, e, clock_us, b"C\x00" + c.to_bytes(8, "big")
-                           + e.to_bytes(8, "big") + pg_ts)
-        bufs.append(b"".join((
-            head, view[offsets[starts[j]]:offsets[starts[j + 1]]], tail)))
-    return bufs, payload_bytes
+        return blob, offsets, int(sum(p.sum() for _, _, p in rendered))
+    return np.zeros(0, np.uint8), np.zeros(1, np.int64), 0
 
 
 def _interleaved(rendered: list, n: int):
     """The blob of `n` events that several kinds of change render: each
-    kind's row matrix is laid into one matrix as wide as the widest, at
-    its events' own places, so the valid bytes come out in WAL order."""
-    mats = [(idx, *_rows_matrix(parts, len(idx), scratch=False))
-            for idx, parts, _ in rendered]
-    width = max(mat.shape[1] for _, mat, _ in mats)
-    whole = np.empty((n, width), dtype=np.uint8)
-    keep = np.zeros((n, width), dtype=bool)
-    for idx, mat, mask in mats:
-        whole[idx, :mat.shape[1]] = mat
-        keep[idx, :mat.shape[1]] = mask
+    kind's rows made on their own, then every run of neighbouring events of
+    one kind taken from its kind's blob, in WAL order — no row is padded to
+    another kind's width."""
+    kind = np.empty(n, dtype=np.int64)
+    rank = np.empty(n, dtype=np.int64)  # an event's row among its kind's
+    length = np.empty(n, dtype=np.int64)
+    blobs = []
+    for k, (idx, parts, _) in enumerate(rendered):
+        blob, offsets = _rows_blob(parts, len(idx), scratch=False)
+        blobs.append((memoryview(blob), offsets))
+        kind[idx], rank[idx] = k, np.arange(len(idx))
+        length[idx] = np.diff(offsets)
+    first = np.flatnonzero(np.diff(kind, prepend=-1))  # each run's first
+    size = np.diff(np.append(first, n))
+    lo, hi = np.empty(len(first), np.int64), np.empty(len(first), np.int64)
+    for k, (_, offsets) in enumerate(blobs):
+        mine = kind[first] == k
+        r = rank[first[mine]]
+        lo[mine], hi[mine] = offsets[r], offsets[r + size[mine]]
+    views = [blobs[k][0] for k in kind[first].tolist()]
     offsets = np.zeros(n + 1, dtype=np.int64)
-    np.cumsum(keep.sum(axis=1), out=offsets[1:])
-    return whole[keep], offsets
+    np.cumsum(length, out=offsets[1:])
+    return np.frombuffer(b"".join([
+        v[a:b] for v, a, b in zip(views, lo.tolist(), hi.tolist())]),
+        dtype=np.uint8), offsets
 
 
 # ---------------------------------------------------------------------------
@@ -556,12 +809,20 @@ def _interleaved(rendered: list, n: int):
 def render_copy_rows(table: dict, cols: list, n: int, extra=None):
     """CopyData('d') messages of COPY text, one row each: the columns'
     texts between tabs (NULL as \\N, COPY's escapes in text values) and a
-    newline, as a server sends them. Returns (uint8 array, offsets[n+1])."""
+    newline, as a server sends them. Returns (uint8 array, offsets[n+1]).
+    Rows that would hold more than RENDER_BYTES in matrices at once render
+    in slices, joined."""
+    step = _slice_rows(5 + sum(
+        1 + max(2, width_bound(column, col.values, extra, copy=True))
+        for column, col in zip(table["columns"], cols)))
+    if n > step:
+        return _joined([render_copy_rows(
+            table, [c.pick(slice(lo, lo + step)) for c in cols],
+            min(step, n - lo), extra) for lo in range(0, n, step)])
     parts = []
     for i, (column, col) in enumerate(zip(table["columns"], cols)):
-        block, valid, length = text_block(column, col.values, n, extra)
-        if column["type"] in TEXT_TYPES or (extra and column["type"] in extra):
-            block, valid, length = _copy_escaped(block, valid, length)
+        part = text_block(column, col.values, n, extra, copy=True)
+        block, valid, length = part
         if col.null is not None and col.null.any():
             w = max(2, block.shape[1])
             wide = np.empty((n, w), dtype=np.uint8)
@@ -572,9 +833,8 @@ def render_copy_rows(table: dict, cols: list, n: int, extra=None):
             keep[col.null] = np.arange(w) < 2
             length = np.where(col.null, 2, length if length is not None
                               else block.shape[1])
-            block, valid = wide, keep
-        parts += [(block, valid, length),
-                  _const(n, b"\n" if i == len(cols) - 1 else b"\t")]
+            part = _only_rows(part, ~col.null, (wide, keep, length))
+        parts += [part, _const(n, b"\n" if i == len(cols) - 1 else b"\t")]
     line_len = _lengths(parts, n)
     return _rows_blob(_merged(
         [_const(n, b"d"), (_be(line_len + 4, ">i4"), None, None)] + parts,
